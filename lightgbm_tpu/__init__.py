@@ -14,11 +14,10 @@ Public API mirrors the reference Python package
 from .utils.backend import enable_compilation_cache as _enable_cache
 
 # persistent XLA compilation cache: the grower is one big program whose
-# cold compile costs minutes; cached compiles load in seconds.  Opt out
-# with LIGHTGBM_TPU_CACHE=off; override the location (default
-# <repo>/.jax_cache) with LIGHTGBM_TPU_CACHE_DIR.
-if __import__("os").environ.get("LIGHTGBM_TPU_CACHE", "") != "off":
-    _enable_cache()
+# cold compile costs minutes; cached compiles load in seconds.  The
+# directory is JAX_COMPILATION_CACHE_DIR where set, else
+# <checkout>/.jax_cache (utils/backend.py holds the rule).
+_enable_cache()
 
 from .version import __version__
 from .config import Config

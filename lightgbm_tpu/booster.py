@@ -217,10 +217,7 @@ class Booster:
             return True
         import jax
 
-        try:
-            return jax.default_backend() == "tpu"
-        except Exception:
-            return False
+        return jax.default_backend() == "tpu"
 
     def predict(self, data, num_iteration: Optional[int] = None,
                 raw_score: bool = False, pred_leaf: bool = False,

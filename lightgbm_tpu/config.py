@@ -490,10 +490,11 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     # quantized training's renew-leaf).  Turn off for strictly bitwise
     # cross-shard model files
     "tpu_quant_refit_leaves": ("bool", True, ()),
-    # persistent XLA compilation cache directory (empty = off): repeat
-    # runs of same-shaped programs skip the cold compile tail.  Applied
-    # at first device use (jax_compilation_cache_dir); CPU-destined
-    # processes get a host-fingerprinted subdir (utils/backend.py)
+    # persistent XLA compilation cache directory for this run (empty =
+    # the package default, <checkout>/.jax_cache): every program of the
+    # run is cached there, whatever its compile time.  Ignored where
+    # JAX_COMPILATION_CACHE_DIR is set — the environment places the
+    # cache (utils/backend.py)
     "tpu_compile_cache_dir": ("str", "", ()),
     # persisted perf autotuning (utils/autotune.py): off | load | tune.
     #   off  - every "auto" resolves from the built-in heuristics
@@ -524,18 +525,16 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     # per pass
     "tpu_split_batch": ("int", 0, ()),
     # batched-histogram backend: auto | xla | pallas | pallas2 | fused.
-    # auto picks the hardware-validated pallas kernel on TPU when its VMEM
-    # working set fits (measured 1.9x over the xla scan on Higgs-1M: the
-    # one-hot never round-trips to HBM), else xla.  pallas2 = per-feature
-    # one-hot variant running 2-8k-row blocks.  fused = the grow
-    # megakernel (ops/fused.py): pallas2's accumulator PLUS in-VMEM
-    # sibling subtraction and the split gain scan, emitting per-feature
-    # best-split records so split search never leaves the device.  The
-    # in-kernel scan engages on serial quantized (int8/int16) plain dense
-    # training — bit-identical models to the unfused path — and degrades
-    # to pallas2 + device select() everywhere else.  auto promotes
-    # int8/int16 to fused on TPU only after the runtime validation probe
-    # (fused.fused_scan_ok) passes; a Mosaic failure falls back LOUDLY
+    # auto is a fixed rule (learner._resolve_hist_impl): pallas2 on a TPU
+    # at hilo/bf16/int8 when its VMEM working set fits, xla everywhere
+    # else (CPU, f32/f64, int16).  pallas2 = per-feature one-hot variant
+    # running 2-8k-row blocks.  fused = the grow megakernel
+    # (ops/fused.py): pallas2's accumulator PLUS in-VMEM sibling
+    # subtraction and the split gain scan, on serial quantized
+    # (int8/int16) plain dense training.  fused is explicit-only and auto
+    # never picks it: Mosaic does not lower its in-kernel scan on a TPU
+    # (cumsum), so there it raises the compiler's error; it runs in
+    # interpret mode on CPU
     "tpu_hist_impl": ("str", "auto", ()),
     # data-axis histogram aggregation (tree_learner=data / voting /
     # data_feature): psum | scatter | auto.
@@ -573,7 +572,9 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     # (ops/fused.py partition_rows): vselect's exact integer math as one
     # VMEM pass over the row blocks instead of a separate XLA program
     # point — plain dense numerical columns only (no categoricals, EFB,
-    # sparse storage, or 4-bit packing)
+    # sparse storage, or 4-bit packing).  CPU (interpret mode) only so
+    # far: Mosaic on a v5e refuses the kernel ("Unsupported target
+    # bitwidth for truncation", an i8->i1 trunci)
     "tpu_partition_impl": ("str", "select", ()),
     # frontier ramp: unrolled K'=1,2,4,... pre-rounds before the full-K
     # loop (bit-identical trees, removes early rounds' dead-slot MXU

@@ -735,11 +735,7 @@ class GBDT:
         # it bit-equivalently
         pool = (getattr(self.learner, "_pool", None)
                 if self.learner is not None else None)
-        try:
-            deleted = pool is not None and pool.is_deleted()
-        except AttributeError:  # pragma: no cover - old jaxlib
-            deleted = False
-        if deleted:
+        if pool is not None and pool.is_deleted():
             self.learner.reset_pool()
         self._invalidate_tables()
         self._restore_extra(snap)
@@ -1244,11 +1240,8 @@ class GBDT:
     def _key_words(key) -> List[int]:
         """PRNG key -> raw uint32 words (JSON-able)."""
         arr = key
-        try:
-            if jnp.issubdtype(arr.dtype, jax.dtypes.prng_key):
-                arr = jax.random.key_data(arr)
-        except (AttributeError, TypeError):  # pragma: no cover - old jax
-            pass
+        if jnp.issubdtype(arr.dtype, jax.dtypes.prng_key):
+            arr = jax.random.key_data(arr)
         return [int(w) for w in
                 np.ravel(np.asarray(jax.device_get(arr))).astype(np.uint32)]
 
@@ -1256,11 +1249,8 @@ class GBDT:
     def _words_to_key(words, like):
         """uint32 words -> a key matching `like`'s representation."""
         arr = jnp.asarray(np.asarray(words, np.uint32).reshape(-1))
-        try:
-            if jnp.issubdtype(like.dtype, jax.dtypes.prng_key):
-                return jax.random.wrap_key_data(arr)
-        except (AttributeError, TypeError):  # pragma: no cover - old jax
-            pass
+        if jnp.issubdtype(like.dtype, jax.dtypes.prng_key):
+            return jax.random.wrap_key_data(arr)
         return arr
 
     def topology_snapshot(self) -> Dict:

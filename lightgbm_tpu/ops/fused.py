@@ -36,29 +36,22 @@ the scan fusion is forgone).
 blocks, mirroring the "vselect" lowering's integer math bit-for-bit
 (split.numeric_go_left is the shared decision function).
 
-Runtime validation (`mosaic_int16_ok` / `fused_scan_ok`): Mosaic support
-for int16 MXU dots and for the traced scan body differs across TPU
-generations, so `auto` resolution never *assumes* — it runs a tiny eager
-probe (un-jitted: invisible to the compile ledger) against the XLA
-reference and falls back LOUDLY on exception or mismatch.  On CPU the
-kernels run in interpret mode (plain jnp) and the probes pass trivially.
+Mosaic does not lower the in-kernel scan on a TPU (`cumsum` has no Pallas
+TPU lowering, and behind it sit a reversed argmax and integer gathers), so
+`tpu_hist_impl=auto` never picks "fused": it is explicit-only, raises the
+compiler's own error on a TPU and runs in interpret mode (plain jnp) on
+CPU, where the parity tests exercise it.
 """
 
 from __future__ import annotations
 
-import functools
-import logging
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from .histogram import (_INT_STAT_DTYPES, _dot_spec, _unpack_hist,
-                        bench_hist_operands, build_histogram_batched_t)
+                        pallas_interpret)
 from .split import (PF_RECORD_WIDTH, pack_pf_records, numeric_go_left,
                     per_feature_best_split, unpack_pf_records)
-
-LOG = logging.getLogger("lightgbm_tpu.fused")
 
 # VMEM budget for the fused kernel's resident blocks (accumulator +
 # parent histograms + records); smaller than the plain perfeature
@@ -216,7 +209,6 @@ def fused_hist_scan(bins_t_blocks, stats_blocks, leaf_blocks,
                     acc_scale=qs, **kw)
                 rec_ref[:, j * RW:(j + 1) * RW] = pack_pf_records(pf)
 
-    interpret = jax.devices()[0].platform not in ("tpu",)
     stats_nb = jnp.moveaxis(stats_blocks, 1, 0)
     raw, recs = pl.pallas_call(
         kernel,
@@ -239,7 +231,7 @@ def fused_hist_scan(bins_t_blocks, stats_blocks, leaf_blocks,
             jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
             jax.ShapeDtypeStruct((F, C * RW), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
       slot_leaf_ids.reshape(K, 1), par_flat, child_ctx,
       meta_i, meta_f)
@@ -292,7 +284,6 @@ def partition_rows(cols, leaf_ids, sel, new_ids, thr, dleft, mt, nbf, db,
                         keepdims=True)                    # [1, blk]
         out_ref[...] = jnp.where(moved >= 0, moved, li)
 
-    interpret = jax.devices()[0].platform not in ("tpu",)
     out = pl.pallas_call(
         kernel,
         grid=(nb,),
@@ -303,122 +294,9 @@ def partition_rows(cols, leaf_ids, sel, new_ids, thr, dleft, mt, nbf, db,
         ],
         out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(cols.astype(jnp.int32), ints, leaf_ids.reshape(1, n_pad))
     return out.reshape(n_pad)
-
-
-# --------------------------------------------------------------------------
-# Runtime (hardware) validation probes — eager, tiny, invisible to the
-# compile ledger; memoized so each backend pays once per process
-# --------------------------------------------------------------------------
-
-def _probe_operands(precision: str, seed: int = 0):
-    """Tiny deterministic operands shared by the validation probes."""
-    rng = np.random.default_rng(seed)
-    n, F, B, block = 256, 8, 16, 128
-    bins_np = rng.integers(0, B, size=(n, F)).astype(np.uint8)
-    bins_tb, stats, _ = bench_hist_operands(bins_np, precision, block,
-                                            seed=seed)
-    nb = n // block
-    leaf_np = rng.integers(0, 2, size=(nb, block)).astype(np.int32)
-    return bins_tb, stats, jnp.asarray(leaf_np), F, B, nb, block
-
-
-@functools.lru_cache(maxsize=4)
-def mosaic_int16_ok() -> bool:
-    """Hardware-validate the Mosaic int16 histogram dot.
-
-    Compares the pallas2 perfeature kernel's int16 contraction against
-    the XLA reference on tiny operands, eagerly (no jit → no ledger
-    site).  int32 accumulation is exact, so anything but bitwise
-    equality means the backend mis-lowers the int16 dot and auto must
-    keep pinning int16 to XLA there.  On CPU the kernel runs in
-    interpret mode and the probe passes trivially; on TPU it is a true
-    Mosaic compile + execute check."""
-    try:
-        bins_tb, stats, leaf, F, B, nb, block = _probe_operands("int16")
-        slots = jnp.full(4, -1, jnp.int32).at[0].set(0).at[1].set(1)
-        ref = build_histogram_batched_t(bins_tb, stats, leaf, slots, B,
-                                        "int16", impl="xla")
-        got = build_histogram_batched_t(bins_tb, stats, leaf, slots, B,
-                                        "int16", impl="pallas2")
-        ok = bool(jnp.array_equal(ref, got))
-    except Exception as exc:  # Mosaic validation/compile failure
-        LOG.warning(
-            "mosaic int16 probe FAILED (%s: %s) — tpu_hist_impl=auto "
-            "keeps int16 pinned to the XLA contraction on this backend",
-            type(exc).__name__, exc)
-        return False
-    if not ok:
-        LOG.warning(
-            "mosaic int16 probe MISMATCHED the XLA reference — "
-            "tpu_hist_impl=auto keeps int16 pinned to XLA on this backend")
-    return ok
-
-
-@functools.lru_cache(maxsize=8)
-def fused_scan_ok(precision: str = "int8") -> bool:
-    """Validate the fused kernel's in-kernel split scan on this backend.
-
-    Runs `fused_hist_scan` eagerly on tiny operands and compares its
-    records bitwise against the reference composition (XLA batched
-    histograms → sibling subtraction → per_feature_best_split).  A
-    Mosaic lowering failure (the traced scan uses 1-D iota/gather
-    patterns some TPU generations reject) or any f32 divergence returns
-    False, and auto resolution falls back — loudly — to the plain
-    perfeature kernel + device select()."""
-    try:
-        bins_tb, stats, leaf, F, B, nb, block = _probe_operands(precision)
-        K = 2
-        slots = jnp.asarray([0, 1], jnp.int32)
-        # reference smaller-child histograms + a synthetic parent pool
-        small_ref = build_histogram_batched_t(bins_tb, stats, leaf, slots,
-                                              B, precision, impl="xla")
-        total = jnp.sum(small_ref, axis=0)
-        parent = jnp.broadcast_to(total, small_ref.shape) * 2
-        qs = jnp.asarray([0.5, 0.25, 1.0], jnp.float32)
-        C = 2 * K
-        ctx = np.zeros((C + 1, 8), np.float32)
-        for j in range(C):
-            ctx[j] = [1.0 + j, 2.0 + j, 128.0, -1e30, 1e30,
-                      1.0 if j % 2 == 0 else 0.0, 0.0, 0.0]
-        ctx[C, :3] = np.asarray(qs)
-        ctx = jnp.asarray(ctx)
-        meta_i = jnp.stack(
-            [jnp.full(F, B, jnp.int32), jnp.zeros(F, jnp.int32),
-             jnp.zeros(F, jnp.int32), jnp.zeros(F, jnp.int32)]
-            + [jnp.zeros(F, jnp.int32)] * 4, axis=1)
-        meta_f = jnp.stack(
-            [jnp.ones(F, jnp.float32), jnp.ones(F, jnp.float32)]
-            + [jnp.zeros(F, jnp.float32)] * 6, axis=1)
-        kw = dict(l1=0.0, l2=1.0, max_delta_step=0.0,
-                  min_data_in_leaf=1.0, min_sum_hessian=1e-3,
-                  min_gain_to_split=0.0)
-        hist, recs = fused_hist_scan(
-            bins_tb, stats, leaf, slots, parent, ctx, meta_i, meta_f,
-            B, precision, split_kw=kw)
-        if not bool(jnp.array_equal(hist, small_ref)):
-            raise AssertionError("fused histogram != XLA reference")
-        for j in range(C):
-            k = j % K
-            hs = jnp.where(ctx[j, CTX_USE_SMALL] > 0, small_ref[k],
-                           parent[k] - small_ref[k])
-            pf = per_feature_best_split(
-                hs, ctx[j, CTX_SUM_G], ctx[j, CTX_SUM_H],
-                ctx[j, CTX_COUNT], meta_i[:, 0], meta_i[:, 1],
-                meta_i[:, 2], meta_i[:, 3], meta_f[:, 0], meta_f[:, 1],
-                min_constraint=ctx[j, CTX_MIN_C],
-                max_constraint=ctx[j, CTX_MAX_C], acc_scale=qs, **kw)
-            if not bool(jnp.array_equal(recs[j], pack_pf_records(pf))):
-                raise AssertionError(f"fused records diverge (child {j})")
-        return True
-    except Exception as exc:
-        LOG.warning(
-            "fused grow-scan probe FAILED (%s: %s) — falling back to the "
-            "perfeature histogram kernel + device select() on this "
-            "backend", type(exc).__name__, exc)
-        return False
 
 
 def children_from_records(records, finalize):
@@ -431,6 +309,5 @@ def children_from_records(records, finalize):
 __all__ = [
     "CTX_SUM_G", "CTX_SUM_H", "CTX_COUNT", "CTX_MIN_C", "CTX_MAX_C",
     "CTX_USE_SMALL", "children_from_records", "fused_hist_scan",
-    "fused_scan_ok", "fused_supported", "mosaic_int16_ok",
-    "partition_rows", "unpack_pf_records",
+    "fused_supported", "partition_rows", "unpack_pf_records",
 ]

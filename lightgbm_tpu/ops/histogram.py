@@ -110,11 +110,8 @@ def hashed_uniform(idx: jnp.ndarray, seed_a, seed_b, salt: int
 
 def key_words(key: jnp.ndarray):
     """Two uint32 words from a PRNG key (raw uint32[2] or typed)."""
-    try:
-        if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
-            key = jax.random.key_data(key)
-    except (AttributeError, TypeError):  # pragma: no cover - old jax
-        pass
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
     kw = jnp.ravel(key).astype(jnp.uint32)
     return kw[0], kw[-1]
 
@@ -206,11 +203,18 @@ def pack_stats(grad: jnp.ndarray, hess: jnp.ndarray, mask: jnp.ndarray,
         return jnp.stack([grad, hess, mask]).astype(jnp.float32)
     if precision == "bf16":
         return jnp.stack([grad, hess, mask]).astype(jnp.bfloat16)
-    # hilo
-    g_hi = grad.astype(jnp.bfloat16)
-    g_lo = (grad - g_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    h_hi = hess.astype(jnp.bfloat16)
-    h_lo = (hess - h_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    # hilo.  The hi half is rounded with reduce_precision, which XLA never
+    # elides: written as x.astype(bf16).astype(f32), the TPU compiler keeps
+    # the f32 value through the round trip (excess precision is allowed by
+    # default), x - hi is then 0 and the lo half is lost — histograms at
+    # bf16 accuracy against exact f32 leaf totals (first chip run, PR 21: a
+    # 149-row leaf of the first Higgs-1M tree came out at -5359)
+    def split(x):
+        hi = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+        return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
+
+    g_hi, g_lo = split(grad)
+    h_hi, h_lo = split(hess)
     cnt = mask.astype(jnp.bfloat16)  # exact: 0.0 or 1.0
     return jnp.stack([g_hi, g_lo, h_hi, h_lo, cnt])
 
@@ -407,6 +411,18 @@ def build_histogram_sparse(sidx: jnp.ndarray, sbin: jnp.ndarray,
 # pallas kernel; the remaining ~10 MB of VMEM holds the [Bp, blk] one-hot,
 # the [K*S, blk] expanded stats, and the double-buffered input DMAs
 _PERFEATURE_OUT_BUDGET = 6 * 1024 * 1024
+# the precisions `tpu_hist_impl=auto` (and the autotuner) may hand to the
+# perfeature kernel: each compiled and ran at full Higgs width on a v5e,
+# at 8192- and 16384-row blocks, equal to the xla contraction (PR 21).
+# f32 runs too but took 157 s to compile; Mosaic refuses int16 dots
+PERFEATURE_AUTO_PRECISIONS = ("hilo", "bf16", "int8")
+
+
+def pallas_interpret() -> bool:
+    """Whether `pallas_call` runs its kernels in interpret mode — the ONE
+    place that is decided: compiled by Mosaic whenever the platform is
+    `tpu`, interpreted (plain jnp, for tests) everywhere else."""
+    return jax.devices()[0].platform != "tpu"
 
 
 def unpack2d(b2):
@@ -416,9 +432,10 @@ def unpack2d(b2):
     block's first half of rows, high nibbles the second): the pallas
     kernels and the grower's partition unpack must agree or packed
     histograms and packed partitions silently diverge."""
-    return jnp.concatenate(
-        [(b2 & 0xF).astype(jnp.int32), (b2 >> 4).astype(jnp.int32)],
-        axis=-1)
+    # widen FIRST: the VPU has no 8-bit shift (Mosaic on a v5e: "failed to
+    # legalize operation 'arith.shrui'" on i8 vectors)
+    b = b2.astype(jnp.int32)
+    return jnp.concatenate([b & 0xF, b >> 4], axis=-1)
 
 
 def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
@@ -434,16 +451,14 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     Two kernel-body variants share this scaffolding:
 
     * "flat" (impl "pallas"): one [F*B, blk] one-hot dot per grid step.
-      Hardware-validated at 256-row blocks (1.93 it/s on the Higgs-1M
-      bench shape, docs/PERF_NOTES.md); the monolithic one-hot costs a
-      multi-MB VMEM retiling copy per step (merging the [F, B, blk]
-      iota-compare into dot operand layout) and caps the block at 256
-      rows before VMEM overflows, putting ~4k grid steps of accumulator
-      read-modify-write on the critical path.
-    * "perfeature" (impl "pallas2", the hardware-validated auto default:
-      3.14 it/s on the Higgs-1M bench shape at 8192-row blocks with
-      hilo precision + frontier ramp, round-3 sweep in
-      docs/PERF_NOTES.md): the one-hot is generated per feature ([Bp, blk],
+      Compiles and runs on a v5e at 256-row blocks (PR 21); the
+      monolithic one-hot costs a multi-MB VMEM retiling copy per step
+      (merging the [F, B, blk] iota-compare into dot operand layout) and
+      caps the block at 256 rows before VMEM overflows, putting ~4k grid
+      steps of accumulator read-modify-write on the critical path.
+    * "perfeature" (impl "pallas2", the auto default on a TPU at
+      8192-row blocks; its speed on the current installation is not
+      measured): the one-hot is generated per feature ([Bp, blk],
       statically-unrolled dots), so the largest temporary shrinks from
       [F*B, blk] to [Bp, blk], blocks of 2-8k rows fit, and the grid
       shrinks ~16x.  Each feature's bin rows live at a sublane-aligned
@@ -455,6 +470,7 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
       chunk's accumulator stays VMEM-resident across its row sweep.
     """
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     nb, F, bins_block = bins_t_blocks.shape
     # packed 4-bit storage (the reference dense_nbits_bin.hpp analog,
@@ -485,8 +501,17 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         s = stats_ref[0]                        # [S, blk]
         l = leaf_ref[0]                         # [1, blk] i32
         slots = slots_ref[:]                    # [K, 1] i32
-        slot_oh = (slots == l).astype(dot_dtype)            # [K, blk]
-        sexp = (slot_oh[:, None, :] * s[None, :, :].astype(dot_dtype))
+        hit = slots == l                                    # [K, blk]
+        if precision in _INT_STAT_DTYPES:
+            # the VPU has no narrow-int multiply (Mosaic on a v5e: "failed
+            # to legalize operation 'arith.muli'" on i8 vectors): select
+            # at 32 bits, narrow once for the MXU
+            sexp = jnp.where(hit[:, None, :],
+                             s.astype(jnp.int32)[None, :, :],
+                             0).astype(dot_dtype)
+        else:
+            sexp = (hit.astype(dot_dtype)[:, None, :]
+                    * s[None, :, :].astype(dot_dtype))
         return sexp.reshape(K * S, block)
 
     def accumulate(i, out_ref, rows, acc):
@@ -536,7 +561,7 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     # out [nb, ..., block] so each grid step's block matches the trailing
     # dims exactly; the S/leaf axes ride along whole.
     stats_nb = jnp.moveaxis(stats_blocks, 1, 0)             # [nb, S, blk]
-    interpret = jax.devices()[0].platform not in ("tpu",)
+    interpret = pallas_interpret()
     if variant == "flat":
         raw = pl.pallas_call(
             kernel_flat,
@@ -578,6 +603,21 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             if cands:
                 fblk = max(cands)
         nf = F // fblk
+        # scoped-VMEM ceiling, from the shapes: the compiler's default
+        # (16 MiB on a v5e) is under what the block-scaled temporaries
+        # need at 16384 rows (int8 there: "Scoped allocation with size
+        # 18.45M and limit 16.00M exceeded scoped vmem limit").  An upper
+        # bound, not a reservation: double-buffered in/out blocks (the
+        # [S, blk] stats and [1, blk] leaf ids each pad to one 32-byte
+        # sublane tile per row) plus the [Bp, blk] iota, compare and
+        # one-hot and the [K*S, blk] slot expansion at 32 bits and
+        # narrowed, all live at once
+        pipelined = 2 * (fblk * Bp * ks_pad * 4
+                         + fblk * bins_block * bins_t_blocks.dtype.itemsize
+                         + (32 + 32) * block)
+        temporaries = block * (Bp * (4 + 4 + jnp.dtype(dot_dtype).itemsize)
+                               + ks_pad * (4 + 4))
+        vmem_limit = pipelined + temporaries
         # grid order: the row-block axis is LAST (innermost), so each
         # feature chunk's accumulator block stays resident while the row
         # sweep accumulates into it
@@ -593,6 +633,8 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             out_specs=pl.BlockSpec((fblk * Bp, K * S),
                                    lambda fi, i: (fi, 0)),
             out_shape=jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=vmem_limit),
             interpret=interpret,
         )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
           slot_leaf_ids.reshape(K, 1))

@@ -36,25 +36,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-import inspect
-
 import jax
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # older releases ship it under jax.experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-_SM_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, **kwargs):
-    """Version-compat shard_map: newer jax renamed check_rep->check_vma;
-    translate so one spelling works against either signature."""
-    if "check_vma" in kwargs and "check_vma" not in _SM_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map_impl(f, **kwargs)
-
+from jax import shard_map
 
 import functools
 

@@ -1,2 +1,1 @@
-from .backend import pin_cpu_backend, probe_default_backend  # noqa: F401
 from .log import Log  # noqa: F401

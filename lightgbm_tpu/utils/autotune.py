@@ -139,17 +139,16 @@ def tune_entry(n_rows: int, num_features: int, num_bins: int,
 
     Synthetic operands (bucket-keyed rng) through the grower's own
     batched contraction — the same microbench tools/perf_probe.py's hist
-    sweep times — across impl x block, including the fused megakernel
-    path where the precision supports its in-kernel scan.  Returns the
-    profile entry (winning impl/block + the full measured table)."""
+    sweep times — across impl x block.  A candidate the compiler refuses
+    raises: the winner is never decided by a caught exception.  Returns
+    the profile entry (winning impl/block + the full measured table)."""
     import jax
     import jax.numpy as jnp
 
-    from ..ops.fused import fused_scan_ok, mosaic_int16_ok
-    from ..ops.histogram import (_INT_STAT_DTYPES, bench_hist_operands,
+    from ..ops.histogram import (PERFEATURE_AUTO_PRECISIONS,
+                                 bench_hist_operands,
                                  build_histogram_batched_t)
 
-    on_tpu = jax.devices()[0].platform == "tpu"
     n = min(int(n_rows), _TUNE_ROWS_CAP)
     rng = np.random.default_rng(num_features * 1_000_003 + num_bins)
     bins_np = rng.integers(
@@ -157,14 +156,13 @@ def tune_entry(n_rows: int, num_features: int, num_bins: int,
             np.uint8 if num_bins <= 256 else np.int32)
     K = split_batch
 
+    # pallas candidates run the interpreter off-TPU: slow but small n
+    # keeps a CPU tune pass tractable.  pallas2 is a candidate exactly
+    # where the auto rule offers it (learner._resolve_hist_impl): f32/f64
+    # and int16 pallas2 are explicit-only
     candidates = [("xla", 8192), ("xla", 16384)]
-    if on_tpu or jax.devices()[0].platform == "cpu":
-        # pallas candidates run the interpreter off-TPU: slow but small n
-        # keeps a CPU tune pass tractable, and the RELATIVE ranking is
-        # what load mode consumes
+    if precision in PERFEATURE_AUTO_PRECISIONS:
         candidates += [("pallas2", 4096), ("pallas2", 8192)]
-        if precision in _INT_STAT_DTYPES:
-            candidates += [("fused", 4096), ("fused", 8192)]
 
     def _fit_block(block: int) -> int:
         # datasets smaller than a candidate block still deserve a
@@ -183,33 +181,24 @@ def tune_entry(n_rows: int, num_features: int, num_bins: int,
         if n < block or (impl, block) in seen:
             continue
         seen.add((impl, block))
-        if impl == "pallas2" and precision == "int16" and on_tpu \
-                and not mosaic_int16_ok():
-            continue  # probe already warned loudly
-        if impl == "fused" and not fused_scan_ok(precision):
-            continue
-        try:
-            bins_tb, stats, n_use = bench_hist_operands(
-                bins_np, precision, block)
-            nb = n_use // block
-            leaf_b = jnp.asarray(
-                rng.integers(0, K, size=n_use).astype(np.int32)
-                .reshape(nb, block))
-            slots = jnp.arange(K, dtype=jnp.int32)
-            # graftlint: disable-next-line=J201 throwaway measurement probes on synthetic operands — deliberately off-ledger so tuning never perturbs n_programs gates
-            fn = jax.jit(lambda b, s, l, i=impl: build_histogram_batched_t(
-                b, s, l, slots, num_bins, precision, impl=i))
-            # graftlint: disable-next-line=J201 probe warm-up (see above)
-            jax.block_until_ready(fn(bins_tb, stats, leaf_b))  # compile
-            t0 = time.perf_counter()
-            for _ in range(_TUNE_REPS):
-                # graftlint: disable-next-line=J201 probe timing loop (see above)
-                jax.block_until_ready(fn(bins_tb, stats, leaf_b))
-            rps = n_use * _TUNE_REPS / max(time.perf_counter() - t0, 1e-9)
-            table[f"{impl}:{block}"] = rps
-        except Exception as exc:
-            LOG.warning("autotune candidate %s:%d failed: %s: %s", impl,
-                        block, type(exc).__name__, exc)
+        bins_tb, stats, n_use = bench_hist_operands(
+            bins_np, precision, block)
+        nb = n_use // block
+        leaf_b = jnp.asarray(
+            rng.integers(0, K, size=n_use).astype(np.int32)
+            .reshape(nb, block))
+        slots = jnp.arange(K, dtype=jnp.int32)
+        # graftlint: disable-next-line=J201 throwaway measurement probes on synthetic operands — deliberately off-ledger so tuning never perturbs n_programs gates
+        fn = jax.jit(lambda b, s, l, i=impl: build_histogram_batched_t(
+            b, s, l, slots, num_bins, precision, impl=i))
+        # graftlint: disable-next-line=J201 probe warm-up (see above)
+        jax.block_until_ready(fn(bins_tb, stats, leaf_b))  # compile
+        t0 = time.perf_counter()
+        for _ in range(_TUNE_REPS):
+            # graftlint: disable-next-line=J201 probe timing loop (see above)
+            jax.block_until_ready(fn(bins_tb, stats, leaf_b))
+        rps = n_use * _TUNE_REPS / max(time.perf_counter() - t0, 1e-9)
+        table[f"{impl}:{block}"] = rps
     if not table:
         raise RuntimeError(
             f"autotune measured no viable candidate for "

@@ -170,10 +170,7 @@ class CompileLedger:
         auto policy pays it only where HBM numbers exist to read back;
         on CPU the table carries flops/bytes from the (compile-free)
         lowered analysis and None for the memory fields."""
-        try:
-            return jax.devices()[0].platform != "cpu"
-        except Exception:  # pragma: no cover - backend init failure
-            return False
+        return jax.devices()[0].platform != "cpu"
 
     def analyze(self, memory: Optional[bool] = None) -> List[Dict]:
         """Attach each captured program's `cost_analysis()` (flops,
@@ -284,9 +281,7 @@ class LedgeredJit:
 
     New-program detection uses the jitted callable's own `_cache_size()`
     (the executable cache the jit keys on static args + avals), so the
-    ledger can never disagree with what jax actually compiled.  When
-    `_cache_size` is unavailable (older jax), every call while enabled
-    falls back to signature bookkeeping in the ledger itself.
+    ledger can never disagree with what jax actually compiled.
     """
 
     def __init__(self, fn, site: Optional[str] = None, **jit_kwargs):
@@ -300,19 +295,12 @@ class LedgeredJit:
         self._static_argnums = _as_tuple(jit_kwargs.get("static_argnums"))
         self._static_argnames = _as_tuple(
             jit_kwargs.get("static_argnames"))
-        self._seen_sigs = set()
         # serializes the (cache-size, call, cache-size) window while the
         # ledger is ENABLED: without it, a thread's cache-hit call that
         # overlaps another thread's compile observes the cache growing
         # and double-records the program.  The disabled path (default,
         # production serving) never touches the lock.
         self._lock = threading.Lock()
-
-    def _cache_len(self) -> Optional[int]:
-        try:
-            return int(self._fn._cache_size())
-        except Exception:
-            return None
 
     def _capture_specs(self, args, kwargs):
         """Re-lowerable specs of one call, built only on the RARE
@@ -333,18 +321,10 @@ class LedgeredJit:
         if not LEDGER.enabled:
             return self._fn(*args, **kwargs)
         with self._lock:
-            before = self._cache_len()
+            before = self._fn._cache_size()
             t0 = time.perf_counter()
             out = self._fn(*args, **kwargs)
-            after = self._cache_len()
-            if before is None:
-                sig = call_signature(args, kwargs)
-                if sig not in self._seen_sigs:
-                    self._seen_sigs.add(sig)
-                    LEDGER.record(self.site, sig,
-                                  time.perf_counter() - t0,
-                                  aot=self._capture_specs(args, kwargs))
-            elif after is not None and after > before:
+            if self._fn._cache_size() > before:
                 LEDGER.record(self.site, call_signature(args, kwargs),
                               time.perf_counter() - t0,
                               aot=self._capture_specs(args, kwargs))

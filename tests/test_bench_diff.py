@@ -1,8 +1,7 @@
 """Perf-regression sentinel (ISSUE 12): tools/bench_diff.py.
 
 Exit-code contract: 0 = comparable + clean, 1 = regression, 2 =
-refused (cross-backend / degraded / crash record — the comparisons the
-r04->r05 postmortem proved are fiction), 3 = usage error.  Plus the
+refused (cross-backend / degraded / crash record), 3 = usage error.  Plus the
 blackbox overlay mode of tools/trace_merge.py (who hung first).
 """
 
@@ -141,8 +140,6 @@ class TestRefusal:
         code, text = bd.run(old_path=a,
                             new_path=str(tmp_path / "missing.json"))
         assert code == bd.EXIT_ERROR and "cannot read" in text
-        code, _ = bd.run(head=str(tmp_path / "missing.json"))
-        assert code == bd.EXIT_ERROR
         bad = tmp_path / "bad.json"
         bad.write_text("{not json")
         assert bd.run(old_path=a, new_path=str(bad))[0] == bd.EXIT_ERROR
@@ -155,31 +152,27 @@ class TestRefusal:
         code, text = bd.run(old_path=a, new_path=b)
         assert code == bd.EXIT_REFUSED and "CRASH" in text
 
-    def test_committed_rounds_refuse_by_default(self):
-        """The repo's own newest rounds (r04/r05) are degraded CPU
-        runs: the default committed-vs-committed diff must refuse —
-        exactly the honest verdict the r04->r05 postmortem reached by
-        hand."""
-        code, text = bd.run()
-        assert code == bd.EXIT_REFUSED
+    def test_round_wrapper_records_refuse_when_degraded(self, tmp_path):
+        """A driver round file wraps the bench line ({'rc', 'parsed'}):
+        the wrapper is unwrapped, and two degraded cpu rounds refuse by
+        default and diff under --allow-degraded."""
+        deg = _rec(backend="cpu", degraded=True)
+        a = _write(tmp_path, "r04.json", {"rc": 0, "parsed": deg})
+        b = _write(tmp_path, "r05.json",
+                   {"rc": 0, "parsed": {**deg, "value": 1.01}})
+        code, text = bd.run(old_path=a, new_path=b)
+        assert code == bd.EXIT_REFUSED and "degraded" in text
+        code, text = bd.run(old_path=a, new_path=b, allow_degraded=True)
+        assert code == bd.EXIT_OK          # within tolerance: clean
 
-
-class TestHeadMode:
-    def test_head_vs_newest_committed(self, tmp_path):
-        """--head compares a fresh record against the newest committed
-        round (r05: degraded cpu), so a matching degraded-cpu HEAD
-        refuses by default and diffs under --allow-degraded."""
-        committed = bd.committed_records()
-        assert committed, "repo has committed BENCH rounds"
-        newest = committed[0][1]
-        head = _write(tmp_path, "head.json", {
-            **{k: v for k, v in newest.items()
-               if isinstance(v, (int, float, str, bool))},
-        })
-        code, _ = bd.run(head=head)
-        assert code == bd.EXIT_REFUSED     # r05 is degraded
-        code, text = bd.run(head=head, allow_degraded=True)
-        assert code == bd.EXIT_OK          # identical record: clean
+    def test_crashed_round_wrapper_refused(self, tmp_path):
+        """A round that crashed before printing ({'rc': 1, 'parsed':
+        null}) is a crash record, refused loudly — never an IO error and
+        never silently skipped."""
+        a = _write(tmp_path, "r01.json", {"rc": 1, "parsed": None})
+        b = _write(tmp_path, "r02.json", {"rc": 0, "parsed": _rec()})
+        code, text = bd.run(old_path=a, new_path=b)
+        assert code == bd.EXIT_REFUSED and "CRASH" in text
 
 
 class TestCLI:

@@ -39,8 +39,7 @@ from lightgbm_tpu.booster import Booster
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.models.learner import TPUTreeLearner
 from lightgbm_tpu.ops import split as SP
-from lightgbm_tpu.ops.fused import (fused_hist_scan, fused_scan_ok,
-                                    fused_supported, mosaic_int16_ok)
+from lightgbm_tpu.ops.fused import fused_hist_scan, fused_supported
 from lightgbm_tpu.ops.histogram import (bench_hist_operands,
                                         build_histogram_batched_t)
 from lightgbm_tpu.utils import autotune, faultline, membudget
@@ -197,12 +196,25 @@ class TestDeviceRecordsOracle:
             np.testing.assert_array_equal(np.asarray(back.threshold),
                                           np.asarray(pf.threshold))
 
-    def test_validation_probes_pass_here(self):
-        # trivially exact on CPU interpret; true Mosaic checks on TPU.
-        # auto's loud-fallback contract rides on these two.
-        assert mosaic_int16_ok() is True
-        for prec in PRECS:
-            assert fused_scan_ok(prec) is True
+    def test_auto_is_a_rule_never_a_probe(self, monkeypatch):
+        # auto resolves from (platform, precision) alone: no kernel runs
+        # to decide it, fused is explicit-only, int16 stays on xla
+        cfg = Config({"objective": "binary", "num_leaves": 255})
+
+        def resolve(prec):
+            return TPUTreeLearner._resolve_hist_impl(cfg, 255, prec)[0]
+
+        for prec in ("hilo", "int8", "int16"):
+            assert resolve(prec) == "xla"            # cpu
+
+        class _Tpu:
+            platform = "tpu"
+
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Tpu()])
+        assert resolve("hilo") == "pallas2"
+        assert resolve("int8") == "pallas2"
+        assert resolve("int16") == "xla"
+        assert resolve("f32") == "xla"
 
 
 # ---------------------------------------------------------------------------

@@ -56,14 +56,11 @@ class TestMultihostMapping:
 
 
 _WORKER_SRC = """
-import os, sys, importlib.util
+import os, sys
 root = {root!r}
 sys.path.insert(0, root)
-spec = importlib.util.spec_from_file_location(
-    "_boot", os.path.join(root, "lightgbm_tpu", "utils", "backend.py"))
-_b = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(_b)
-_b.pin_cpu_backend(force_device_count=4)
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 import numpy as np

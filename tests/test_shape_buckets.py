@@ -92,14 +92,9 @@ class TestBucketShapes:
 
 
 _CACHE_WORKER = """
-import os, sys, time, importlib.util
+import os, sys, time
 root = {root!r}
 sys.path.insert(0, root)
-spec = importlib.util.spec_from_file_location(
-    "_boot", os.path.join(root, "lightgbm_tpu", "utils", "backend.py"))
-_b = importlib.util.module_from_spec(spec)
-spec.loader.exec_module(_b)
-_b.pin_cpu_backend()
 import numpy as np
 import lightgbm_tpu as lgb
 
@@ -128,7 +123,7 @@ class TestPersistentCacheReuse:
         cold time."""
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         cache = tmp_path / "fake_jax_cache"
-        env = dict(os.environ, LIGHTGBM_TPU_CACHE_DIR=str(cache))
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache))
         env.pop("XLA_FLAGS", None)
 
         def run(n):

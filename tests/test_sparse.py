@@ -156,8 +156,6 @@ class TestBoschShapedMemory:
         code = textwrap.dedent("""
             import resource, sys
             sys.path.insert(0, %r)
-            from lightgbm_tpu.utils.backend import pin_cpu_backend
-            pin_cpu_backend()
             import numpy as np
             from scipy import sparse as sps
             from lightgbm_tpu.config import Config

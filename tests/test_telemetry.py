@@ -370,14 +370,14 @@ class TestOffOverhead:
         X, y = _problem(n=1500, f=6, seed=3)
         ds = lgb.Dataset(X, label=y, params=_P)
         bst = lgb.Booster(params=dict(_P), train_set=ds)
-        from lightgbm_tpu.utils.backend import host_sync
+        import jax
 
         bst.update()  # compile + warm
-        host_sync(bst._driver.train_scores.scores)
+        jax.block_until_ready(bst._driver.train_scores.scores)
         t0 = time.perf_counter()
         for _ in range(self.N_ITERS):
             bst.update()
-        host_sync(bst._driver.train_scores.scores)
+        jax.block_until_ready(bst._driver.train_scores.scores)
         return time.perf_counter() - t0
 
     def test_off_mode_regression_under_1pct(self, monkeypatch):

@@ -1,15 +1,7 @@
 """Perf-regression sentinel: compare two bench records metric-by-metric.
 
-The bench trajectory was untrustworthy for three rounds (every round
-since r02 ran on degraded CPU fallback) and nothing refused the
-apples-to-oranges comparisons — the r04→r05 "regression" cost a
-postmortem to diagnose as container variance.  This tool is the gate
-that replaces the ad-hoc ``compile_vs_prior`` note:
-
-    python tools/bench_diff.py                      # newest two committed
-    python tools/bench_diff.py A.json B.json        # explicit old vs new
-    python tools/bench_diff.py --head NEW.json      # newest committed vs NEW
-    python tools/bench_diff.py --gate [...]         # exit nonzero on fail
+    python tools/bench_diff.py OLD.json NEW.json    # explicit old vs new
+    python tools/bench_diff.py --gate OLD.json NEW.json   # same, CI intent
 
 Semantics:
 
@@ -17,24 +9,20 @@ Semantics:
   lower-better walls/overheads) and a relative TOLERANCE — a metric
   outside tolerance in the bad direction is a regression;
 * comparisons are REFUSED (exit 2, loud message) when the two records
-  ran on different backends, when either side is a degraded run, or
-  when either side is a crash record — a TPU-vs-degraded-CPU ratio is
-  fiction and the tool says so instead of printing it;
+  ran on different backends, when either side is marked degraded, or
+  when either side is a crash record — a TPU-vs-CPU ratio is fiction
+  and the tool says so instead of printing it;
 * ``--allow-degraded`` permits same-backend degraded-vs-degraded
-  comparisons (informational runs on the CPU container);
+  comparisons (informational);
 * exit codes: 0 = comparable + no regression, 1 = regression,
   2 = refused, 3 = usage/IO error.  ``--gate`` is an alias that makes
-  the intent explicit where the dryrun tail wires it in.
+  the intent explicit where a nonzero exit must fail a run.
 """
 
 import argparse
-import glob
 import json
 import os
-import re
 import sys
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 EXIT_OK = 0
 EXIT_REGRESSION = 1
@@ -86,13 +74,9 @@ METRICS = {
     # (bitwise models, bounded programs) live in tests/test_stream.py
     "stream_rows_per_sec": (+1, 0.35),
     "stream_overlap_pct": (+1, 0.50),
-    # fused frontier growth (ISSUE 18): per-iteration grow wall, the
-    # grow-megakernel probe throughput, and the steady-state autotune
-    # profile load+resolve cost.  The bitwise and program-count
-    # guarantees live in tests/test_fused_grow.py; these rows track the
-    # speed the fusion exists for
+    # per-iteration grow wall and the steady-state autotune profile
+    # load+resolve cost (ISSUE 18)
     "grow_iter_ms": (-1, 0.30),
-    "fused_frontier_rows_per_sec": (+1, 0.30),
     "autotune_resolve_ms": (-1, 0.50),
     # fleet serving (ISSUE 19): replicated-dispatch goodput across the
     # device set, cold-replica time-to-first-batch (AOT deserialization
@@ -121,28 +105,12 @@ def load_record(path):
     parsed = rec.get("parsed", rec)
     if not isinstance(parsed, dict):
         if "parsed" in rec:
-            # a committed crash wrapper ({'rc': 1, 'parsed': null},
-            # e.g. BENCH_r01): keep it as a record so refusal() fires
-            # LOUDLY on it — silently dropping the newest round and
-            # diffing two older ones would report 'no regressions'
-            # right after a round crashed
+            # a crash wrapper ({'rc': 1, 'parsed': null}): keep it as a
+            # record so refusal() fires LOUDLY on it
             return {"error": f"crashed round (rc={rec.get('rc')}, "
                              "parsed=null)"}
         raise RecordError(f"bench_diff: {path!r} holds no record dict")
     return parsed
-
-
-def committed_records():
-    """Newest-first [(name, parsed record)] of the committed BENCH_r*."""
-    files = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")),
-                   key=lambda p: [int(s) for s in re.findall(r"\d+", p)])
-    out = []
-    for path in reversed(files):
-        try:
-            out.append((os.path.basename(path), load_record(path)))
-        except RecordError:
-            continue
-    return out
 
 
 def _backend(rec):
@@ -216,30 +184,13 @@ def format_table(rows, old_name, new_name):
     return "\n".join(lines)
 
 
-def run(old_path=None, new_path=None, head=None, allow_degraded=False,
-        tolerance_scale=1.0):
+def run(old_path, new_path, allow_degraded=False, tolerance_scale=1.0):
     """-> (exit_code, text).  The CLI and the dryrun tail both call
     this; the dryrun treats EXIT_REFUSED as a loud skip, never a
     pass."""
     try:
-        if head is not None:
-            committed = committed_records()
-            if not committed:
-                return EXIT_ERROR, "bench_diff: no committed BENCH_r*.json"
-            old_name, old = committed[0]
-            new_name, new = os.path.basename(head), load_record(head)
-        elif old_path is not None and new_path is not None:
-            old_name, old = os.path.basename(old_path), \
-                load_record(old_path)
-            new_name, new = os.path.basename(new_path), \
-                load_record(new_path)
-        else:
-            committed = committed_records()
-            if len(committed) < 2:
-                return EXIT_ERROR, ("bench_diff: need two committed "
-                                    "BENCH_r*.json (or explicit paths)")
-            new_name, new = committed[0]
-            old_name, old = committed[1]
+        old_name, old = os.path.basename(old_path), load_record(old_path)
+        new_name, new = os.path.basename(new_path), load_record(new_path)
     except RecordError as exc:
         return EXIT_ERROR, str(exc)
     reason = refusal(old, new, allow_degraded=allow_degraded)
@@ -262,12 +213,7 @@ def run(old_path=None, new_path=None, head=None, allow_degraded=False,
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("paths", nargs="*",
-                    help="OLD.json NEW.json (default: the two newest "
-                         "committed BENCH_r*.json)")
-    ap.add_argument("--head", default=None, metavar="NEW.json",
-                    help="compare the newest committed record against "
-                         "this fresh (HEAD) record")
+    ap.add_argument("paths", nargs=2, metavar=("OLD.json", "NEW.json"))
     ap.add_argument("--gate", action="store_true",
                     help="CI intent marker: identical behavior, spelled "
                          "out where a nonzero exit must fail the run")
@@ -278,11 +224,7 @@ def main(argv=None):
                     help="scale every per-metric tolerance (2.0 = twice "
                          "as lenient)")
     args = ap.parse_args(argv)
-    if args.paths and len(args.paths) != 2:
-        ap.error("pass exactly two record paths (OLD NEW), or none")
-    old_path, new_path = (args.paths if args.paths else (None, None))
-    code, text = run(old_path=old_path, new_path=new_path, head=args.head,
-                     allow_degraded=args.allow_degraded,
+    code, text = run(*args.paths, allow_degraded=args.allow_degraded,
                      tolerance_scale=args.tolerance_scale)
     print(text, file=sys.stderr if code in (EXIT_REFUSED, EXIT_ERROR)
           else sys.stdout)

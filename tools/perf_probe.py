@@ -56,7 +56,7 @@ def _exc_inline(exc, limit=400):
 def run_one(X, y, k, block, impl, iters=8, leaves=255, bins=255,
             partition="select", precision="hilo", ramp=False, alpha=0.0):
     import lightgbm_tpu as lgb
-    from lightgbm_tpu.utils.backend import host_sync
+    import jax
     from sklearn.metrics import roc_auc_score
 
     # bin once per (data, label, bins): sweep iterations reuse the Dataset
@@ -77,12 +77,12 @@ def run_one(X, y, k, block, impl, iters=8, leaves=255, bins=255,
         "tpu_ramp": ramp}, train_set=ds)
     t0 = time.time()
     bst.update()
-    host_sync(bst._driver.train_scores.scores)
+    jax.block_until_ready(bst._driver.train_scores.scores)
     compile_s = time.time() - t0
     t0 = time.time()
     for _ in range(iters):
         bst.update()
-    host_sync(bst._driver.train_scores.scores)
+    jax.block_until_ready(bst._driver.train_scores.scores)
     ms = (time.time() - t0) / iters * 1e3
     auc = roc_auc_score(y, bst.predict(X, raw_score=True))
     return ms, compile_s, auc
@@ -158,7 +158,7 @@ def run_predict_sweep(X, y, rounds=50, leaves=255, bins=255):
     # incremental device tree-score pass + materialize + metric fetch)
     # against plain update iterations — a post-training eval_valid()
     # would only time the score fetch
-    from lightgbm_tpu.utils.backend import host_sync
+    import jax
 
     def train_loop(with_eval, iters=3):
         t = time.time()
@@ -167,7 +167,7 @@ def run_predict_sweep(X, y, rounds=50, leaves=255, bins=255):
             if with_eval:
                 bst.eval_valid()
         bst._driver._materialize()
-        host_sync(bst._driver.train_scores.scores)
+        jax.block_until_ready(bst._driver.train_scores.scores)
         return (time.time() - t) / iters
 
     n_eval = min(50_000, n)
@@ -202,7 +202,6 @@ def run_hist_sweep(X, y, bins=255, reps=4):
     from lightgbm_tpu.models.learner import TPUTreeLearner
     from lightgbm_tpu.ops.histogram import (bench_hist_operands,
                                             build_histogram_batched_t)
-    from lightgbm_tpu.utils.backend import host_sync
 
     on_tpu = jax.devices()[0].platform == "tpu"
     ds = lgb.Dataset(X, label=y, params={"max_bin": bins})
@@ -230,10 +229,10 @@ def run_hist_sweep(X, y, bins=255, reps=4):
         slots = jnp.arange(K, dtype=jnp.int32)
         fn = jax.jit(lambda b, s, l: build_histogram_batched_t(
             b, s, l, slots, B, precision, impl=impl))
-        host_sync(fn(bins_tb, stats, leaf_b))  # compile
+        jax.block_until_ready(fn(bins_tb, stats, leaf_b))  # compile
         t0 = time.time()
         for _ in range(reps):
-            host_sync(fn(bins_tb, stats, leaf_b))
+            jax.block_until_ready(fn(bins_tb, stats, leaf_b))
         return n_use * reps / max(time.time() - t0, 1e-9), n_use
 
     blocks = {"xla": (8192, 16384), "pallas": (256,),
@@ -251,9 +250,9 @@ def run_hist_sweep(X, y, bins=255, reps=4):
 
     # ---- frontier step (hist + split scan): the fused megakernel next
     # to the exact unfused composition it replaces (perfeature hist +
-    # the vmapped 2K-child per-feature scan).  This is the acceptance
-    # microbench for tpu_hist_impl=fused: auto only claims fused on a
-    # backend where the fused rows beat the best unfused ones here ----
+    # the vmapped 2K-child per-feature scan).  tpu_hist_impl=fused is
+    # explicit-only; a backend whose compiler refuses it prints FAILED
+    # with the compiler's message ----
     def one_frontier(precision, impl, block):
         from lightgbm_tpu.ops import fused as FU
         from lightgbm_tpu.ops import split as SP
@@ -305,8 +304,6 @@ def run_hist_sweep(X, y, bins=255, reps=4):
                         acc_scale=ctx[C, :3], **kw)
                 return hist, jax.vmap(child)(jnp.arange(C))
             fn = jax.jit(unfused)
-        # block_until_ready, not host_sync: both variants return a
-        # (hist, records/pf) pytree, not a single array
         jax.block_until_ready(fn(bins_tb, stats, leaf_b))  # compile
         t0 = time.time()
         for _ in range(reps):
@@ -438,7 +435,7 @@ def run_comm_sweep(shard_counts, reps=10, host_counts=(1,)):
 
     from lightgbm_tpu.parallel.mesh import (tiered_allreduce_recv_bytes,
                                             tiered_reduce_scatter_recv_bytes)
-    from lightgbm_tpu.parallel.strategies import shard_map
+    from jax import shard_map
     from lightgbm_tpu.parallel.topology import (ROW_AXES, axis_psum,
                                                 axis_psum_scatter,
                                                 make_topology)
@@ -529,7 +526,7 @@ def run_retrace(n=20000, f=10, leaves=31, bins=63, iters=3):
     import lightgbm_tpu as lgb
     from lightgbm_tpu.booster import Booster
     from lightgbm_tpu.serving import ServingSession
-    from lightgbm_tpu.utils.backend import host_sync
+    import jax
     from lightgbm_tpu.utils.compile_ledger import LEDGER
 
     X, y = make_data(n, f=f)
@@ -546,7 +543,7 @@ def run_retrace(n=20000, f=10, leaves=31, bins=63, iters=3):
     bst = Booster(params=p, train_set=ds)
     for _ in range(iters):
         bst.update()
-    host_sync(bst._driver.train_scores.scores)
+    jax.block_until_ready(bst._driver.train_scores.scores)
     phase(f"ingest + train ({iters} iters)")
 
     # the retrace-elimination contract: an identical second training run
@@ -556,7 +553,7 @@ def run_retrace(n=20000, f=10, leaves=31, bins=63, iters=3):
     bst2 = Booster(params=p, train_set=ds2)
     for _ in range(iters):
         bst2.update()
-    host_sync(bst2._driver.train_scores.scores)
+    jax.block_until_ready(bst2._driver.train_scores.scores)
     phase("second identical train")
 
     for sz in (1, 100, 4096, min(n, 20000)):
@@ -607,7 +604,6 @@ def run_trace(n=100_000, iters=3, leaves=255, bins=255):
     import jax
     import lightgbm_tpu as lgb
     from lightgbm_tpu import obs
-    from lightgbm_tpu.utils.backend import host_sync
 
     import shutil
 
@@ -627,7 +623,7 @@ def run_trace(n=100_000, iters=3, leaves=255, bins=255):
         **json.loads(os.environ.get("EXTRA", "{}"))}, train_set=ds)
     for _ in range(2):  # compile + warm
         bst.update()
-    host_sync(bst._driver.train_scores.scores)
+    jax.block_until_ready(bst._driver.train_scores.scores)
     obs.reset_events()  # profile the WARM loop, not the compile tail
 
     xprof = os.environ.get("XPROF", "") not in ("", "0")
@@ -636,7 +632,7 @@ def run_trace(n=100_000, iters=3, leaves=255, bins=255):
     t0 = time.time()
     for _ in range(iters):
         bst.update()
-    host_sync(bst._driver.train_scores.scores)
+    jax.block_until_ready(bst._driver.train_scores.scores)
     wall = time.time() - t0
     if xprof:
         jax.profiler.stop_trace()
@@ -704,7 +700,6 @@ def run_mem(n=20000, f=10, leaves=31, bins=63, iters=3):
     from lightgbm_tpu.booster import Booster
     from lightgbm_tpu.obs import resources
     from lightgbm_tpu.serving import ServingSession
-    from lightgbm_tpu.utils.backend import host_sync
     from lightgbm_tpu.utils.compile_ledger import LEDGER
 
     obs.configure(mode="metrics")        # arm the phase watermarks
@@ -720,7 +715,7 @@ def run_mem(n=20000, f=10, leaves=31, bins=63, iters=3):
     bst = Booster(params=p, train_set=ds)
     for _ in range(iters):
         bst.update()
-    host_sync(bst._driver.train_scores.scores)
+    jax.block_until_ready(bst._driver.train_scores.scores)
     bst.predict(X[:4096], raw_score=True, device="tpu",
                 tpu_predict_device="true")
     sess = ServingSession(params={"serving_max_batch_rows": 1024,
@@ -1233,24 +1228,14 @@ def main():
                   bins=int(os.environ.get("BINS", 255)))
         return
     if arg == "comm":
-        # no dataset needed.  Default: a virtual CPU mesh sized to the
-        # sweep (must pin BEFORE the first jax import); COMM_BACKEND=tpu
-        # keeps the attached accelerator mesh for real ICI numbers
+        # no dataset needed.  Runs on the devices jax has: the chips of
+        # the host for real ICI numbers, or — exported by the caller —
+        # JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_
+        # count=8 for a virtual mesh (byte counts only, no timing claim)
         shard_counts = [int(s) for s in
                         os.environ.get("SHARDS", "2,4,8").split(",")]
         host_counts = [int(s) for s in
                        os.environ.get("HOSTS", "1").split(",")]
-        if os.environ.get("COMM_BACKEND", "cpu") != "tpu":
-            import importlib.util as _ilu
-
-            spec = _ilu.spec_from_file_location(
-                "_lgbm_backend_boot",
-                os.path.join(os.path.dirname(os.path.dirname(
-                    os.path.abspath(__file__))),
-                    "lightgbm_tpu", "utils", "backend.py"))
-            mod = _ilu.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            mod.pin_cpu_backend(force_device_count=max(shard_counts))
         run_comm_sweep(shard_counts, host_counts=host_counts)
         return
     if arg == "tune":
