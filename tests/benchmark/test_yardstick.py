@@ -1,0 +1,295 @@
+"""The parts of the yardstick that need no trace: operation counts, peaks,
+timing arithmetic, the generator, the plain reference, the forest tiling."""
+
+import numpy as np
+import pytest
+
+from benchmarks.lib import forest, opcount, peaks, reference, timing
+from benchmarks.lib.harness import BENCH_DIR, load_module
+
+
+# ---- operations and bytes -------------------------------------------------------
+@pytest.mark.parametrize("features, want_ops, want_bytes", [
+    # 1,000 rows, 255 bins, 25 slots x 5 bf16 planes, one byte per bin:
+    # 2 * 1000 * F * 255 * 125 multiply-adds counted as two operations;
+    # per row F bins + 5 * 2 bytes of statistics + 4 of leaf id, and the
+    # float32 accumulator F * 255 * 125 * 4 written once
+    (28, 1_785_000_000, 1000 * (28 + 10 + 4) + 28 * 255 * 125 * 4),
+    (136, 8_670_000_000, 1000 * (136 + 10 + 4) + 136 * 255 * 125 * 4),
+])
+def test_hist_contraction_against_hand_counts(features, want_ops, want_bytes):
+    assert opcount.hist_contraction(1000, features, 255, slots=25,
+                                    planes=5) == (want_ops, want_bytes)
+
+
+def test_hist_contraction_int8_planes():
+    ops, byts = opcount.hist_contraction(8, 2, 4, slots=1, planes=3,
+                                         stat_bytes=1)
+    assert ops == 2 * 8 * 2 * 4 * 3
+    assert byts == 8 * (2 + 3 + 4) + 2 * 4 * 3 * 4
+
+
+def test_forest_walk_against_hand_counts():
+    ops, byts = opcount.forest_walk(rows=10, trees=3, depth=4, features=28)
+    assert ops == 10 * 3 * 4 * 4
+    assert byts == 10 * 3 * 4 * 24 + 10 * 28 * 4 + 10 * 4
+
+
+@pytest.mark.parametrize("ops, byts, seconds, want_share, want_bound", [
+    (197e12, 1.0, 2.0, 50.0, "compute"),     # one second of MXU in two
+    (1.0, 819e9, 4.0, 25.0, "memory"),       # one second of HBM in four
+])
+def test_roofline(ops, byts, seconds, want_share, want_bound):
+    p = peaks.peaks_for("TPU v5 lite")
+    share, bound = opcount.roofline(ops, byts, seconds, p["bf16_flops"],
+                                    p["hbm_bytes_per_s"])
+    assert share == pytest.approx(want_share) and bound == want_bound
+
+
+def test_an_unknown_device_kind_is_an_error():
+    assert peaks.peaks_for("TPU v5 lite")["int8_ops"] == 393e12
+    with pytest.raises(KeyError, match="TPU v5p"):
+        peaks.peaks_for("TPU v5p")
+
+
+# ---- the wait for the device ------------------------------------------------------------
+def test_sync_skips_a_donated_array_and_raises_what_else_fails(monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.lib import device
+
+    gone = jnp.ones(3)
+    gone.delete()
+    monkeypatch.setattr(jax, "live_arrays", lambda: [gone, jnp.ones(3)])
+    device.sync()
+
+    class Broken:
+        def is_deleted(self):
+            return False
+
+        def block_until_ready(self):
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    monkeypatch.setattr(jax, "live_arrays", lambda: [Broken()])
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        device.sync()
+
+
+# ---- timing ---------------------------------------------------------------------------
+def test_window_runs_whole_steps_past_the_deadline():
+    calls = []
+    walls, elapsed = timing.run_window(lambda: calls.append(1), 0.05)
+    assert len(walls) == len(calls) >= 1
+    assert 0.05 <= elapsed < 0.5 and sum(walls) <= elapsed
+
+
+def test_a_step_can_end_its_window():
+    calls = []
+
+    def step():
+        calls.append(1)
+        return timing.STOP if len(calls) == 3 else None
+
+    walls, elapsed = timing.run_window(step, 60.0)
+    assert len(walls) == len(calls) == 3 and elapsed < 1.0
+
+
+def test_summary_quartiles():
+    s = timing.summary([1.0, 2.0, 3.0, 4.0, 5.0])
+    assert (s["n"], s["median"], s["q1"], s["q3"]) == (5, 3.0, 2.0, 4.0)
+    assert timing.summary([]) == {"n": 0}
+
+
+# ---- the generator ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def higgs_like():
+    return load_module(BENCH_DIR, "datagen", "higgs_like")
+
+
+def test_rows_are_a_function_of_seed_stream_and_position(higgs_like):
+    spec = {"features": 28}
+    a = higgs_like.make(spec, 5, 3000, stream=0)
+    b = higgs_like.make(spec, 5, 3000, stream=0)
+    assert np.array_equal(a["X"], b["X"]) and np.array_equal(a["y"], b["y"])
+    assert a["X"].shape == (3000, 28) and a["X"].dtype == np.float64
+    assert set(np.unique(a["y"])) == {0.0, 1.0}
+    other_seed = higgs_like.make(spec, 6, 3000, stream=0)
+    other_stream = higgs_like.make(spec, 5, 3000, stream=1)
+    assert not np.array_equal(a["X"], other_seed["X"])
+    assert not np.array_equal(a["X"], other_stream["X"])
+    # a longer table of the same seed starts with the same rows
+    longer = higgs_like.make(spec, 5, 5000, stream=0)
+    assert np.array_equal(longer["X"][:3000], a["X"])
+
+
+def test_rows_do_not_depend_on_the_thread_count(higgs_like, monkeypatch):
+    spec = {"features": 6}
+    monkeypatch.setattr(higgs_like, "SLAB_ROWS", 1000)
+    many = higgs_like.make(spec, 9, 4500, stream=0)
+    monkeypatch.setattr(higgs_like, "THREADS", 1)
+    one = higgs_like.make(spec, 9, 4500, stream=0)
+    assert np.array_equal(many["X"], one["X"])
+    assert np.array_equal(many["y"], one["y"])
+
+
+def test_labels_follow_the_rule_of_the_seed(higgs_like):
+    d = higgs_like.make({"features": 28}, 3, 200_000, stream=0)
+    X, y = d["X"], d["y"]
+    assert 0.45 < y.mean() < 0.55
+    # the squares of the first eight features carry signal, the later do not
+    first = np.corrcoef((X[:, :8] ** 2).sum(axis=1), y)[0, 1]
+    later = np.corrcoef((X[:, 8:16] ** 2).sum(axis=1), y)[0, 1]
+    assert first > 0.2 and abs(later) < 0.02
+
+
+# ---- the plain reference ------------------------------------------------------------------
+def hand_tree():
+    """Three leaves: x0 <= 0.5 -> leaf 0; else x1 <= -1 -> leaf 1, else 2.
+    Node 1 sends missing (NaN) left; node 0 has no missing rule."""
+    return {"num_leaves": 3, "num_cat": 0,
+            "split_feature": np.array([0, 1]),
+            "threshold": np.array([0.5, -1.0]),
+            "decision_type": np.array([0, 2 | (2 << 2)]),
+            "left_child": np.array([-1, -2]),
+            "right_child": np.array([1, -3]),
+            "leaf_value": np.array([0.1, 0.2, 0.4]),
+            "leaf_count": np.array([2, 2, 1])}
+
+
+HAND_ROWS = np.array([[0.5, 9.0],        # x0 <= 0.5            -> leaf 0
+                      [np.nan, 9.0],     # NaN counts as 0 here -> leaf 0
+                      [0.6, -1.0],       # x1 <= -1             -> leaf 1
+                      [0.6, np.nan],     # NaN goes left here   -> leaf 1
+                      [0.6, -0.9]])      #                      -> leaf 2
+
+
+def test_walker_follows_the_published_decision_rule():
+    tree = hand_tree()
+    assert reference.leaf_index(tree, HAND_ROWS).tolist() == [0, 0, 1, 1, 2]
+    assert reference.leaf_index_threaded(tree, HAND_ROWS, threads=2).tolist() \
+        == [0, 0, 1, 1, 2]
+    got = reference.walk([tree, tree], HAND_ROWS)
+    assert got == pytest.approx([0.2, 0.2, 0.4, 0.4, 0.8])
+
+
+def test_zero_as_missing_takes_the_default_side():
+    tree = hand_tree()
+    tree["decision_type"] = np.array([1 << 2, 0])   # zero is missing, go right
+    rows = np.array([[0.0, 0.0], [1e-36, 0.0], [-0.5, 0.0]])
+    assert reference.leaf_index(tree, rows).tolist() == [2, 2, 0]
+
+
+def test_a_stump_of_one_leaf():
+    stump = {"num_leaves": 1, "leaf_value": np.array([0.7])}
+    assert reference.walk([stump], np.zeros((3, 2))) == pytest.approx([0.7] * 3)
+
+
+def test_first_tree_recount():
+    # 10 rows, 4 positive: p = 0.4.  Leaf 0 holds 6 rows with 1 positive,
+    # leaf 1 holds 4 rows with 3.  value = logit(p) - lr*(n*p - pos)/(n*p*(1-p))
+    y = np.array([1, 0, 0, 0, 0, 0, 1, 1, 1, 0], float)
+    leaf = np.array([0] * 6 + [1] * 4)
+    logit = np.log(0.4 / 0.6)
+    values = np.array([logit - 0.1 * (2.4 - 1) / (6 * 0.24),
+                       logit - 0.1 * (1.6 - 3) / (4 * 0.24)])
+    tree = {"num_leaves": 2, "leaf_value": values,
+            "leaf_count": np.array([6, 4])}
+    off, err, _ = reference.recount_first_tree(tree, leaf, y, 0.1)
+    assert off == 0 and err < 1e-12
+    tree["leaf_value"] = values + np.array([0.0, 0.01])
+    off, err, worst = reference.recount_first_tree(tree, leaf, y, 0.1)
+    assert off == 0 and err == pytest.approx(0.01) and worst == 1
+    tree["leaf_count"] = np.array([4, 6])
+    assert reference.recount_first_tree(tree, leaf, y, 0.1)[0] == 2
+
+
+@pytest.mark.parametrize("score, y, want", [
+    ([0.1, 0.4, 0.35, 0.8], [0, 0, 1, 1], 0.75),    # one of four pairs wrong
+    ([1, 2, 3, 4], [0, 0, 1, 1], 1.0),
+    ([4, 3, 2, 1], [0, 0, 1, 1], 0.0),
+    ([0.5, 0.5, 0.5, 0.5], [0, 1, 0, 1], 0.5),      # all tied
+    ([0.2, 0.5, 0.5, 0.9], [0, 0, 1, 1], 0.875),    # one tie counts a half
+])
+def test_auc(score, y, want):
+    assert reference.auc(score, y) == pytest.approx(want)
+
+
+def test_auc_needs_both_classes():
+    with pytest.raises(ValueError):
+        reference.auc([0.1, 0.2], [1, 1])
+
+
+# ---- model text: parse and tile ------------------------------------------------------------
+MODEL_TEXT = """tree
+version=v3
+num_class=1
+max_feature_idx=1
+feature_names=Column_0 Column_1
+tree_sizes=1 2
+
+Tree=0
+num_leaves=3
+num_cat=0
+split_feature=0 1
+threshold=0.5 -1
+decision_type=0 10
+left_child=-1 -2
+right_child=1 -3
+leaf_value=0.1 0.2 0.4
+leaf_count=2 2 1
+shrinkage=0.1
+
+
+Tree=1
+num_leaves=2
+num_cat=0
+split_feature=1
+threshold=0
+decision_type=2
+left_child=-1
+right_child=-2
+leaf_value=-1 1
+leaf_count=3 2
+shrinkage=0.1
+
+
+end of trees
+
+parameters:
+[num_leaves: 3]
+end of parameters
+tpu_bin_mappers:{"kept": "as it was"}
+"""
+
+
+def test_model_text_parses_to_the_trees_it_states():
+    trees = reference.parse_model(MODEL_TEXT)
+    assert [t["num_leaves"] for t in trees] == [3, 2]
+    assert trees[0]["decision_type"].tolist() == [0, 10]
+    # tree 1 has no missing rule, so the NaN of row 3 counts as 0 and goes left
+    assert reference.walk(trees, HAND_ROWS) == pytest.approx(
+        [1.1, 1.1, -0.8, -0.8, -0.6])
+
+
+def test_tiling_repeats_the_trees_and_keeps_the_trailers():
+    tiled = forest.tile_model_text(MODEL_TEXT, 5)
+    trees = reference.parse_model(tiled)
+    assert [t["num_leaves"] for t in trees] == [3, 2, 3, 2, 3]
+    assert tiled.count("\nTree=") == 5 and "\nTree=4\n" in tiled
+    assert tiled.endswith('tpu_bin_mappers:{"kept": "as it was"}\n')
+    assert tiled.startswith("tree\nversion=v3\n")
+    sizes = [int(s) for s in tiled.split("tree_sizes=")[1].split("\n")[0].split()]
+    chunks = tiled[tiled.index("Tree=0"):tiled.index("end of trees")]
+    assert sum(sizes) == len(chunks) and len(sizes) == 5
+    base = reference.walk(reference.parse_model(MODEL_TEXT), HAND_ROWS)
+    assert reference.walk(reference.parse_model(
+        forest.tile_model_text(MODEL_TEXT, 6)), HAND_ROWS) == pytest.approx(
+            3 * base)
+
+
+def test_tiling_refuses_text_without_trees():
+    with pytest.raises(ValueError):
+        forest.tile_model_text("tree\nversion=v3\n", 3)
+    with pytest.raises(reference.ModelTextError):
+        reference.parse_model(MODEL_TEXT.replace("num_cat=0", "num_cat=1", 1))
