@@ -304,51 +304,52 @@ class TrainingData:
         from .. import obs
 
         obs.configure_from_config(config)
-        X = np.asarray(X, dtype=np.float64)
-        if X.ndim != 2:
-            raise ValueError("X must be 2-D")
-        n, nf = X.shape
-        self = cls()
-        self.config = config
-        self.num_data = n
-        self.num_total_features = nf
-        self.feature_names = (list(feature_names) if feature_names
-                              else [f"Column_{i}" for i in range(nf)])
+        with obs.span("dataset/construct", source="matrix"):
+            X = np.asarray(X, dtype=np.float64)
+            if X.ndim != 2:
+                raise ValueError("X must be 2-D")
+            n, nf = X.shape
+            self = cls()
+            self.config = config
+            self.num_data = n
+            self.num_total_features = nf
+            self.feature_names = (list(feature_names) if feature_names
+                                  else [f"Column_{i}" for i in range(nf)])
 
-        from ..utils import timer
+            from ..utils import timer
 
-        with timer.PHASE("sketch"):
-            if reference is not None:
-                self._adopt_reference_mappers(reference)
-            else:
-                self._find_mappers_maybe_distributed(
-                    X, config, categorical_features or [], forced_bins or {})
+            with timer.PHASE("sketch"):
+                if reference is not None:
+                    self._adopt_reference_mappers(reference)
+                else:
+                    self._find_mappers_maybe_distributed(
+                        X, config, categorical_features or [], forced_bins or {})
 
-        # bin all used columns: device chunk-streamed kernel on the fast
-        # path, host per-column numpy otherwise
-        with timer.PHASE("binning"):
-            dtype = np.uint8 if self.max_num_bin <= 256 else np.uint16
-            binner = self._make_device_binner(config, dtype, n)
-            if binner is not None:
-                self._ingest_bins = binner.bin_matrix(X)
-                self._bins = None
-            else:
-                bins = np.empty((n, self.num_features), dtype=dtype)
+            # bin all used columns: device chunk-streamed kernel on the fast
+            # path, host per-column numpy otherwise
+            with timer.PHASE("binning"):
+                dtype = np.uint8 if self.max_num_bin <= 256 else np.uint16
+                binner = self._make_device_binner(config, dtype, n)
+                if binner is not None:
+                    self._ingest_bins = binner.bin_matrix(X)
+                    self._bins = None
+                else:
+                    bins = np.empty((n, self.num_features), dtype=dtype)
 
-                def _bin_col(j: int) -> None:
-                    col = self.used_feature_idx[j]
-                    # contiguous column copy: searchsorted on a strided
-                    # view costs ~40% more than the 8 MB copy saves
-                    bins[:, j] = self.mappers[col].values_to_bins(
-                        np.ascontiguousarray(X[:, col])).astype(
-                            dtype, copy=False)
+                    def _bin_col(j: int) -> None:
+                        col = self.used_feature_idx[j]
+                        # contiguous column copy: searchsorted on a strided
+                        # view costs ~40% more than the 8 MB copy saves
+                        bins[:, j] = self.mappers[col].values_to_bins(
+                            np.ascontiguousarray(X[:, col])).astype(
+                                dtype, copy=False)
 
-                _parallel_columns(_bin_col, self.num_features, config)
-                self.bins = bins
+                    _parallel_columns(_bin_col, self.num_features, config)
+                    self.bins = bins
 
-        self.metadata = Metadata(n, label, weight, group_sizes, init_score)
-        self._set_constraints(config)
-        return self
+            self.metadata = Metadata(n, label, weight, group_sizes, init_score)
+            self._set_constraints(config)
+            return self
 
     def _make_device_binner(self, config: Config, dtype, n_rows: int):
         """A ready DeviceBinner when config routes ingest to the device
@@ -395,52 +396,56 @@ class TrainingData:
         then scatters the O(nnz) stored-value bins.
         """
         config = config or Config()
-        sp = sp.tocsc()
-        # non-canonical inputs (duplicate coordinates) must SUM like
-        # scipy's own toarray(), not last-write-win in the bin scatter
-        sp.sum_duplicates()
-        n, nf = sp.shape
-        self = cls()
-        self.config = config
-        self.num_data = n
-        self.num_total_features = nf
-        self.feature_names = (list(feature_names) if feature_names
-                              else [f"Column_{i}" for i in range(nf)])
+        from .. import obs
 
-        from ..utils import timer
+        obs.configure_from_config(config)
+        with obs.span("dataset/construct", source="sparse"):
+            sp = sp.tocsc()
+            # non-canonical inputs (duplicate coordinates) must SUM like
+            # scipy's own toarray(), not last-write-win in the bin scatter
+            sp.sum_duplicates()
+            n, nf = sp.shape
+            self = cls()
+            self.config = config
+            self.num_data = n
+            self.num_total_features = nf
+            self.feature_names = (list(feature_names) if feature_names
+                                  else [f"Column_{i}" for i in range(nf)])
 
-        with timer.PHASE("sketch"):
-            if reference is not None:
-                self._adopt_reference_mappers(reference)
-            else:
-                # sparse ingest joins the collective bin-finding path
-                # directly: the feature-sharded mapper search slices CSC
-                # columns and samples stored values exactly like the local
-                # find (local_payload -> _find_mappers is sparse-aware)
-                self._find_mappers_maybe_distributed(
-                    sp, config, categorical_features or [], forced_bins or {})
+            from ..utils import timer
 
-        with timer.PHASE("binning"):
-            dtype = np.uint8 if self.max_num_bin <= 256 else np.uint16
-            bins = np.empty((n, self.num_features), dtype=dtype)
-            indptr, indices, data = sp.indptr, sp.indices, sp.data
-            for j, col in enumerate(self.used_feature_idx):
-                m = self.mappers[col]
-                lo, hi = int(indptr[col]), int(indptr[col + 1])
-                # implicit zeros take the column's zero-value bin
-                # (default_bin IS value_to_bin(0.0), set at find time;
-                # most_freq_bin semantics fall out of it)
-                colbins = np.full(n, m.default_bin, dtype=dtype)
-                if hi > lo:
-                    vals = np.asarray(data[lo:hi], dtype=np.float64)
-                    colbins[indices[lo:hi]] = \
-                        m.values_to_bins(vals).astype(dtype)
-                bins[:, j] = colbins
-            self.bins = bins
+            with timer.PHASE("sketch"):
+                if reference is not None:
+                    self._adopt_reference_mappers(reference)
+                else:
+                    # sparse ingest joins the collective bin-finding path
+                    # directly: the feature-sharded mapper search slices CSC
+                    # columns and samples stored values exactly like the local
+                    # find (local_payload -> _find_mappers is sparse-aware)
+                    self._find_mappers_maybe_distributed(
+                        sp, config, categorical_features or [], forced_bins or {})
 
-        self.metadata = Metadata(n, label, weight, group_sizes, init_score)
-        self._set_constraints(config)
-        return self
+            with timer.PHASE("binning"):
+                dtype = np.uint8 if self.max_num_bin <= 256 else np.uint16
+                bins = np.empty((n, self.num_features), dtype=dtype)
+                indptr, indices, data = sp.indptr, sp.indices, sp.data
+                for j, col in enumerate(self.used_feature_idx):
+                    m = self.mappers[col]
+                    lo, hi = int(indptr[col]), int(indptr[col + 1])
+                    # implicit zeros take the column's zero-value bin
+                    # (default_bin IS value_to_bin(0.0), set at find time;
+                    # most_freq_bin semantics fall out of it)
+                    colbins = np.full(n, m.default_bin, dtype=dtype)
+                    if hi > lo:
+                        vals = np.asarray(data[lo:hi], dtype=np.float64)
+                        colbins[indices[lo:hi]] = \
+                            m.values_to_bins(vals).astype(dtype)
+                    bins[:, j] = colbins
+                self.bins = bins
+
+            self.metadata = Metadata(n, label, weight, group_sizes, init_score)
+            self._set_constraints(config)
+            return self
 
     @classmethod
     def from_file(cls, path: str, config: Optional[Config] = None,
@@ -456,37 +461,38 @@ class TrainingData:
         from .. import obs
 
         obs.configure_from_config(config)
-        ensure_distributed(config)
-        skip_cache = config_wants_distributed(config)
-        if reference is None and not skip_cache \
-                and os.path.exists(path + ".bin"):
-            try:
-                return cls.from_binary(path + ".bin")
-            except Exception as exc:
-                from ..utils.log import Log
+        with obs.span("dataset/construct", source="file"):
+            ensure_distributed(config)
+            skip_cache = config_wants_distributed(config)
+            if reference is None and not skip_cache \
+                    and os.path.exists(path + ".bin"):
+                try:
+                    return cls.from_binary(path + ".bin")
+                except Exception as exc:
+                    from ..utils.log import Log
 
-                Log.warning(f"ignoring stale binary cache {path}.bin: {exc}")
-        if bool(config.two_round):
-            try:
-                data = cls._from_file_two_round(path, config, reference)
-                if bool(config.save_binary):
-                    data.save_binary(path + ".bin")
-                return data
-            except ValueError as exc:  # e.g. libsvm: no streaming reader
-                from ..utils.log import Log
+                    Log.warning(f"ignoring stale binary cache {path}.bin: {exc}")
+            if bool(config.two_round):
+                try:
+                    data = cls._from_file_two_round(path, config, reference)
+                    if bool(config.save_binary):
+                        data.save_binary(path + ".bin")
+                    return data
+                except ValueError as exc:  # e.g. libsvm: no streaming reader
+                    from ..utils.log import Log
 
-                Log.warning(f"two_round fell back to one-pass load: {exc}")
-        X, y, w, group, init, names = load_text_file(
-            path, label_column=config.label_column,
-            header=True if config.header else None)
-        cat = _parse_column_spec(config.categorical_feature, names)
-        data = cls.from_matrix(X, y, config, weight=w, group_sizes=group,
-                               init_score=init, reference=reference,
-                               feature_names=names, categorical_features=cat,
-                               forced_bins=_load_forced_bins(config))
-        if bool(config.save_binary):
-            data.save_binary(path + ".bin")
-        return data
+                    Log.warning(f"two_round fell back to one-pass load: {exc}")
+            X, y, w, group, init, names = load_text_file(
+                path, label_column=config.label_column,
+                header=True if config.header else None)
+            cat = _parse_column_spec(config.categorical_feature, names)
+            data = cls.from_matrix(X, y, config, weight=w, group_sizes=group,
+                                   init_score=init, reference=reference,
+                                   feature_names=names, categorical_features=cat,
+                                   forced_bins=_load_forced_bins(config))
+            if bool(config.save_binary):
+                data.save_binary(path + ".bin")
+            return data
 
     @classmethod
     def _from_file_two_round(cls, path: str, config: Config,

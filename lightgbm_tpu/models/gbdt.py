@@ -276,82 +276,90 @@ class GBDT:
 
     # ------------------------------------------------------------------
     def init(self, config: Config, train_data: TrainingData) -> None:
-        self.config = config
-        self.train_data = train_data
-        # cap (or restore: the native side maps n<=0 back to the captured
-        # startup default) the walker's OpenMP pool unconditionally, so a
-        # cap from a previous Booster never leaks into this training
-        # (reference honors num_threads process-wide via
-        # omp_set_num_threads)
-        from ..native import set_num_threads
-
-        set_num_threads(int(config.num_threads))
-        self.num_class = int(config.num_class)
-        self.shrinkage_rate = float(config.learning_rate)
-        self.objective = create_objective(config)
-        if self.objective is not None:
-            self.objective.init(train_data.metadata, train_data.num_data)
-            self.num_tree_per_iteration = self.objective.num_model_per_iteration()
-        else:
-            self.num_tree_per_iteration = self.num_class
-        self.learner = make_tree_learner(config, train_data)
-        self.metrics = create_metrics(
-            config, self.objective.name if self.objective else "")
-        for m in self.metrics:
-            m.init(train_data.metadata, train_data.num_data)
-        self.train_scores = _ScoreState(self.num_tree_per_iteration,
-                                        train_data.num_data,
-                                        train_data.metadata.init_score)
-        self.feature_names = list(train_data.feature_names)
-        self.max_feature_idx = train_data.num_total_features - 1
-        self._bag_rng = np.random.default_rng(int(config.bagging_seed))
-        self._boosted_from_average = [False] * self.num_tree_per_iteration
-        # async fast path: fused device step + lazily materialized trees
-        self._pending: List[Tuple] = []
-        self._stopped = False
-        self._key = jax.random.PRNGKey(int(config.seed))
-        self._bag_key = jax.random.PRNGKey(int(config.bagging_seed))
-        self._train_step = None
-        self._bag_cfg = self._bagging_config()
-        # numeric guardrails (tpu_guard_numerics=off|warn|raise|skip):
-        # validated here so a typo fails at init, not mid-run; the
-        # quantized headroom sentinel is a one-time init check
-        self._guard = str(config.tpu_guard_numerics).strip().lower()
-        if self._guard not in ("off", "warn", "raise", "skip"):
-            raise ValueError("tpu_guard_numerics must be off|warn|raise|"
-                             f"skip, got {self._guard!r}")
-        self._guard_streak = 0
-        self._guard_skips_total = 0
-        # collective watchdog defaults (Network::Init analog): armed
-        # process-wide so metric sync / checkpoint barriers / binning
-        # allgathers all share one deadline policy; no-clobber rule
-        # lives in configure_from_config
-        from ..parallel.collective import configure_from_config
-
-        configure_from_config(config)
         # telemetry policy (tpu_telemetry / tpu_trace_dir) is process-
-        # global under the same no-clobber convention
+        # global and no-clobber; armed FIRST, so that a Booster given
+        # telemetry its Dataset was not still records its own set-up
         obs.configure_from_config(config)
-        if self._guard != "off" \
-                and str(config.tpu_hist_precision) in ("int8", "int16"):
-            quant_headroom_check(str(config.tpu_hist_precision),
-                                 train_data.num_data, self._guard)
-        if self.learner.params.has_cegb and self._goss_cfg is not None:
-            raise NotImplementedError(
-                "CEGB penalties do not compose with GOSS yet")
-        # pre-partitioned rows: every statistic that must be GLOBAL
-        # either reduces (metrics, boost-from-average, the renew leaf
-        # averaging in _renew_and_update) or is local by the reference's
-        # own distributed semantics (GOSS sampling, per-query ranking
-        # lambdas, per-machine percentile renew)
-            # GOSS composes: its threshold/sample run over LOCAL rows,
-            # which is the reference's distributed behavior too (each
-            # machine subsets its own data, goss.hpp Bagging override)
-        self._maybe_make_train_step()
-        # HBM preflight (ISSUE 15): predict peak device bytes from the
-        # live buffers + closed-form models and enforce the budget
-        # BEFORE iteration 0 burns a compile on a doomed configuration
-        self._run_preflight()
+        with obs.span("booster/init"):
+            self.config = config
+            self.train_data = train_data
+            # cap (or restore: the native side maps n<=0 back to the
+            # captured startup default) the walker's OpenMP pool
+            # unconditionally, so a cap from a previous Booster never
+            # leaks into this training (reference honors num_threads
+            # process-wide via omp_set_num_threads)
+            from ..native import set_num_threads
+
+            set_num_threads(int(config.num_threads))
+            self.num_class = int(config.num_class)
+            self.shrinkage_rate = float(config.learning_rate)
+            with obs.span("objective/init"):
+                self.objective = create_objective(config)
+                if self.objective is not None:
+                    self.objective.init(train_data.metadata,
+                                        train_data.num_data)
+                    self.num_tree_per_iteration = \
+                        self.objective.num_model_per_iteration()
+                else:
+                    self.num_tree_per_iteration = self.num_class
+            self.learner = make_tree_learner(config, train_data)
+            self.metrics = create_metrics(
+                config, self.objective.name if self.objective else "")
+            for m in self.metrics:
+                m.init(train_data.metadata, train_data.num_data)
+            self.train_scores = _ScoreState(self.num_tree_per_iteration,
+                                            train_data.num_data,
+                                            train_data.metadata.init_score)
+            self.feature_names = list(train_data.feature_names)
+            self.max_feature_idx = train_data.num_total_features - 1
+            self._bag_rng = np.random.default_rng(int(config.bagging_seed))
+            self._boosted_from_average = \
+                [False] * self.num_tree_per_iteration
+            # async fast path: fused device step + lazily materialized
+            # trees
+            self._pending: List[Tuple] = []
+            self._stopped = False
+            self._key = jax.random.PRNGKey(int(config.seed))
+            self._bag_key = jax.random.PRNGKey(int(config.bagging_seed))
+            self._train_step = None
+            self._bag_cfg = self._bagging_config()
+            # numeric guardrails (tpu_guard_numerics=off|warn|raise|skip):
+            # validated here so a typo fails at init, not mid-run; the
+            # quantized headroom sentinel is a one-time init check
+            self._guard = str(config.tpu_guard_numerics).strip().lower()
+            if self._guard not in ("off", "warn", "raise", "skip"):
+                raise ValueError("tpu_guard_numerics must be off|warn|raise|"
+                                 f"skip, got {self._guard!r}")
+            self._guard_streak = 0
+            self._guard_skips_total = 0
+            # collective watchdog defaults (Network::Init analog): armed
+            # process-wide so metric sync / checkpoint barriers / binning
+            # allgathers all share one deadline policy; no-clobber rule
+            # lives in configure_from_config
+            from ..parallel.collective import configure_from_config
+
+            configure_from_config(config)
+            if self._guard != "off" \
+                    and str(config.tpu_hist_precision) in ("int8", "int16"):
+                quant_headroom_check(str(config.tpu_hist_precision),
+                                     train_data.num_data, self._guard)
+            if self.learner.params.has_cegb and self._goss_cfg is not None:
+                raise NotImplementedError(
+                    "CEGB penalties do not compose with GOSS yet")
+            # pre-partitioned rows: every statistic that must be GLOBAL
+            # either reduces (metrics, boost-from-average, the renew leaf
+            # averaging in _renew_and_update) or is local by the
+            # reference's own distributed semantics (GOSS sampling,
+            # per-query ranking lambdas, per-machine percentile renew)
+                # GOSS composes: its threshold/sample run over LOCAL rows,
+                # which is the reference's distributed behavior too (each
+                # machine subsets its own data, goss.hpp Bagging override)
+            self._maybe_make_train_step()
+            # HBM preflight (ISSUE 15): predict peak device bytes from
+            # the live buffers + closed-form models and enforce the
+            # budget BEFORE iteration 0 burns a compile on a doomed
+            # configuration
+            self._run_preflight()
 
     def _maybe_make_train_step(self) -> None:
         """(Re)build the fused async step when the configuration supports
@@ -359,25 +367,26 @@ class GBDT:
         rebuild site (init / reset_training_data / reset_config) applies
         identical conditions."""
         self._train_step = None
-        if (self.objective is not None and not self.objective.needs_renew
-                and not self.objective.host_only
-                # CEGB threads cross-tree used/paid state through
-                # learner.train (the sync path); the fused step's meta is
-                # closure-captured and cannot carry it
-                and not self.learner.params.has_cegb
-                # multi-host meshes need learner.train's global array
-                # placement (put_global); the fused step mixes local
-                # score state into the global-mesh program
-                and not self.learner._multiproc
-                # the streamed layout has no device-resident bins_t for
-                # the fused step to close over: its train() drives the
-                # per-block host loop (ops/stream.py) — sync path only
-                and not self.learner.stream_layout
-                and all(self.objective.class_need_train(k)
-                        for k in range(self.num_tree_per_iteration))):
-            self._train_step = self.learner.make_train_step(
-                self.objective.get_gradients, self.shrinkage_rate,
-                self._bag_cfg, self._goss_cfg)
+        with obs.span("train_step/build"):
+            if (self.objective is not None and not self.objective.needs_renew
+                    and not self.objective.host_only
+                    # CEGB threads cross-tree used/paid state through
+                    # learner.train (the sync path); the fused step's meta is
+                    # closure-captured and cannot carry it
+                    and not self.learner.params.has_cegb
+                    # multi-host meshes need learner.train's global array
+                    # placement (put_global); the fused step mixes local
+                    # score state into the global-mesh program
+                    and not self.learner._multiproc
+                    # the streamed layout has no device-resident bins_t for
+                    # the fused step to close over: its train() drives the
+                    # per-block host loop (ops/stream.py) — sync path only
+                    and not self.learner.stream_layout
+                    and all(self.objective.class_need_train(k)
+                            for k in range(self.num_tree_per_iteration))):
+                self._train_step = self.learner.make_train_step(
+                    self.objective.get_gradients, self.shrinkage_rate,
+                    self._bag_cfg, self._goss_cfg)
 
     def _bagging_config(self) -> Optional[Dict]:
         cfg = self.config

@@ -9,7 +9,6 @@ variants wrap the same grower with mesh shardings (lightgbm_tpu.parallel).
 
 from __future__ import annotations
 
-import time
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -17,6 +16,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..config import Config
 from ..io.bin_mapper import MissingType
 from ..io.dataset import TrainingData
@@ -28,6 +28,7 @@ from ..parallel.strategies import (bins_sharding, make_strategy_grower,
                                    pool_partition_spec,
                                    resolve_tree_learner, rows_sharding)
 from ..utils import timer
+from ..utils.compile_ledger import ledger_jit
 from ..utils.log import Log
 from .tree import Tree
 
@@ -223,543 +224,547 @@ class TPUTreeLearner:
         # layout phase timer (bench.py splits ingest into sketch / bin /
         # layout): everything from EFB planning to the placed device
         # arrays below counts as layout
-        _t_layout = time.perf_counter()
+        with timer.PHASE("layout"):
+            # ---- EFB bundling (reference FindGroups/
+            # FastFeatureBundling, dataset.cpp:91-263): sparse
+            # zero-default features share columns, shrinking the
+            # histogram matrix's feature axis ----
+            plan = None
+            if (bool(config.enable_bundle)
+                    and strategy not in ("serial", "data")
+                    and self.num_features > 1):
+                # voting/feature learners train unbundled (the grower's
+                # bundle expansion composes with serial/data only) — say so
+                # instead of silently dropping the requested EFB
+                Log.info(f"EFB bundling is inactive under tree_learner="
+                         f"{strategy}; training on plain columns")
+            if (bool(config.enable_bundle) and strategy in ("serial", "data")
+                    and not forced and self.num_features > 1
+                    and not self.stream_layout):
+                from ..io.bundling import (EFB_SAMPLE_ROWS, find_bundles,
+                                           find_bundles_multihost)
 
-        # ---- EFB bundling (reference FindGroups/FastFeatureBundling,
-        # dataset.cpp:91-263): sparse zero-default features share columns,
-        # shrinking the histogram matrix's feature axis ----
-        plan = None
-        if (bool(config.enable_bundle) and strategy not in ("serial", "data")
-                and self.num_features > 1):
-            # voting/feature learners train unbundled (the grower's
-            # bundle expansion composes with serial/data only) — say so
-            # instead of silently dropping the requested EFB
-            Log.info(f"EFB bundling is inactive under tree_learner="
-                     f"{strategy}; training on plain columns")
-        if (bool(config.enable_bundle) and strategy in ("serial", "data")
-                and not forced and self.num_features > 1
-                and not self.stream_layout):
-            from ..io.bundling import (EFB_SAMPLE_ROWS, find_bundles,
-                                       find_bundles_multihost)
-
-            zero_frac = train_data.column_zero_fraction()
-            if self._partitioned:
-                # every rank must greedy-group the SAME plan or the
-                # global arrays' num_columns/meta diverge; all plan-
-                # determining statistics reduce inside the helper
-                cand_plan = find_bundles_multihost(
-                    train_data.bins, meta_np["num_bin"], zero_frac, n,
-                    float(config.sparse_threshold),
-                    float(config.max_conflict_rate), B)
-            else:
-                # the greedy only ever reads the strided row sample;
-                # hand it exactly that sample (a bounded device fetch
-                # when the matrix is device-resident) instead of the
-                # full host matrix — identical rows, identical plan
-                cand_plan = find_bundles(
-                    train_data.strided_row_sample(EFB_SAMPLE_ROWS),
-                    meta_np["num_bin"],
-                    zero_frac >= float(config.sparse_threshold),
-                    float(config.max_conflict_rate), B,
-                    sample_rows=EFB_SAMPLE_ROWS)
-            if not cand_plan.is_trivial:
-                plan = cand_plan
-                B = max(B, int(plan.num_bin.max()))
-                self.num_bins = B
-                Log.info(
-                    f"EFB: bundled {self.num_features} features into "
-                    f"{plan.num_columns} columns")
-        self.bundle_plan = plan
-
-        if plan is not None:
-            from ..io.bundling import apply_bundles
-
-            cols_src = apply_bundles(train_data.bins, plan)
-            dev_src = None
-            meta_np["bundle_idx"] = plan.bundle_idx.astype(np.int32)
-            meta_np["bin_offset"] = plan.bin_offset.astype(np.int32)
-            meta_np["needs_fix"] = plan.needs_fix.astype(np.int32)
-            self.num_columns = cols_src.shape[1]
-        else:
-            # device-resident ingest keeps the host matrix lazy: the
-            # plain-column layout below can transpose on device, so
-            # cols_src stays unmaterialized until a host-only path
-            # (sparse COO packing, parallel placement) asks for it
-            dev_src = train_data.device_ingest_bins()
-            cols_src = None if dev_src is not None else train_data.bins
-            F_ = self.num_features
-            meta_np["bundle_idx"] = np.arange(F_, dtype=np.int32)
-            meta_np["bin_offset"] = np.zeros(F_, np.int32)
-            meta_np["needs_fix"] = np.zeros(F_, np.int32)
-            self.num_columns = F_
-        self.g_pad = (self.f_pad if self.f_shards > 1 else self.num_columns)
-
-        # ---- sparse train-time storage (reference OrderedSparseBin,
-        # src/io/ordered_sparse_bin.hpp / sparse_bin.hpp:73): features
-        # whose nonzero-bin fraction is <= tpu_sparse_threshold keep only
-        # their O(nnz) (row, bin) pairs; the dense [Gd, n] matrix holds
-        # the rest.  Wide very-sparse data (Bosch-shaped 1M x 968 @ ~2%)
-        # stops paying dense HBM for rows sitting at the zero bin. ----
-        self._sparse_mask = None
-        sth = float(config.tpu_sparse_threshold)
-        if sth > 0.0:
-            if quantized:
-                # the sparse zero-bin reconstruction mixes histogram rows
-                # with scalar leaf totals; keeping that exact in the
-                # integer domain is future work — reject loudly
-                raise ValueError(
-                    "tpu_sparse_threshold does not compose with quantized "
-                    "histogram precisions (tpu_hist_precision=int8|int16)")
-            if bool(config.enable_bundle):
-                # deterministic gate on the FLAG, not on whether a plan
-                # happened to form for this data — the error must not
-                # depend on bundle-ability
-                raise ValueError(
-                    "tpu_sparse_threshold requires enable_bundle=false "
-                    "(EFB already re-columns sparse features; pick one)")
-            if strategy not in ("serial", "data", "voting"):
-                raise NotImplementedError(
-                    "tpu_sparse_threshold requires tree_learner=serial, "
-                    "data, or voting (feature sharding replicates rows)")
-            if forced:
-                raise ValueError("tpu_sparse_threshold does not compose "
-                                 "with forced splits")
-            zb_f = meta_np["default_bin"]
-            # one vectorized (bins != zero_bin).sum(axis=0) pass — the
-            # sparse gate implies enable_bundle=false, so the columns
-            # are the plain training bins; the helper row-chunks the
-            # boolean temporary (Bosch scale) and reduces on device
-            # when the matrix is device-resident
-            nz_counts = train_data.column_nonzero_counts(zb_f)
-            denom = n
-            if self._partitioned:
-                # every rank must agree on WHICH features are sparse, or
-                # Gs/perm diverge and the global tables are inconsistent
-                # — decide from the GLOBAL nonzero fractions
-                from ..parallel.topology import host_allgather
-
-                g = host_allgather(
-                    np.concatenate([nz_counts, [n]]).astype(np.int32),
-                    name="sparse_global_fractions")
-                tot = g.sum(axis=0)
-                nz_counts, denom = tot[:-1], int(tot[-1])
-            nz_frac = nz_counts / max(denom, 1)
-            sp_mask = nz_frac <= sth
-            if sp_mask.all():
-                # the dense kernel needs a nonempty matrix; keep the
-                # densest feature dense
-                sp_mask[int(np.argmax(nz_frac))] = False
-            if sp_mask.any():
-                self._sparse_mask = sp_mask
-
-        # impl/block resolution happens HERE, once, with the final
-        # histogram shape: bundling above only needs the host bin matrix,
-        # while the padded row count below depends on the resolved block.
-        # (The perfeature kernel chunks the feature axis itself, so the
-        # VMEM fit depends only on the bin count, not the feature width.)
-        # persisted autotune profile (utils/autotune.py): measured winners
-        # for this (platform, device count, shape bucket) override the
-        # "auto" heuristics below; a stale profile (other topology) raises
-        # AutotuneStaleProfile here rather than training on wrong winners
-        self._autotune_entry = None
-        if str(config.tpu_autotune) != "off":
-            from ..utils.autotune import resolve_autotune
-
-            self._autotune_entry = resolve_autotune(
-                config, n, self.num_features, B, precision)
-        hist_impl, block = self._resolve_hist_impl(
-            config, B, precision, tuned=self._autotune_entry)
-        if hist_impl in ("pallas2", "fused"):
-            # the perfeature kernel chunks its feature grid in
-            # sublane-aligned (multiple-of-32) divisors (ops/histogram.py
-            # _hist_pallas); pad the histogram column axis so every width
-            # admits aligned chunks.  Padding columns hold constant bin 0
-            # (num_bin=1 features) and can never split.  Feature-parallel
-            # pads to 32 * n_shards so each shard's slice stays aligned
-            if self.f_shards > 1:
-                a = 32 * self.f_shards
-                self.f_pad = -(-self.f_pad // a) * a
-                self.g_pad = self.f_pad
-            elif plan is None:
-                self.f_pad = -(-self.f_pad // 32) * 32
-                self.g_pad = self.f_pad
-            else:
-                self.g_pad = -(-self.g_pad // 32) * 32
-        # ---- shape bucketing (compile-cache policy): quantize the padded
-        # axes so at most `tpu_shape_buckets` distinct shapes exist per
-        # power-of-2 octave — a new dataset of similar size then hits the
-        # persistent compilation cache instead of paying the 70-150 s
-        # cold remote compile (SURVEY §7 "dispatch overhead is the #1
-        # wall-clock risk").  Worst-case pad waste is 2/buckets (~6% at
-        # the default 32); 0 disables (exact block-multiple padding,
-        # maximum throughput — bench.py pins this).
-        buckets = int(config.tpu_shape_buckets)
-
-        def bucket_up(count: int, quantum: int) -> int:
-            padded = -(-count // quantum) * quantum
-            if buckets <= 0:
-                return padded
-            q = quantum
-            while q * buckets < padded:
-                q *= 2
-            return -(-count // q) * q
-
-        def bucket_rows(count: int) -> int:
-            # supra-block: quantize the BLOCK COUNT (pad_rows clamps the
-            # block to the row count, so derive the effective block the
-            # same way).  Sub-block (count < tpu_block_rows, the common
-            # case on TPU where the resolved block is 8-16k): quantize
-            # the row count itself from the 128-lane tile upward, capped
-            # at one block — without this, every sub-block n is its own
-            # XLA program
-            eff = min(block, max(count, 1))
-            base = pad_rows(count, block)
-            if buckets <= 0:
-                return base
-            if base >= block:
-                return bucket_up(base // eff, 1) * eff
-            return min(bucket_up(count, 128), block)
-
-        if self._partitioned:
-            # rows per shard must be UNIFORM across the whole mesh: size
-            # from the largest process's share (short ranks pad with
-            # masked rows); n here is only THIS process's row count
-            from ..parallel.topology import host_allgather
-
-            shards_local = self.d_shards // jax.process_count()
-            ns = host_allgather(np.asarray([n], np.int32),
-                                name="shard_rows_sync")
-            max_shard_rows = -(-int(ns.max()) // shards_local)
-            self.n_pad = bucket_rows(max_shard_rows) * self.d_shards
-            self._local_width = (self.n_pad // self.d_shards) * shards_local
-        elif self.d_shards > 1:
-            # every shard holds an equal, whole number of histogram blocks
-            self.n_pad = bucket_rows(
-                (n + self.d_shards - 1) // self.d_shards) * self.d_shards
-        else:
-            self.n_pad = bucket_rows(n)
-        # feature axis: bucket above the alignment the padding code above
-        # already established (32-multiples for pallas2, shard-count
-        # multiples for feature sharding); padding features are trivial
-        # (num_bin=1) and can never split
-        if buckets > 0:
-            if hist_impl == "pallas2":
-                align = 32 * self.f_shards if self.f_shards > 1 else 32
-            else:
-                align = self.f_shards if self.f_shards > 1 else 8
-            if self.g_pad == self.f_pad:
-                self.f_pad = bucket_up(self.f_pad, align)
-                self.g_pad = self.f_pad
-            else:
-                # EFB keeps g_pad (bundle columns) separate from f_pad
-                self.g_pad = bucket_up(self.g_pad, align)
-
-        # ---- scatter-aggregation alignment (tpu_hist_agg=scatter): the
-        # reduce-scatter hands shard d a contiguous 1/P slice of the
-        # histogram column axis, so that axis must divide by the data-
-        # shard count — on top of whatever alignment feature sharding /
-        # the pallas2 kernel already demanded.  Padding columns/features
-        # are trivial (num_bin=1) and can never split.  Voting scatters
-        # only the voted [k, B, 3] block (padded inside the grower).
-        if self.hist_agg == "scatter" and strategy != "voting":
-            import math
-
-            if plan is None:
-                a = self.f_shards * self.d_shards
-                if hist_impl == "pallas2":
-                    a = math.lcm(a, 32 * max(self.f_shards, 1))
-                self.f_pad = -(-self.f_pad // a) * a
-                self.g_pad = self.f_pad
-            else:
-                # EFB: only the bundle-column axis scatters; the shard ->
-                # feature assignment rides the scatter_feat table below
-                a = self.d_shards
-                if hist_impl == "pallas2":
-                    a = math.lcm(a, 32)
-                self.g_pad = -(-self.g_pad // a) * a
-
-        # transposed [G, n] bin matrix: rows ride the 128-lane minor axis
-        # for the histogram contraction (see ops/histogram.py).  Stored
-        # uint8 when bins fit (the reference's narrow dense bins,
-        # dense_bin.hpp / dense_nbits_bin.hpp): the matrix is re-read every
-        # grower round, so width directly scales histogram HBM traffic;
-        # the one-hot compare upcasts on the fly
-        bin_dtype = np.uint8 if B <= 256 else np.int32
-        if self._sparse_mask is not None:
-            if cols_src is None:  # COO packing reads host columns
-                cols_src = train_data.bins
-                dev_src = None
-            dense_idx = np.flatnonzero(~self._sparse_mask)
-            sparse_idx_cols = np.flatnonzero(self._sparse_mask)
-            gd = len(dense_idx)
-            # the perfeature pallas kernel chunks its feature grid in
-            # 32-multiples — align the DENSE matrix width; the sparse
-            # groups never enter that kernel
-            gd_pad = -(-gd // 32) * 32 if hist_impl == "pallas2" else gd
-            width_sp = (self._local_width if self._partitioned
-                        else self.n_pad)
-            bins_t = np.zeros((gd_pad, width_sp), dtype=bin_dtype)
-            bins_t[:gd, :n] = cols_src[:, dense_idx].T
-            zb_np = meta_np["default_bin"]
-            Gs = len(sparse_idx_cols)
-            # ONE vectorized nonzero pass over the sparse columns,
-            # column-blocked to bound the boolean temporary; entries
-            # come out sorted by (slot, row), exactly the order the
-            # per-column scans produced
-            slot_parts, row_parts, bin_parts = [], [], []
-            blk = max((1 << 28) // max(n, 1), 1)
-            for lo_c in range(0, Gs, blk):
-                cols = sparse_idx_cols[lo_c:lo_c + blk]
-                sub = cols_src[:, cols]
-                g_i, r_i = np.nonzero((sub != zb_np[cols][None, :]).T)
-                slot_parts.append((g_i + lo_c).astype(np.int64))
-                row_parts.append(r_i.astype(np.int64))
-                bin_parts.append(sub[r_i, g_i].astype(np.int32))
-            slot = (np.concatenate(slot_parts) if slot_parts
-                    else np.zeros(0, np.int64))
-            row_id = (np.concatenate(row_parts) if row_parts
-                      else np.zeros(0, np.int64))
-            binval = (np.concatenate(bin_parts) if bin_parts
-                      else np.zeros(0, np.int32))
-            # pad row-id = the (local) width (out of range: the
-            # partition scatter drops it); pad bin = B (its one-hot row
-            # is all-zero, so the clipped histogram gather contributes
-            # nothing)
-            if self.d_shards > 1:
-                # data sharding: per-SHARD tables with shard-local row
-                # ids — the leading axis shards over 'data' so each
-                # device holds only its block, and the sparse
-                # contraction psums like the dense one.  Partitioned
-                # ingest: this process's local rows cover exactly its
-                # own shards, so it builds [shards_local, Gs, M] and
-                # contributes them via put_local; the entry capacity M
-                # must still be the GLOBAL max.
-                rps = self.n_pad // self.d_shards
-                sl = (self.d_shards // jax.process_count()
-                      if self._partitioned else self.d_shards)
-                shard = row_id // rps
-                key = shard * Gs + slot
-                counts = np.bincount(key, minlength=sl * Gs)
-                max_nnz = int(counts.max()) if counts.size else 0
+                zero_frac = train_data.column_zero_fraction()
                 if self._partitioned:
+                    # every rank must greedy-group the SAME plan or the
+                    # global arrays' num_columns/meta diverge; all plan-
+                    # determining statistics reduce inside the helper
+                    cand_plan = find_bundles_multihost(
+                        train_data.bins, meta_np["num_bin"], zero_frac, n,
+                        float(config.sparse_threshold),
+                        float(config.max_conflict_rate), B)
+                else:
+                    # the greedy only ever reads the strided row sample;
+                    # hand it exactly that sample (a bounded device fetch
+                    # when the matrix is device-resident) instead of the
+                    # full host matrix — identical rows, identical plan
+                    cand_plan = find_bundles(
+                        train_data.strided_row_sample(EFB_SAMPLE_ROWS),
+                        meta_np["num_bin"],
+                        zero_frac >= float(config.sparse_threshold),
+                        float(config.max_conflict_rate), B,
+                        sample_rows=EFB_SAMPLE_ROWS)
+                if not cand_plan.is_trivial:
+                    plan = cand_plan
+                    B = max(B, int(plan.num_bin.max()))
+                    self.num_bins = B
+                    Log.info(
+                        f"EFB: bundled {self.num_features} features into "
+                        f"{plan.num_columns} columns")
+            self.bundle_plan = plan
+
+            if plan is not None:
+                from ..io.bundling import apply_bundles
+
+                cols_src = apply_bundles(train_data.bins, plan)
+                dev_src = None
+                meta_np["bundle_idx"] = plan.bundle_idx.astype(np.int32)
+                meta_np["bin_offset"] = plan.bin_offset.astype(np.int32)
+                meta_np["needs_fix"] = plan.needs_fix.astype(np.int32)
+                self.num_columns = cols_src.shape[1]
+            else:
+                # device-resident ingest keeps the host matrix lazy: the
+                # plain-column layout below can transpose on device, so
+                # cols_src stays unmaterialized until a host-only path
+                # (sparse COO packing, parallel placement) asks for it
+                dev_src = train_data.device_ingest_bins()
+                cols_src = None if dev_src is not None else train_data.bins
+                F_ = self.num_features
+                meta_np["bundle_idx"] = np.arange(F_, dtype=np.int32)
+                meta_np["bin_offset"] = np.zeros(F_, np.int32)
+                meta_np["needs_fix"] = np.zeros(F_, np.int32)
+                self.num_columns = F_
+            self.g_pad = (self.f_pad if self.f_shards > 1
+                          else self.num_columns)
+
+            # ---- sparse train-time storage (reference OrderedSparseBin,
+            # src/io/ordered_sparse_bin.hpp / sparse_bin.hpp:73): features
+            # whose nonzero-bin fraction is <= tpu_sparse_threshold keep only
+            # their O(nnz) (row, bin) pairs; the dense [Gd, n] matrix holds
+            # the rest.  Wide very-sparse data (Bosch-shaped 1M x 968 @ ~2%)
+            # stops paying dense HBM for rows sitting at the zero bin. ----
+            self._sparse_mask = None
+            sth = float(config.tpu_sparse_threshold)
+            if sth > 0.0:
+                if quantized:
+                    # the sparse zero-bin reconstruction mixes histogram rows
+                    # with scalar leaf totals; keeping that exact in the
+                    # integer domain is future work — reject loudly
+                    raise ValueError(
+                        "tpu_sparse_threshold does not compose with quantized "
+                        "histogram precisions (tpu_hist_precision=int8|int16)")
+                if bool(config.enable_bundle):
+                    # deterministic gate on the FLAG, not on whether a plan
+                    # happened to form for this data — the error must not
+                    # depend on bundle-ability
+                    raise ValueError(
+                        "tpu_sparse_threshold requires enable_bundle=false "
+                        "(EFB already re-columns sparse features; pick one)")
+                if strategy not in ("serial", "data", "voting"):
+                    raise NotImplementedError(
+                        "tpu_sparse_threshold requires tree_learner=serial, "
+                        "data, or voting (feature sharding replicates rows)")
+                if forced:
+                    raise ValueError("tpu_sparse_threshold does not compose "
+                                     "with forced splits")
+                zb_f = meta_np["default_bin"]
+                # one vectorized (bins != zero_bin).sum(axis=0) pass — the
+                # sparse gate implies enable_bundle=false, so the columns
+                # are the plain training bins; the helper row-chunks the
+                # boolean temporary (Bosch scale) and reduces on device
+                # when the matrix is device-resident
+                nz_counts = train_data.column_nonzero_counts(zb_f)
+                denom = n
+                if self._partitioned:
+                    # every rank must agree on WHICH features are sparse, or
+                    # Gs/perm diverge and the global tables are inconsistent
+                    # — decide from the GLOBAL nonzero fractions
                     from ..parallel.topology import host_allgather
 
-                    max_nnz = int(host_allgather(
-                        np.asarray([max_nnz], np.int32),
-                        name="sparse_table_width").max())
-                M = max(128, -(-max_nnz // 128) * 128)
-                sp_rows = np.full((sl, Gs, M), rps, np.int32)
-                sp_bins = np.full((sl, Gs, M), B, np.int32)
-                # stable sort by (shard, slot) keeps rows ascending
-                # within each table row, like the per-shard slices did
-                order = np.argsort(key, kind="stable")
-                k_s = key[order]
-                starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-                pos = np.arange(len(k_s)) - starts[k_s]
-                sp_rows[shard[order], slot[order], pos] = \
-                    row_id[order] - shard[order] * rps
-                sp_bins[shard[order], slot[order], pos] = binval[order]
-            else:
-                counts = np.bincount(slot, minlength=Gs)
-                max_nnz = int(counts.max()) if counts.size else 0
-                M = max(128, -(-max_nnz // 128) * 128)
-                sp_rows = np.full((Gs, M), self.n_pad, np.int32)
-                sp_bins = np.full((Gs, M), B, np.int32)
-                starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-                pos = np.arange(len(row_id)) - starts[slot]
-                sp_rows[slot, pos] = row_id
-                sp_bins[slot, pos] = binval
-            F_ = self.num_features
-            is_sparse = np.zeros(F_, np.int32)
-            is_sparse[sparse_idx_cols] = 1
-            sparse_slot = np.zeros(F_, np.int32)
-            sparse_slot[sparse_idx_cols] = np.arange(Gs)
-            dense_col = np.zeros(F_, np.int32)
-            dense_col[dense_idx] = np.arange(gd)
-            meta_np["is_sparse"] = is_sparse
-            meta_np["sparse_slot"] = sparse_slot
-            meta_np["dense_col"] = dense_col
-            # a known-dense feature id: expand_sparse reads this
-            # feature's histogram for exact leaf totals (padded by the
-            # meta loop; only element 0 is read)
-            meta_np["dense_ref"] = np.full(F_, dense_idx[0], np.int32)
-            # feature -> slot in concat(dense columns, sparse groups);
-            # padding features (g_pad > F) point at a dense padding
-            # column — trivial (num_bin=1), never searched or split
-            perm = np.full(self.g_pad, min(gd, gd_pad - 1), np.int32)
-            perm[dense_idx] = np.arange(gd)
-            perm[sparse_idx_cols] = gd_pad + np.arange(Gs)
-            self._sparse_arrays = (sp_rows, sp_bins, perm)
-            Log.info(f"sparse storage: {Gs} of {F_} features as COO "
-                     f"({M} entry slots), dense matrix "
-                     f"{gd_pad}x{self.n_pad}")
-        else:
-            self._sparse_arrays = None
-            # partitioned: only this process's rows, at its local width
-            width = self._local_width if self._partitioned else self.n_pad
-            if (dev_src is not None and strategy == "serial"
-                    and not self.stream_layout):
-                # device-side layout: transpose + pad the device-
-                # resident ingest matrix in HBM — the host [n, F]
-                # matrix never exists on this path
-                bins_t = jnp.zeros(
-                    (self.g_pad, width),
-                    dtype=jnp.uint8 if B <= 256 else jnp.int32)
-                bins_t = bins_t.at[:self.num_columns, :n].set(
-                    dev_src.T.astype(bins_t.dtype))
-            else:
-                if cols_src is None:  # parallel placement ships host
-                    cols_src = train_data.bins
-                bins_t = np.zeros((self.g_pad, width), dtype=bin_dtype)
-                bins_t[:self.num_columns, :n] = cols_src.T
+                    g = host_allgather(
+                        np.concatenate([nz_counts, [n]]).astype(np.int32),
+                        name="sparse_global_fractions")
+                    tot = g.sum(axis=0)
+                    nz_counts, denom = tot[:-1], int(tot[-1])
+                nz_frac = nz_counts / max(denom, 1)
+                sp_mask = nz_frac <= sth
+                if sp_mask.all():
+                    # the dense kernel needs a nonempty matrix; keep the
+                    # densest feature dense
+                    sp_mask[int(np.argmax(nz_frac))] = False
+                if sp_mask.any():
+                    self._sparse_mask = sp_mask
 
-        # 4-bit packing (reference dense_nbits_bin.hpp): two rows per
-        # byte in a per-block stride layout (row j low nibble, row
-        # j + block/2 high nibble) so the pallas kernel unpacks with a
-        # nibble mask + lane concat.  Halves the row sweep's DMA traffic.
-        # the pack layout's blocks must coincide with the GROWER's blocks,
-        # which are derived from the PER-SHARD row count under data
-        # sharding — a global-block layout split across shards would
-        # decode the wrong rows silently
-        local_rows = self.n_pad // self.d_shards
-        eff_block = min(block, local_rows)
-        self.packed_bins = (
-            bool(config.tpu_pack_bins) and B <= 16
-            and not self.stream_layout
-            and hist_impl in ("pallas", "pallas2") and plan is None
-            and self._sparse_arrays is None and not self._partitioned
-            and str(config.tpu_partition_impl) in ("select", "vselect")
-            and eff_block % 256 == 0 and local_rows % eff_block == 0)
-        if self.packed_bins:
-            x = bins_t.reshape(self.g_pad, self.n_pad // eff_block, 2,
-                               eff_block // 2)
-            packed = (x[:, :, 0, :] | (x[:, :, 1, :] << 4)).reshape(
-                self.g_pad, self.n_pad // 2)
-            # device-laid-out bins_t packs in HBM; host arrays keep the
-            # contiguity the kernel's DMA expects
-            bins_t = (np.ascontiguousarray(packed)
-                      if isinstance(packed, np.ndarray) else packed)
+            # impl/block resolution happens HERE, once, with the final
+            # histogram shape: bundling above only needs the host bin matrix,
+            # while the padded row count below depends on the resolved block.
+            # (The perfeature kernel chunks the feature axis itself, so the
+            # VMEM fit depends only on the bin count, not the feature width.)
+            # persisted autotune profile (utils/autotune.py): measured winners
+            # for this (platform, device count, shape bucket) override the
+            # "auto" heuristics below; a stale profile (other topology) raises
+            # AutotuneStaleProfile here rather than training on wrong winners
+            self._autotune_entry = None
+            if str(config.tpu_autotune) != "off":
+                from ..utils.autotune import resolve_autotune
 
-        meta_host = {}
-        for k, v in meta_np.items():
-            pad_val = 1 if k == "num_bin" else (1.0 if k == "penalty" else 0)
-            if self.f_pad != self.num_features:
-                v = np.concatenate(
-                    [v, np.full(self.f_pad - self.num_features, pad_val,
-                                dtype=v.dtype)])
-            meta_host[k] = v
-
-        from ..parallel import topology as _topo
-
-        if strategy == "serial":
-            self.topology = None
-            self.mesh = None
-            _topo.activate(None)
-            self._place_serial_bins(bins_t, n)
-        else:
-            self.topology = _topo.make_topology(
-                num_data_shards=self.d_shards,
-                num_feature_shards=self.f_shards,
-                num_hosts=self.hosts,
-                partitioned_rows=self._partitioned)
-            _topo.activate(self.topology)
-            self.mesh = self.topology.mesh
-            if self._partitioned:
-                # each process contributes only ITS rows to the global
-                # arrays (reference pre_partition: rows never leave
-                # their machine)
-                self.bins_t = put_local(
-                    bins_t, bins_sharding(self.mesh, strategy),
-                    (bins_t.shape[0], self.n_pad))
-                ones = np.zeros(self._local_width, np.float32)
-                ones[:n] = 1.0
-                self._ones_host = ones
-                self._ones_mask = put_local(
-                    ones, rows_sharding(self.mesh, strategy),
-                    (self.n_pad,))
-            else:
-                self.bins_t = put_global(
-                    bins_t, bins_sharding(self.mesh, strategy))
-                ones = np.ones(self.n_pad, np.float32)
-                ones[n:] = 0.0
-                self._ones_host = ones
-                self._ones_mask = put_global(
-                    ones, rows_sharding(self.mesh, strategy))
-        self.n = n
-
-        meta_cast = {k: (v.astype(np.int32) if v.dtype != np.float32 else v)
-                     for k, v in meta_host.items()}
-        # multi-host mesh: every array entering the sharded grower must be
-        # a GLOBAL jax.Array; cache the shardings train() re-uses per tree
-        self._multiproc = self.mesh is not None and jax.process_count() > 1
-        # traced mode switches (ops/grower.py MF_*): the real boolean/
-        # scalar mode values ride this meta vector so ONE compiled grow
-        # program serves every combination; the GrowerParams fields they
-        # replace are canonicalized out of the grower cache key below
-        meta_cast["mode_flags"] = mode_flags_np(
-            quant_round=str(config.tpu_quant_round),
-            quant_refit=(quantized
-                         and bool(config.tpu_quant_refit_leaves)),
-            cegb_tradeoff=float(config.cegb_tradeoff),
-            cegb_penalty_split=float(config.cegb_penalty_split))
-        if self._multiproc:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            self._rep_sharding = NamedSharding(self.mesh, P())
-            self._rows_shard = rows_sharding(self.mesh, strategy)
-            self.meta = {k: put_global(v, self._rep_sharding)
-                         for k, v in meta_cast.items()}
-        else:
-            self.meta = {k: jnp.asarray(v) for k, v in meta_cast.items()}
-        if self._sparse_arrays is not None:
-            # COO tables ride meta like the CEGB state does (the pad
-            # loop above only handles per-feature vectors).  Data-
-            # sharded learners shard the per-shard leading axis at
-            # placement so no replicated->sharded reshard crosses the
-            # program boundary (the CPU gloo backend aborts on those)
-            sp_rows, sp_bins, perm = self._sparse_arrays
-            if self._multiproc:
-                from jax.sharding import NamedSharding
-                from jax.sharding import PartitionSpec as P_
-
-                from ..parallel.topology import ROW_AXES
-
-                shard3 = NamedSharding(self.mesh, P_(ROW_AXES))
-                if self._partitioned:
-                    # this process built only ITS shards' tables
-                    gshape = (self.d_shards,) + sp_rows.shape[1:]
-                    self.meta["sparse_idx"] = put_local(sp_rows, shard3,
-                                                        gshape)
-                    self.meta["sparse_bin"] = put_local(sp_bins, shard3,
-                                                        gshape)
+                self._autotune_entry = resolve_autotune(
+                    config, n, self.num_features, B, precision)
+            hist_impl, block = self._resolve_hist_impl(
+                config, B, precision, tuned=self._autotune_entry)
+            if hist_impl in ("pallas2", "fused"):
+                # the perfeature kernel chunks its feature grid in
+                # sublane-aligned (multiple-of-32) divisors (ops/histogram.py
+                # _hist_pallas); pad the histogram column axis so every width
+                # admits aligned chunks.  Padding columns hold constant bin 0
+                # (num_bin=1 features) and can never split.  Feature-parallel
+                # pads to 32 * n_shards so each shard's slice stays aligned
+                if self.f_shards > 1:
+                    a = 32 * self.f_shards
+                    self.f_pad = -(-self.f_pad // a) * a
+                    self.g_pad = self.f_pad
+                elif plan is None:
+                    self.f_pad = -(-self.f_pad // 32) * 32
+                    self.g_pad = self.f_pad
                 else:
-                    self.meta["sparse_idx"] = put_global(sp_rows, shard3)
-                    self.meta["sparse_bin"] = put_global(sp_bins, shard3)
-                self.meta["hist_perm"] = put_global(perm,
-                                                    self._rep_sharding)
+                    self.g_pad = -(-self.g_pad // 32) * 32
+            # ---- shape bucketing (compile-cache policy): quantize the padded
+            # axes so at most `tpu_shape_buckets` distinct shapes exist per
+            # power-of-2 octave — a new dataset of similar size then hits the
+            # persistent compilation cache instead of paying the 70-150 s
+            # cold remote compile (SURVEY §7 "dispatch overhead is the #1
+            # wall-clock risk").  Worst-case pad waste is 2/buckets (~6% at
+            # the default 32); 0 disables (exact block-multiple padding,
+            # maximum throughput — bench.py pins this).
+            buckets = int(config.tpu_shape_buckets)
+
+            def bucket_up(count: int, quantum: int) -> int:
+                padded = -(-count // quantum) * quantum
+                if buckets <= 0:
+                    return padded
+                q = quantum
+                while q * buckets < padded:
+                    q *= 2
+                return -(-count // q) * q
+
+            def bucket_rows(count: int) -> int:
+                # supra-block: quantize the BLOCK COUNT (pad_rows clamps the
+                # block to the row count, so derive the effective block the
+                # same way).  Sub-block (count < tpu_block_rows, the common
+                # case on TPU where the resolved block is 8-16k): quantize
+                # the row count itself from the 128-lane tile upward, capped
+                # at one block — without this, every sub-block n is its own
+                # XLA program
+                eff = min(block, max(count, 1))
+                base = pad_rows(count, block)
+                if buckets <= 0:
+                    return base
+                if base >= block:
+                    return bucket_up(base // eff, 1) * eff
+                return min(bucket_up(count, 128), block)
+
+            if self._partitioned:
+                # rows per shard must be UNIFORM across the whole mesh: size
+                # from the largest process's share (short ranks pad with
+                # masked rows); n here is only THIS process's row count
+                from ..parallel.topology import host_allgather
+
+                shards_local = self.d_shards // jax.process_count()
+                ns = host_allgather(np.asarray([n], np.int32),
+                                    name="shard_rows_sync")
+                max_shard_rows = -(-int(ns.max()) // shards_local)
+                self.n_pad = bucket_rows(max_shard_rows) * self.d_shards
+                self._local_width = ((self.n_pad // self.d_shards)
+                                     * shards_local)
+            elif self.d_shards > 1:
+                # every shard holds an equal, whole number of histogram blocks
+                self.n_pad = bucket_rows(
+                    (n + self.d_shards - 1) // self.d_shards) * self.d_shards
             else:
-                self.meta["sparse_idx"] = jnp.asarray(sp_rows)
-                self.meta["sparse_bin"] = jnp.asarray(sp_bins)
-                self.meta["hist_perm"] = jnp.asarray(perm)
-        if self.hist_agg == "scatter" and plan is not None:
-            # static shard -> feature-ids table for the scattered EFB
-            # search: shard d owns bundle columns [d*SGc, (d+1)*SGc) and
-            # therefore exactly the features bundled into them.  Rows are
-            # ascending (so the per-shard argmax keeps the lowest-feature
-            # tie-break) and -1-padded to the widest shard's count.
-            sgc = self.g_pad // self.d_shards
-            bidx = meta_np["bundle_idx"][:self.num_features]
-            by_shard = [np.sort(np.flatnonzero(bidx // sgc == d))
-                        for d in range(self.d_shards)]
-            sf = np.full((self.d_shards,
-                          max(1, max(len(l) for l in by_shard))), -1,
-                         np.int32)
-            for d, l in enumerate(by_shard):
-                sf[d, :len(l)] = l
-            self.meta["scatter_feat"] = (
-                put_global(sf, self._rep_sharding) if self._multiproc
-                else jnp.asarray(sf))
-        timer.add("layout", time.perf_counter() - _t_layout)
+                self.n_pad = bucket_rows(n)
+            # feature axis: bucket above the alignment the padding code above
+            # already established (32-multiples for pallas2, shard-count
+            # multiples for feature sharding); padding features are trivial
+            # (num_bin=1) and can never split
+            if buckets > 0:
+                if hist_impl == "pallas2":
+                    align = 32 * self.f_shards if self.f_shards > 1 else 32
+                else:
+                    align = self.f_shards if self.f_shards > 1 else 8
+                if self.g_pad == self.f_pad:
+                    self.f_pad = bucket_up(self.f_pad, align)
+                    self.g_pad = self.f_pad
+                else:
+                    # EFB keeps g_pad (bundle columns) separate from f_pad
+                    self.g_pad = bucket_up(self.g_pad, align)
+
+            # ---- scatter-aggregation alignment (tpu_hist_agg=scatter): the
+            # reduce-scatter hands shard d a contiguous 1/P slice of the
+            # histogram column axis, so that axis must divide by the data-
+            # shard count — on top of whatever alignment feature sharding /
+            # the pallas2 kernel already demanded.  Padding columns/features
+            # are trivial (num_bin=1) and can never split.  Voting scatters
+            # only the voted [k, B, 3] block (padded inside the grower).
+            if self.hist_agg == "scatter" and strategy != "voting":
+                import math
+
+                if plan is None:
+                    a = self.f_shards * self.d_shards
+                    if hist_impl == "pallas2":
+                        a = math.lcm(a, 32 * max(self.f_shards, 1))
+                    self.f_pad = -(-self.f_pad // a) * a
+                    self.g_pad = self.f_pad
+                else:
+                    # EFB: only the bundle-column axis scatters; the shard ->
+                    # feature assignment rides the scatter_feat table below
+                    a = self.d_shards
+                    if hist_impl == "pallas2":
+                        a = math.lcm(a, 32)
+                    self.g_pad = -(-self.g_pad // a) * a
+
+            # transposed [G, n] bin matrix: rows ride the 128-lane minor axis
+            # for the histogram contraction (see ops/histogram.py).  Stored
+            # uint8 when bins fit (the reference's narrow dense bins,
+            # dense_bin.hpp / dense_nbits_bin.hpp): the matrix is re-read every
+            # grower round, so width directly scales histogram HBM traffic;
+            # the one-hot compare upcasts on the fly
+            bin_dtype = np.uint8 if B <= 256 else np.int32
+            if self._sparse_mask is not None:
+                if cols_src is None:  # COO packing reads host columns
+                    cols_src = train_data.bins
+                    dev_src = None
+                dense_idx = np.flatnonzero(~self._sparse_mask)
+                sparse_idx_cols = np.flatnonzero(self._sparse_mask)
+                gd = len(dense_idx)
+                # the perfeature pallas kernel chunks its feature grid in
+                # 32-multiples — align the DENSE matrix width; the sparse
+                # groups never enter that kernel
+                gd_pad = -(-gd // 32) * 32 if hist_impl == "pallas2" else gd
+                width_sp = (self._local_width if self._partitioned
+                            else self.n_pad)
+                bins_t = np.zeros((gd_pad, width_sp), dtype=bin_dtype)
+                bins_t[:gd, :n] = cols_src[:, dense_idx].T
+                zb_np = meta_np["default_bin"]
+                Gs = len(sparse_idx_cols)
+                # ONE vectorized nonzero pass over the sparse columns,
+                # column-blocked to bound the boolean temporary; entries
+                # come out sorted by (slot, row), exactly the order the
+                # per-column scans produced
+                slot_parts, row_parts, bin_parts = [], [], []
+                blk = max((1 << 28) // max(n, 1), 1)
+                for lo_c in range(0, Gs, blk):
+                    cols = sparse_idx_cols[lo_c:lo_c + blk]
+                    sub = cols_src[:, cols]
+                    g_i, r_i = np.nonzero((sub != zb_np[cols][None, :]).T)
+                    slot_parts.append((g_i + lo_c).astype(np.int64))
+                    row_parts.append(r_i.astype(np.int64))
+                    bin_parts.append(sub[r_i, g_i].astype(np.int32))
+                slot = (np.concatenate(slot_parts) if slot_parts
+                        else np.zeros(0, np.int64))
+                row_id = (np.concatenate(row_parts) if row_parts
+                          else np.zeros(0, np.int64))
+                binval = (np.concatenate(bin_parts) if bin_parts
+                          else np.zeros(0, np.int32))
+                # pad row-id = the (local) width (out of range: the
+                # partition scatter drops it); pad bin = B (its one-hot row
+                # is all-zero, so the clipped histogram gather contributes
+                # nothing)
+                if self.d_shards > 1:
+                    # data sharding: per-SHARD tables with shard-local row
+                    # ids — the leading axis shards over 'data' so each
+                    # device holds only its block, and the sparse
+                    # contraction psums like the dense one.  Partitioned
+                    # ingest: this process's local rows cover exactly its
+                    # own shards, so it builds [shards_local, Gs, M] and
+                    # contributes them via put_local; the entry capacity M
+                    # must still be the GLOBAL max.
+                    rps = self.n_pad // self.d_shards
+                    sl = (self.d_shards // jax.process_count()
+                          if self._partitioned else self.d_shards)
+                    shard = row_id // rps
+                    key = shard * Gs + slot
+                    counts = np.bincount(key, minlength=sl * Gs)
+                    max_nnz = int(counts.max()) if counts.size else 0
+                    if self._partitioned:
+                        from ..parallel.topology import host_allgather
+
+                        max_nnz = int(host_allgather(
+                            np.asarray([max_nnz], np.int32),
+                            name="sparse_table_width").max())
+                    M = max(128, -(-max_nnz // 128) * 128)
+                    sp_rows = np.full((sl, Gs, M), rps, np.int32)
+                    sp_bins = np.full((sl, Gs, M), B, np.int32)
+                    # stable sort by (shard, slot) keeps rows ascending
+                    # within each table row, like the per-shard slices did
+                    order = np.argsort(key, kind="stable")
+                    k_s = key[order]
+                    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+                    pos = np.arange(len(k_s)) - starts[k_s]
+                    sp_rows[shard[order], slot[order], pos] = \
+                        row_id[order] - shard[order] * rps
+                    sp_bins[shard[order], slot[order], pos] = binval[order]
+                else:
+                    counts = np.bincount(slot, minlength=Gs)
+                    max_nnz = int(counts.max()) if counts.size else 0
+                    M = max(128, -(-max_nnz // 128) * 128)
+                    sp_rows = np.full((Gs, M), self.n_pad, np.int32)
+                    sp_bins = np.full((Gs, M), B, np.int32)
+                    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+                    pos = np.arange(len(row_id)) - starts[slot]
+                    sp_rows[slot, pos] = row_id
+                    sp_bins[slot, pos] = binval
+                F_ = self.num_features
+                is_sparse = np.zeros(F_, np.int32)
+                is_sparse[sparse_idx_cols] = 1
+                sparse_slot = np.zeros(F_, np.int32)
+                sparse_slot[sparse_idx_cols] = np.arange(Gs)
+                dense_col = np.zeros(F_, np.int32)
+                dense_col[dense_idx] = np.arange(gd)
+                meta_np["is_sparse"] = is_sparse
+                meta_np["sparse_slot"] = sparse_slot
+                meta_np["dense_col"] = dense_col
+                # a known-dense feature id: expand_sparse reads this
+                # feature's histogram for exact leaf totals (padded by the
+                # meta loop; only element 0 is read)
+                meta_np["dense_ref"] = np.full(F_, dense_idx[0], np.int32)
+                # feature -> slot in concat(dense columns, sparse groups);
+                # padding features (g_pad > F) point at a dense padding
+                # column — trivial (num_bin=1), never searched or split
+                perm = np.full(self.g_pad, min(gd, gd_pad - 1), np.int32)
+                perm[dense_idx] = np.arange(gd)
+                perm[sparse_idx_cols] = gd_pad + np.arange(Gs)
+                self._sparse_arrays = (sp_rows, sp_bins, perm)
+                Log.info(f"sparse storage: {Gs} of {F_} features as COO "
+                         f"({M} entry slots), dense matrix "
+                         f"{gd_pad}x{self.n_pad}")
+            else:
+                self._sparse_arrays = None
+                # partitioned: only this process's rows, at its local width
+                width = self._local_width if self._partitioned else self.n_pad
+                if (dev_src is not None and strategy == "serial"
+                        and not self.stream_layout):
+                    # device-side layout: transpose + pad the device-
+                    # resident ingest matrix in HBM — the host [n, F]
+                    # matrix never exists on this path
+                    bins_t = jnp.zeros(
+                        (self.g_pad, width),
+                        dtype=jnp.uint8 if B <= 256 else jnp.int32)
+                    bins_t = bins_t.at[:self.num_columns, :n].set(
+                        dev_src.T.astype(bins_t.dtype))
+                else:
+                    if cols_src is None:  # parallel placement ships host
+                        cols_src = train_data.bins
+                    bins_t = np.zeros((self.g_pad, width), dtype=bin_dtype)
+                    bins_t[:self.num_columns, :n] = cols_src.T
+
+            # 4-bit packing (reference dense_nbits_bin.hpp): two rows per
+            # byte in a per-block stride layout (row j low nibble, row
+            # j + block/2 high nibble) so the pallas kernel unpacks with a
+            # nibble mask + lane concat.  Halves the row sweep's DMA traffic.
+            # the pack layout's blocks must coincide with the GROWER's blocks,
+            # which are derived from the PER-SHARD row count under data
+            # sharding — a global-block layout split across shards would
+            # decode the wrong rows silently
+            local_rows = self.n_pad // self.d_shards
+            eff_block = min(block, local_rows)
+            self.packed_bins = (
+                bool(config.tpu_pack_bins) and B <= 16
+                and not self.stream_layout
+                and hist_impl in ("pallas", "pallas2") and plan is None
+                and self._sparse_arrays is None and not self._partitioned
+                and str(config.tpu_partition_impl) in ("select", "vselect")
+                and eff_block % 256 == 0 and local_rows % eff_block == 0)
+            if self.packed_bins:
+                x = bins_t.reshape(self.g_pad, self.n_pad // eff_block, 2,
+                                   eff_block // 2)
+                packed = (x[:, :, 0, :] | (x[:, :, 1, :] << 4)).reshape(
+                    self.g_pad, self.n_pad // 2)
+                # device-laid-out bins_t packs in HBM; host arrays keep the
+                # contiguity the kernel's DMA expects
+                bins_t = (np.ascontiguousarray(packed)
+                          if isinstance(packed, np.ndarray) else packed)
+
+            meta_host = {}
+            for k, v in meta_np.items():
+                pad_val = (1 if k == "num_bin"
+                           else 1.0 if k == "penalty" else 0)
+                if self.f_pad != self.num_features:
+                    v = np.concatenate(
+                        [v, np.full(self.f_pad - self.num_features, pad_val,
+                                    dtype=v.dtype)])
+                meta_host[k] = v
+
+            from ..parallel import topology as _topo
+
+            if strategy == "serial":
+                self.topology = None
+                self.mesh = None
+                _topo.activate(None)
+                self._place_serial_bins(bins_t, n)
+            else:
+                self.topology = _topo.make_topology(
+                    num_data_shards=self.d_shards,
+                    num_feature_shards=self.f_shards,
+                    num_hosts=self.hosts,
+                    partitioned_rows=self._partitioned)
+                _topo.activate(self.topology)
+                self.mesh = self.topology.mesh
+                if self._partitioned:
+                    # each process contributes only ITS rows to the global
+                    # arrays (reference pre_partition: rows never leave
+                    # their machine)
+                    self.bins_t = put_local(
+                        bins_t, bins_sharding(self.mesh, strategy),
+                        (bins_t.shape[0], self.n_pad))
+                    ones = np.zeros(self._local_width, np.float32)
+                    ones[:n] = 1.0
+                    self._ones_host = ones
+                    self._ones_mask = put_local(
+                        ones, rows_sharding(self.mesh, strategy),
+                        (self.n_pad,))
+                else:
+                    self.bins_t = put_global(
+                        bins_t, bins_sharding(self.mesh, strategy))
+                    ones = np.ones(self.n_pad, np.float32)
+                    ones[n:] = 0.0
+                    self._ones_host = ones
+                    self._ones_mask = put_global(
+                        ones, rows_sharding(self.mesh, strategy))
+            self.n = n
+
+            meta_cast = {k: (v.astype(np.int32) if v.dtype != np.float32
+                             else v)
+                         for k, v in meta_host.items()}
+            # multi-host mesh: every array entering the sharded grower must be
+            # a GLOBAL jax.Array; cache the shardings train() re-uses per tree
+            self._multiproc = self.mesh is not None and jax.process_count() > 1
+            # traced mode switches (ops/grower.py MF_*): the real boolean/
+            # scalar mode values ride this meta vector so ONE compiled grow
+            # program serves every combination; the GrowerParams fields they
+            # replace are canonicalized out of the grower cache key below
+            meta_cast["mode_flags"] = mode_flags_np(
+                quant_round=str(config.tpu_quant_round),
+                quant_refit=(quantized
+                             and bool(config.tpu_quant_refit_leaves)),
+                cegb_tradeoff=float(config.cegb_tradeoff),
+                cegb_penalty_split=float(config.cegb_penalty_split))
+            if self._multiproc:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                self._rep_sharding = NamedSharding(self.mesh, P())
+                self._rows_shard = rows_sharding(self.mesh, strategy)
+                self.meta = {k: put_global(v, self._rep_sharding)
+                             for k, v in meta_cast.items()}
+            else:
+                self.meta = {k: jnp.asarray(v) for k, v in meta_cast.items()}
+            if self._sparse_arrays is not None:
+                # COO tables ride meta like the CEGB state does (the pad
+                # loop above only handles per-feature vectors).  Data-
+                # sharded learners shard the per-shard leading axis at
+                # placement so no replicated->sharded reshard crosses the
+                # program boundary (the CPU gloo backend aborts on those)
+                sp_rows, sp_bins, perm = self._sparse_arrays
+                if self._multiproc:
+                    from jax.sharding import NamedSharding
+                    from jax.sharding import PartitionSpec as P_
+
+                    from ..parallel.topology import ROW_AXES
+
+                    shard3 = NamedSharding(self.mesh, P_(ROW_AXES))
+                    if self._partitioned:
+                        # this process built only ITS shards' tables
+                        gshape = (self.d_shards,) + sp_rows.shape[1:]
+                        self.meta["sparse_idx"] = put_local(sp_rows, shard3,
+                                                            gshape)
+                        self.meta["sparse_bin"] = put_local(sp_bins, shard3,
+                                                            gshape)
+                    else:
+                        self.meta["sparse_idx"] = put_global(sp_rows, shard3)
+                        self.meta["sparse_bin"] = put_global(sp_bins, shard3)
+                    self.meta["hist_perm"] = put_global(perm,
+                                                        self._rep_sharding)
+                else:
+                    self.meta["sparse_idx"] = jnp.asarray(sp_rows)
+                    self.meta["sparse_bin"] = jnp.asarray(sp_bins)
+                    self.meta["hist_perm"] = jnp.asarray(perm)
+            if self.hist_agg == "scatter" and plan is not None:
+                # static shard -> feature-ids table for the scattered EFB
+                # search: shard d owns bundle columns [d*SGc, (d+1)*SGc) and
+                # therefore exactly the features bundled into them.  Rows are
+                # ascending (so the per-shard argmax keeps the lowest-feature
+                # tie-break) and -1-padded to the widest shard's count.
+                sgc = self.g_pad // self.d_shards
+                bidx = meta_np["bundle_idx"][:self.num_features]
+                by_shard = [np.sort(np.flatnonzero(bidx // sgc == d))
+                            for d in range(self.d_shards)]
+                sf = np.full((self.d_shards,
+                              max(1, max(len(l) for l in by_shard))), -1,
+                             np.int32)
+                for d, l in enumerate(by_shard):
+                    sf[d, :len(l)] = l
+                self.meta["scatter_feat"] = (
+                    put_global(sf, self._rep_sharding) if self._multiproc
+                    else jnp.asarray(sf))
 
         self.params = GrowerParams(
             num_leaves=max(int(config.num_leaves), 2),
@@ -1232,22 +1237,21 @@ class TPUTreeLearner:
             # backend aborts on — and multi-chip wants the fusion anyway.
             # Only goss_on stays static (its sort is structural work);
             # the grower's own ledgered jit donates the pool here and
-            # post donates the scores buffer.  pre/post stay OFF the
-            # ledger by design: they are per-objective closures (label
-            # arrays captured) that re-trace per Booster in milliseconds
-            # — the ledger tracks the programs that dominate compile wall
-            # (grower, fused step, predict/binning/histogram kernels).
-            pre_j = jax.jit(_pre, static_argnames=("goss_on",))  # graftlint: disable=J201 per-objective closure, deliberately off-ledger (see comment above)
-            post_j = jax.jit(_post,  # graftlint: disable=J201 per-objective closure, deliberately off-ledger (see comment above)
-                             donate_argnums=((0,) if donate else ()))
+            # post donates the scores buffer.  pre/post are per-objective
+            # closures (label arrays captured as constants), so each
+            # Booster traces and compiles its own pair, and a new
+            # dataset misses the persistent cache: ~20 s for pre at
+            # 27M rows on a v5e (PERF.md, PR 22 finding 3).
+            pre_j = ledger_jit(_pre, site="learner.pre",
+                               static_argnames=("goss_on",))
+            post_j = ledger_jit(_post, site="learner.post",
+                                donate_argnums=((0,) if donate else ()))
             return make_step(pre_j, post_j)
         # exact-shape mode (tpu_shape_buckets=0): ONE fused program —
         # the round-3 hardware-validated hot path, bit-identical.
         # Donation at the fused boundary: scores (arg 1) and the pool
         # (arg 4) are rewritten in place by XLA.  The fused jit is the
         # ledger site here (the grower's own jit is traced inline).
-        from ..utils.compile_ledger import ledger_jit
-
         dn = []
         if donate:
             dn.append(1)
@@ -1511,6 +1515,7 @@ def make_tree_learner(config: Config,
     budget AND the run is streamable."""
     from ..utils import membudget
 
-    if membudget.select_layout(config, train_data) == "streamed":
-        return StreamedTreeLearner(config, train_data)
-    return TPUTreeLearner(config, train_data)
+    with obs.span("learner/init"):
+        if membudget.select_layout(config, train_data) == "streamed":
+            return StreamedTreeLearner(config, train_data)
+        return TPUTreeLearner(config, train_data)

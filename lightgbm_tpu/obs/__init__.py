@@ -10,7 +10,8 @@ One import surface for every instrumented layer::
   fixed-bucket histograms; Prometheus text export).
 * `span(name, **tags)` — nested structured span (Chrome-trace/Perfetto
   export, JSONL stream, jax TraceAnnotation mirror); null when
-  ``tpu_telemetry`` != trace.
+  ``tpu_telemetry`` != trace.  Every span and event carries an ``id``
+  and its ``parent_id``; `origin_ns()` is the clock origin of ``ts``.
 * `timed(name)` — registry-backed stopwatch (the bench's segment timer).
 * `configure` / `configure_from_config` — process-global policy from
   ``tpu_telemetry`` (off | metrics | trace), ``tpu_trace_dir`` and the
@@ -33,6 +34,6 @@ from . import flightrecorder, modelhealth, resources  # noqa: F401
 from .metrics import (DEFAULT_SECONDS_BUCKETS, MetricsRegistry,  # noqa: F401
                       REGISTRY, histogram_quantile)
 from .trace import (chrome_trace, configure, configure_from_config,  # noqa: F401
-                    event, events, flush, metrics_on, mode,
-                    reset_events, span, timed, trace_dir, tracing_on,
-                    write_chrome_trace)
+                    event, events, flush, metrics_on, mode, origin_ns,
+                    reset_events, span, span_ended, timed, trace_dir,
+                    tracing_on, write_chrome_trace)

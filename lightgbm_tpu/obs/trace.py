@@ -30,6 +30,7 @@ bit-identical with tracing on or off.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import sys
@@ -62,6 +63,9 @@ _tls = threading.local()
 # perf_counter origin: every ts is µs since process telemetry start so
 # Chrome/Perfetto timelines start near zero
 _T0_NS = time.perf_counter_ns()
+
+# span/event identifiers, process-wide (next() on a count is atomic)
+_ids = itertools.count(1)
 
 _stream_lock = threading.Lock()
 _stream = None          # open JSONL file handle
@@ -168,6 +172,13 @@ def tracing_on() -> bool:
 # ---------------------------------------------------------------------------
 # spans
 # ---------------------------------------------------------------------------
+def origin_ns() -> int:
+    """The `time.perf_counter_ns()` reading every ``ts`` counts from: a
+    reader puts a span on the host clock as ``origin_ns() / 1e9 + ts /
+    1e6`` seconds of `time.perf_counter`."""
+    return _T0_NS
+
+
 def _now_us() -> float:
     return (time.perf_counter_ns() - _T0_NS) / 1e3
 
@@ -179,21 +190,39 @@ def _stack() -> list:
     return st
 
 
+def _rec(kind: str, name: str, ph: str, ts: float, dur: float,
+         span_id: int, parent_id: Optional[int], tags: Dict) -> Dict:
+    return {"kind": kind, "name": name, "ph": ph, "ts": ts, "dur": dur,
+            "id": span_id, "parent_id": parent_id,
+            "host": _host_index(), "tid": threading.get_ident() % 100000,
+            "tags": tags}
+
+
+def _adopt(tags: Dict) -> Optional[int]:
+    """Tag a new span with the name of the span this thread has open
+    and its own depth; returns that parent's id (None at the root)."""
+    st = _stack()
+    if st:
+        tags.setdefault("parent", st[-1].name)
+    tags.setdefault("depth", len(st))
+    return st[-1].id if st else None
+
+
 class _Span:
-    __slots__ = ("name", "tags", "t0", "_ann")
+    __slots__ = ("name", "tags", "t0", "id", "parent_id", "_ann")
 
     def __init__(self, name: str, tags: Dict):
         self.name = name
         self.tags = tags
         self.t0 = 0.0
+        self.id = 0
+        self.parent_id = None
         self._ann = None
 
     def __enter__(self) -> "_Span":
-        st = _stack()
-        if st:
-            self.tags.setdefault("parent", st[-1].name)
-        self.tags.setdefault("depth", len(st))
-        st.append(self)
+        self.id = next(_ids)
+        self.parent_id = _adopt(self.tags)
+        _stack().append(self)
         ann_cls = _annotation_cls()
         if ann_cls is not None:
             try:
@@ -214,12 +243,8 @@ class _Span:
         st = _stack()
         if st and st[-1] is self:
             st.pop()
-        _record({
-            "kind": "span", "name": self.name, "ph": "X",
-            "ts": self.t0, "dur": dur,
-            "host": _host_index(), "tid": threading.get_ident() % 100000,
-            "tags": self.tags,
-        })
+        _record(_rec("span", self.name, "X", self.t0, dur, self.id,
+                     self.parent_id, self.tags))
 
 
 def span(_name: str, **tags):
@@ -239,12 +264,21 @@ def event(_name: str, **fields) -> None:
     record, not the count."""
     if not _TRACE:
         return
-    _record({
-        "kind": "event", "name": _name, "ph": "i",
-        "ts": _now_us(), "dur": 0.0,
-        "host": _host_index(), "tid": threading.get_ident() % 100000,
-        "tags": fields,
-    })
+    st = _stack()
+    _record(_rec("event", _name, "i", _now_us(), 0.0, next(_ids),
+                 st[-1].id if st else None, fields))
+
+
+def span_ended(_name: str, seconds: float, **tags) -> None:
+    """A span that ends now and lasted `seconds`, for work whose length
+    is only reported once it is over (a compile, by `jax.monitoring`).
+    A child of whatever span this thread has open; no profiler mirror,
+    since its start is already past."""
+    if not _TRACE:
+        return
+    dur = seconds * 1e6
+    _record(_rec("span", _name, "X", _now_us() - dur, dur, next(_ids),
+                 _adopt(tags), tags))
 
 
 @contextlib.contextmanager
@@ -292,7 +326,8 @@ def _stream_write(ev: Dict) -> None:
     global _stream, _stream_path
     line = json.dumps({
         "kind": ev["kind"], "name": ev["name"], "ts_us": round(ev["ts"], 3),
-        "dur_us": round(ev["dur"], 3), "host": ev["host"],
+        "dur_us": round(ev["dur"], 3), "id": ev["id"],
+        "parent_id": ev["parent_id"], "host": ev["host"],
         "tid": ev["tid"], "tags": ev["tags"],
     })
     with _stream_lock:
@@ -338,7 +373,9 @@ def chrome_trace() -> Dict:
     for ev in evs:
         rec = {"name": ev["name"], "ph": ev["ph"],
                "ts": round(ev["ts"], 3), "pid": ev["host"],
-               "tid": ev["tid"], "args": dict(ev["tags"])}
+               "tid": ev["tid"],
+               "args": dict(ev["tags"], id=ev["id"],
+                            parent_id=ev["parent_id"])}
         if ev["ph"] == "X":
             rec["dur"] = round(ev["dur"], 3)
         else:

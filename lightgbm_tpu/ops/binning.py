@@ -40,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..io.bin_mapper import BinMapper, BinType, MissingType, sort_keys
 from ..utils import membudget
 from ..utils.compile_ledger import ledger_jit
@@ -111,6 +112,7 @@ class DeviceBinner:
         self._cat_widths = tables["cat_width"].copy() if has_cat else None
         self._is_cat = tables["is_cat"].copy() if has_cat else None
         self._dev_tables = {k: jnp.asarray(v) for k, v in tables.items()}
+        self._launches = 0  # kernel launches so far: the spans' chunk tag
 
     # ------------------------------------------------------------------
     @classmethod
@@ -259,13 +261,16 @@ class DeviceBinner:
         if pad:
             block = np.concatenate(
                 [block, np.zeros((pad, block.shape[1]), block.dtype)])
-        vhi, vlo, cv = self._prep_chunk(block)
+        chunk, self._launches = self._launches, self._launches + 1
+        with obs.span("ingest/stage", chunk=chunk, rows=rows):
+            vhi, vlo, cv = self._prep_chunk(block)
         dummy = np.zeros((0,), np.int32)
-        out = _bin_chunk_kernel(
-            jnp.asarray(vhi), jnp.asarray(vlo),
-            jnp.asarray(cv) if cv is not None else jnp.asarray(dummy),
-            self._dev_tables, self.has_cat, str(self.out_dtype))
-        return out[:rows] if pad else out
+        with obs.span("ingest/dispatch", chunk=chunk):
+            out = _bin_chunk_kernel(
+                jnp.asarray(vhi), jnp.asarray(vlo),
+                jnp.asarray(cv) if cv is not None else jnp.asarray(dummy),
+                self._dev_tables, self.has_cat, str(self.out_dtype))
+            return out[:rows] if pad else out
 
     def bin_matrix(self, X: np.ndarray) -> jnp.ndarray:
         """Stream X's used columns through the kernel chunk by chunk.

@@ -25,6 +25,19 @@ transparent — `lower`, `_cache_size`, etc. delegate to the underlying
 jitted callable, so call sites and tests that poke at jit internals
 keep working.
 
+While enabled the ledger also listens to `jax.monitoring`: JAX reports
+one backend-compile duration for every program it has to produce, the
+compiler's work or the persistent cache's answer (the cache's own hit
+event, fired inside that duration on the same thread, tells the two
+apart).  Each is charged to the `ledger_jit` site whose call is in
+flight on that thread, or to `"(none)"` outside any site (eager `jnp`
+ops, a bare `jax.jit`), with the name JAX gives the program, and becomes
+a row of `compiles()`, a count and seconds in
+``lgbm_compile_programs_total`` / ``lgbm_compile_seconds_total``
+``{site,cache}``, and under ``tpu_telemetry=trace`` one ``compile`` span.
+`programs()`'s ``first_call_s`` is a call's wall (trace, compile AND the
+first execution); ``compile_s`` here is the compile alone.
+
 The module-level `LEDGER` singleton is the process-wide audit surface:
 
     from lightgbm_tpu.utils.compile_ledger import LEDGER
@@ -32,6 +45,7 @@ The module-level `LEDGER` singleton is the process-wide audit surface:
     ... train / predict / serve ...
     LEDGER.n_programs()        # the n_programs bench metric
     LEDGER.report()            # per-site breakdown
+    LEDGER.compiles()          # every program produced, by site
 """
 
 from __future__ import annotations
@@ -41,6 +55,27 @@ import time
 from typing import Any, Dict, List, Optional
 
 import jax
+from jax import monitoring
+
+from .. import obs
+
+# the two jax.monitoring events the attribution reads (jax 0.9:
+# interpreters/pxla.py _cached_compilation, compiler.py
+# compile_or_get_cached)
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+NO_SITE = "(none)"
+
+# per thread: the ledger_jit sites whose calls are in flight, and
+# whether the persistent cache answered the program now being produced
+_tls = threading.local()
+
+
+def _site_stack() -> List[str]:
+    st = getattr(_tls, "sites", None)
+    if st is None:
+        st = _tls.sites = []
+    return st
 
 
 def _describe_leaf(x: Any) -> str:
@@ -116,6 +151,7 @@ class CompileLedger:
         self._enabled = False
         self._capture = False
         self._programs: List[Dict] = []
+        self._compiles: List[Dict] = []
 
     # -- control -------------------------------------------------------
     @property
@@ -123,7 +159,19 @@ class CompileLedger:
         return self._enabled
 
     def enable(self, on: bool = True) -> None:
-        self._enabled = bool(on)
+        """Switch the ledger, and with it the `jax.monitoring`
+        listeners: none is registered while it is off."""
+        on = bool(on)
+        with self._lock:
+            if on and not self._enabled:
+                monitoring.register_event_listener(self._on_event)
+                monitoring.register_event_duration_secs_listener(
+                    self._on_duration)
+            elif self._enabled and not on:
+                monitoring.unregister_event_listener(self._on_event)
+                monitoring.unregister_event_duration_listener(
+                    self._on_duration)
+            self._enabled = on
 
     @property
     def capture_costs(self) -> bool:
@@ -140,6 +188,40 @@ class CompileLedger:
     def reset(self) -> None:
         with self._lock:
             self._programs = []
+            self._compiles = []
+
+    # -- compile attribution (called by jax.monitoring) -----------------
+    def _on_event(self, event: str, **_) -> None:
+        if event == _CACHE_HIT:
+            _tls.cache_hit = True
+
+    def _on_duration(self, event: str, seconds: float, **kw) -> None:
+        if event != _BACKEND_COMPILE:
+            return
+        cache = "hit" if getattr(_tls, "cache_hit", False) else "miss"
+        _tls.cache_hit = False
+        sites = _site_stack()
+        site = sites[-1] if sites else NO_SITE
+        fun_name = str(kw.get("fun_name", ""))
+        with self._lock:
+            self._compiles.append({"site": site, "fun_name": fun_name,
+                                   "compile_s": seconds, "cache": cache})
+        obs.REGISTRY.inc("lgbm_compile_seconds_total", seconds,
+                         help="seconds JAX spent producing programs, "
+                              "by ledger site and persistent-cache answer",
+                         site=site, cache=cache)
+        obs.REGISTRY.inc("lgbm_compile_programs_total", 1,
+                         help="programs JAX produced (compiled or loaded)",
+                         site=site, cache=cache)
+        obs.span_ended("compile", seconds, site=site, fun_name=fun_name,
+                       cache=cache)
+
+    def compiles(self) -> List[Dict]:
+        """Every program JAX produced while enabled, in order: `site`,
+        `fun_name`, `compile_s` (compile or cache load, no execution),
+        `cache` ("hit" | "miss")."""
+        with self._lock:
+            return [dict(c) for c in self._compiles]
 
     # -- recording (called by LedgeredJit) ------------------------------
     def record(self, site: str, signature: str, wall_s: float,
@@ -320,10 +402,15 @@ class LedgeredJit:
     def __call__(self, *args, **kwargs):
         if not LEDGER.enabled:
             return self._fn(*args, **kwargs)
+        sites = _site_stack()
         with self._lock:
             before = self._fn._cache_size()
             t0 = time.perf_counter()
-            out = self._fn(*args, **kwargs)
+            sites.append(self.site)
+            try:
+                out = self._fn(*args, **kwargs)
+            finally:
+                sites.pop()
             if self._fn._cache_size() > before:
                 LEDGER.record(self.site, call_signature(args, kwargs),
                               time.perf_counter() - t0,

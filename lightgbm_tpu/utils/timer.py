@@ -8,40 +8,29 @@ unified telemetry layer (`lightgbm_tpu.obs`):
 
 * phase walls accumulate into the process-global registry as
   ``lgbm_phase_seconds_total{phase=...}`` / ``lgbm_phase_runs_total``
-  whenever telemetry (`tpu_telemetry=metrics|trace`) OR the legacy
-  LIGHTGBM_TPU_TIMETAG switch is on — `summary()` reads the registry,
-  so bench and the Prometheus export see the SAME numbers;
+  whenever telemetry (`tpu_telemetry=metrics|trace`) is on or a probe
+  called `enable()` — `summary()` reads the registry, so bench and the
+  Prometheus export see the SAME numbers;
 * under ``tpu_telemetry=trace`` each block is additionally a structured
-  span (Chrome-trace/Perfetto export + xprof mirror via obs.span);
-* `print_summary()` (atexit when LIGHTGBM_TPU_TIMETAG=1) prints the
-  table, like the reference's Log::Info TIMETAG dumps;
-* `trace(dir)` wraps a block in jax.profiler.trace for xprof/tensorboard
-  inspection of the on-device schedule.
+  span (Chrome-trace/Perfetto export + xprof mirror via obs.span).
 
 When everything is off a PHASE block costs one flag check.
 """
 
 from __future__ import annotations
 
-import atexit
 import contextlib
-import os
 import time
 from typing import Dict, Iterator
 
 from ..obs import REGISTRY, span
 from ..obs import metrics_on as _obs_metrics_on
 from ..obs import resources as _resources
-from .log import Log
 
-_enabled = os.environ.get("LIGHTGBM_TPU_TIMETAG", "") not in ("", "0")
+_enabled = False
 
 _SECONDS = "lgbm_phase_seconds_total"
 _RUNS = "lgbm_phase_runs_total"
-
-
-def enabled() -> bool:
-    return _enabled or _obs_metrics_on()
 
 
 def enable(on: bool = True) -> None:
@@ -83,19 +72,9 @@ def PHASE(name: str) -> Iterator[None]:
         _record(name, time.perf_counter() - t0)
 
 
-def add(name: str, seconds: float) -> None:
-    if _enabled or _obs_metrics_on():
-        _record(name, seconds)
-
-
 def summary() -> Dict[str, float]:
     return {p: REGISTRY.value(_SECONDS, phase=p)
             for p in REGISTRY.label_values(_SECONDS, "phase")}
-
-
-def counts() -> Dict[str, int]:
-    return {p: int(REGISTRY.value(_RUNS, phase=p))
-            for p in REGISTRY.label_values(_RUNS, "phase")}
 
 
 def reset() -> None:
@@ -104,30 +83,3 @@ def reset() -> None:
     phase families reset."""
     REGISTRY.clear_family(_SECONDS)
     REGISTRY.clear_family(_RUNS)
-
-
-def print_summary() -> None:
-    acc = summary()
-    if not acc:
-        return
-    cnt = counts()
-    width = max(len(k) for k in acc)
-    Log.info("phase timings:")
-    for name, secs in sorted(acc.items(), key=lambda kv: -kv[1]):
-        Log.info(f"  {name:<{width}}  {secs:9.3f}s  x{cnt.get(name, 0)}")
-
-
-if _enabled:
-    atexit.register(print_summary)
-
-
-@contextlib.contextmanager
-def trace(log_dir: str = "/tmp/lightgbm_tpu_trace") -> Iterator[None]:
-    """jax.profiler trace around a block (view with xprof/tensorboard)."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

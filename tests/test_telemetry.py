@@ -203,6 +203,190 @@ class TestSpans:
         assert samples and samples[-1] >= 0.002
 
 
+class TestSpanArithmetic:
+    """ISSUE 24: ids, parent ids and the clock origin, so that a reader
+    can compute self time and join the spans with its own clock."""
+
+    def test_ids_are_unique_and_parent_id_nests_per_thread(self):
+        obs.configure(mode="trace")
+        obs.reset_events()
+
+        def work(tag):
+            with obs.span("outer", who=tag):
+                with obs.span("inner", who=tag):
+                    obs.event("tick", who=tag)
+
+        with obs.span("main/root"):
+            threads = [threading.Thread(target=work, args=(i,))
+                       for i in range(4)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=30)
+            assert not any(t.is_alive() for t in threads)
+            work("main")
+        evs = obs.events()
+        ids = [e["id"] for e in evs]
+        assert len(ids) == len(set(ids)) == 3 * 5 + 1
+        by_id = {e["id"]: e for e in evs}
+        root = next(e for e in evs if e["name"] == "main/root")
+        assert root["parent_id"] is None
+        for e in evs:
+            who = e["tags"].get("who")
+            if e["name"] == "outer":
+                # a thread's root has no parent: the stack is per thread
+                assert e["parent_id"] == (root["id"] if who == "main"
+                                          else None)
+            elif e["name"] in ("inner", "tick"):
+                parent = by_id[e["parent_id"]]
+                assert parent["name"] == {"inner": "outer",
+                                          "tick": "inner"}[e["name"]]
+                assert parent["tags"]["who"] == who
+                assert parent["ts"] <= e["ts"]
+                assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"]
+
+    def test_origin_puts_a_span_on_perf_counter(self):
+        obs.configure(mode="trace")
+        obs.reset_events()
+        before = time.perf_counter()
+        with obs.span("clocked"):
+            pass
+        after = time.perf_counter()
+        (ev,) = obs.events()
+        start = obs.origin_ns() / 1e9 + ev["ts"] / 1e6
+        assert before - 1e-3 <= start <= after + 1e-3
+        assert start + ev["dur"] / 1e6 <= after + 1e-3
+
+    def test_span_ended_is_a_child_that_ends_now(self):
+        obs.span_ended("late", 0.5)          # off: nothing recorded
+        assert obs.events() == []
+        obs.configure(mode="trace")
+        with obs.span("open"):
+            obs.span_ended("late", 0.25, cache="miss")
+            now = time.perf_counter()
+        late, opened = obs.events()
+        assert (late["name"], late["kind"], late["ph"]) == \
+            ("late", "span", "X")
+        assert late["parent_id"] == opened["id"]
+        assert late["tags"] == {"cache": "miss", "parent": "open",
+                                "depth": 1}
+        assert late["dur"] == pytest.approx(0.25e6)
+        end = obs.origin_ns() / 1e9 + (late["ts"] + late["dur"]) / 1e6
+        assert abs(end - now) < 1e-3
+
+    def test_exports_carry_id_and_parent_id(self, tmp_path):
+        obs.configure(mode="trace", trace_dir=str(tmp_path))
+        obs.reset_events()
+        with obs.span("a"):
+            with obs.span("b"):
+                pass
+        path = obs.write_chrome_trace()
+        obs.flush()
+        a, b = (next(e for e in obs.events() if e["name"] == n)
+                for n in "ab")
+        chrome = {e["name"]: e["args"] for e in
+                  json.loads(open(path).read())["traceEvents"]
+                  if e["ph"] == "X"}
+        lines = {ln["name"]: ln for ln in map(
+            json.loads, open(tmp_path / "events-host0.jsonl"))}
+        for out in (chrome, lines):
+            assert (out["a"]["id"], out["a"]["parent_id"]) == (a["id"], None)
+            assert (out["b"]["id"], out["b"]["parent_id"]) == \
+                (b["id"], a["id"])
+        sys.path.insert(0, TOOLS)
+        try:
+            import trace_merge
+        finally:
+            sys.path.remove(TOOLS)
+        merged, _, _ = trace_merge.merge(str(tmp_path))
+        args = {e["name"]: e["args"] for e in merged["traceEvents"]
+                if e["ph"] == "X"}
+        assert args["b"]["parent_id"] == a["id"]
+
+
+SETUP_TREE = {              # span -> the parent it must sit in
+    "sketch": "dataset/construct", "binning": "dataset/construct",
+    "ingest/stage": "binning", "ingest/dispatch": "binning",
+    "learner/init": "booster/init", "layout": "learner/init",
+    "objective/init": "booster/init", "train_step/build": "booster/init",
+}
+
+
+class TestSetupTree:
+    """ISSUE 24: one span tree from Dataset.construct to the first
+    iteration."""
+
+    def _spans(self):
+        return [e for e in obs.events() if e["kind"] == "span"]
+
+    def test_dataset_and_booster_yield_the_tree(self):
+        X, y = _problem(n=700)
+        p = dict(_P, tpu_telemetry="trace", tpu_ingest_device="true",
+                 tpu_ingest_chunk_rows=256)
+        obs.reset_events()
+        layout0 = obs.REGISTRY.value("lgbm_phase_seconds_total",
+                                     phase="layout")
+        ds = lgb.Dataset(X, label=y, params=p).construct()
+        lgb.Booster(params=p, train_set=ds)
+        spans = self._spans()
+        by_id = {e["id"]: e for e in spans}
+        names = {e["name"] for e in spans}
+        assert set(SETUP_TREE) | {"dataset/construct",
+                                  "booster/init"} <= names
+        for e in spans:
+            if e["name"] in ("dataset/construct", "booster/init"):
+                assert e["parent_id"] is None and e["tags"]["depth"] == 0
+            elif e["name"] in SETUP_TREE:
+                parent = by_id[e["parent_id"]]
+                assert parent["name"] == SETUP_TREE[e["name"]]
+                assert parent["ts"] <= e["ts"]
+                assert e["ts"] + e["dur"] <= \
+                    parent["ts"] + parent["dur"] + 1e-6
+        # 700 rows in chunks of 256: three launches, staged then sent
+        stages = [e for e in spans if e["name"] == "ingest/stage"]
+        assert [e["tags"]["chunk"] for e in stages] == [0, 1, 2]
+        assert [e["tags"]["rows"] for e in stages] == [256, 256, 188]
+        assert len([e for e in spans
+                    if e["name"] == "ingest/dispatch"]) == 3
+        # `layout` is a PHASE: the counter AND the span, the same wall
+        (layout,) = [e for e in spans if e["name"] == "layout"]
+        counted = obs.REGISTRY.value("lgbm_phase_seconds_total",
+                                     phase="layout") - layout0
+        assert counted == pytest.approx(layout["dur"] / 1e6, abs=5e-3)
+
+    def test_telemetry_given_only_to_the_booster(self):
+        X, y = _problem()
+        ds = lgb.Dataset(X, label=y, params=_P).construct()
+        assert obs.events() == []
+        lgb.Booster(params=dict(_P, tpu_telemetry="trace"), train_set=ds)
+        names = [e["name"] for e in self._spans()]
+        # armed before the learner is built, not after
+        assert "learner/init" in names and "layout" in names
+        assert "dataset/construct" not in names
+
+    @pytest.mark.parametrize("source", ["sparse", "file"])
+    def test_the_other_constructors_open_the_same_root(self, source,
+                                                       tmp_path):
+        X, y = _problem(n=300)
+        p = dict(_P, tpu_telemetry="trace")
+        obs.reset_events()
+        if source == "sparse":
+            import scipy.sparse as sp
+
+            data = sp.csr_matrix(np.where(np.abs(X) > 1.0, X, 0.0))
+        else:
+            data = str(tmp_path / "train.csv")
+            np.savetxt(data, np.column_stack([y, X]), delimiter=",")
+        lgb.Dataset(data, label=None if source == "file" else y,
+                    params=p).construct()
+        roots = [e for e in self._spans()
+                 if e["name"] == "dataset/construct"
+                 and e["parent_id"] is None]
+        assert [e["tags"]["source"] for e in roots] == [source]
+        sketch = next(e for e in self._spans() if e["name"] == "sketch")
+        assert sketch["tags"]["parent"] == "dataset/construct"
+
+
 # ---------------------------------------------------------------------------
 # end-to-end train trace
 # ---------------------------------------------------------------------------

@@ -547,8 +547,10 @@ def run_retrace(n=20000, f=10, leaves=31, bins=63, iters=3):
     phase(f"ingest + train ({iters} iters)")
 
     # the retrace-elimination contract: an identical second training run
-    # reuses every cached executable — any program compiled here is a
-    # regression (a jit site keyed on a fresh closure or static value)
+    # reuses every cached executable but the Booster's own learner.pre /
+    # learner.post (closures over the labels, one pair per Booster) —
+    # any OTHER program compiled here is a regression (a jit site keyed
+    # on a fresh closure or static value)
     ds2 = lgb.Dataset(X, label=y, params=p)
     bst2 = Booster(params=p, train_set=ds2)
     for _ in range(iters):

@@ -58,6 +58,9 @@ def merge(trace_dir: str):
                        "pid": int(ev.get("host", host)),
                        "tid": int(ev.get("tid", 0)),
                        "args": dict(ev.get("tags") or {})}
+                for key in ("id", "parent_id"):  # absent in old streams
+                    if key in ev:
+                        rec["args"][key] = ev[key]
                 if rec["ph"] == "X":
                     rec["dur"] = float(ev.get("dur_us", 0.0))
                 else:
