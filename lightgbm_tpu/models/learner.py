@@ -376,9 +376,12 @@ class TPUTreeLearner:
                 # the perfeature kernel chunks its feature grid in
                 # sublane-aligned (multiple-of-32) divisors (ops/histogram.py
                 # _hist_pallas); pad the histogram column axis so every width
-                # admits aligned chunks.  Padding columns hold constant bin 0
-                # (num_bin=1 features) and can never split.  Feature-parallel
-                # pads to 32 * n_shards so each shard's slice stays aligned
+                # admits aligned chunks.  Padding columns are storage only:
+                # they belong to num_bin=1 features that can never split, and
+                # the kernel is told how many leading columns are live
+                # (`live_columns` below) and contracts no others.  Feature-
+                # parallel pads to 32 * n_shards so each shard's slice stays
+                # aligned
                 if self.f_shards > 1:
                     a = 32 * self.f_shards
                     self.f_pad = -(-self.f_pad // a) * a
@@ -504,6 +507,7 @@ class TPUTreeLearner:
                             else self.n_pad)
                 bins_t = np.zeros((gd_pad, width_sp), dtype=bin_dtype)
                 bins_t[:gd, :n] = cols_src[:, dense_idx].T
+                live = gd
                 zb_np = meta_np["default_bin"]
                 Gs = len(sparse_idx_cols)
                 # ONE vectorized nonzero pass over the sparse columns,
@@ -599,6 +603,7 @@ class TPUTreeLearner:
                          f"{gd_pad}x{self.n_pad}")
             else:
                 self._sparse_arrays = None
+                live = self.num_columns
                 # partitioned: only this process's rows, at its local width
                 width = self._local_width if self._partitioned else self.n_pad
                 if (dev_src is not None and strategy == "serial"
@@ -616,6 +621,26 @@ class TPUTreeLearner:
                         cols_src = train_data.bins
                     bins_t = np.zeros((self.g_pad, width), dtype=bin_dtype)
                     bins_t[:self.num_columns, :n] = cols_src.T
+
+            # what the histogram kernel is told to contract: the live
+            # columns are the matrix's first `live`, the padding its tail.
+            # Only the perfeature kernel reads the count; feature shards run
+            # one program on slices whose live counts differ, so they keep
+            # the full extent
+            self.live_columns = (live if hist_impl == "pallas2"
+                                 and self.f_shards == 1 else None)
+            contracted = self.live_columns or bins_t.shape[0]
+            for kind, count in (("live", contracted),
+                                ("padding", bins_t.shape[0] - contracted)):
+                obs.REGISTRY.set_gauge(
+                    "lgbm_hist_columns", count, kind=kind,
+                    help="bin-matrix columns the histogram kernel contracts "
+                         "(live) and skips (padding)")
+            obs.REGISTRY.set_gauge(
+                "lgbm_hist_root_slots",
+                1 if hist_impl in ("pallas", "pallas2", "fused") else 0,
+                help="leaf slots of the root histogram pass (0: the xla "
+                     "root scan has no slot axis)")
 
             # 4-bit packing (reference dense_nbits_bin.hpp): two rows per
             # byte in a per-block stride layout (row j low nibble, row
@@ -862,7 +887,8 @@ class TPUTreeLearner:
         self.grow = make_strategy_grower(
             canonical_params(self.params), self.f_pad, strategy, self.mesh,
             voting_k=int(config.top_k), num_columns=self.g_pad,
-            external_pool=self._external_pool)
+            external_pool=self._external_pool,
+            live_columns=self.live_columns)
         self._feature_rng = np.random.default_rng(int(config.feature_fraction_seed))
 
     def reset_pool(self) -> None:
@@ -1447,7 +1473,8 @@ class StreamedTreeLearner(TPUTreeLearner):
             self.params, self.g_pad, self.n_pad, self._stream_R,
             double_buffer=bool(config.tpu_stream_double_buffer),
             goss_top=float(config.tpu_stream_goss_top),
-            goss_other=float(config.tpu_stream_goss_other))
+            goss_other=float(config.tpu_stream_goss_other),
+            live_columns=self.live_columns)
         Log.info(
             f"streamed layout: {len(self._host_blocks)} host blocks x "
             f"{self._stream_R} rows "

@@ -295,7 +295,8 @@ def make_grower(params: GrowerParams, num_features: int,
                 feature_axis: Optional[str] = None,
                 voting_k: int = 0, num_shards: int = 1, jit: bool = True,
                 num_columns: Optional[int] = None,
-                debug_hist: bool = False, external_pool: bool = False):
+                debug_hist: bool = False, external_pool: bool = False,
+                live_columns: Optional[int] = None):
     """Build the whole-tree grower for fixed shapes/params.
 
     num_features is the LOCAL feature count: with `feature_axis` set it is
@@ -303,6 +304,11 @@ def make_grower(params: GrowerParams, num_features: int,
     the GLOBAL [F_local * num_shards] versions (sliced per shard inside).
     num_columns is the bin-matrix column count: G < F when EFB bundling is
     active (has_bundles), otherwise F.
+    live_columns is how many leading columns of the bin matrix this grower
+    histograms carry data (the rest are the learner's alignment padding);
+    the perfeature kernel contracts only those (ops/histogram.py).  None =
+    all of them, and the only value a feature axis takes: its shards run
+    one program and their live counts differ.
 
     `data_axis` and `feature_axis` COMPOSE (the reference's parallel
     learners are templates over the device learner so device x
@@ -326,7 +332,7 @@ def make_grower(params: GrowerParams, num_features: int,
     instead of allocating a fresh pool per tree."""
     return _build_grower(params, num_features, data_axis, feature_axis,
                          voting_k, num_shards, jit, num_columns,
-                         debug_hist, external_pool)
+                         debug_hist, external_pool, live_columns)
 
 
 # bounded: the key includes dataset-shape-derived fields (block_rows,
@@ -337,7 +343,7 @@ def make_grower(params: GrowerParams, num_features: int,
 @functools.lru_cache(maxsize=64)
 def _build_grower(params, num_features, data_axis, feature_axis,
                   voting_k, num_shards, jit, num_columns, debug_hist,
-                  external_pool):
+                  external_pool, live_columns):
     # the axis-addressed collective vocabulary (the ONLY sanctioned
     # spelling of cross-shard ops — graftlint T5xx).  Imported at build
     # time: parallel/strategies.py imports this module, so a module-level
@@ -348,6 +354,9 @@ def _build_grower(params, num_features, data_axis, feature_axis,
 
     if voting_k and not data_axis:
         raise ValueError("voting requires a data axis")
+    if live_columns is not None and feature_axis:
+        raise ValueError("live_columns does not compose with a feature "
+                         "axis: the shards' live counts differ")
     if voting_k and feature_axis:
         # the reference's voting learner is a data-parallel variant
         # (voting_parallel_tree_learner.cpp); it does not compose with
@@ -1054,16 +1063,18 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             return jnp.take(merged, meta["hist_perm"], axis=-3)
         with jax.named_scope("hist_build"):
             if params.hist_impl in ("pallas", "pallas2", "fused"):
-                # reuse the batched VMEM kernel (slot 0 = the all-zero
-                # root leaf ids): the xla scan at pallas-sized short
+                # reuse the batched VMEM kernel at ONE slot (the all-zero
+                # root leaf ids), the shape the ramp's first pre-round
+                # compiles anyway: the xla scan at pallas-sized short
                 # blocks would round-trip a materialized one-hot per
                 # block through HBM
-                root_slots = jnp.full(K, -1, jnp.int32).at[0].set(0)
                 root_local = build_histogram_batched_t(
                     bins_blocks, stats_blocks,
-                    jnp.zeros((nb, block), jnp.int32), root_slots, B,
+                    jnp.zeros((nb, block), jnp.int32),
+                    jnp.zeros(1, jnp.int32), B,
                     precision, impl=params.hist_impl,
-                    packed_rows=params.packed_bins)[0]
+                    packed_rows=params.packed_bins,
+                    live_columns=live_columns)[0]
             else:
                 root_local = build_histogram_t(bins_blocks, stats_blocks,
                                                B, precision)
@@ -1443,7 +1454,8 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                         leaf_ids.reshape(nb, block),
                         smaller_ids, B, precision,
                         impl=params.hist_impl,
-                        packed_rows=params.packed_bins)      # [K, F, B, 3]
+                        packed_rows=params.packed_bins,
+                        live_columns=live_columns)           # [K, F, B, 3]
                     h_local = merge_sparse_hist(h_local, leaf_ids,
                                                 smaller_ids)
                     if sparse_tot:

@@ -37,7 +37,7 @@ Precision modes (`tpu_hist_precision`):
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -283,7 +283,9 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
                               slot_leaf_ids, num_bins: int,
                               precision: str = "hilo",
                               impl: str = "xla",
-                              packed_rows: bool = False) -> jnp.ndarray:
+                              packed_rows: bool = False,
+                              live_columns: Optional[int] = None
+                              ) -> jnp.ndarray:
     """Transposed-layout batched histogram: rows on the lane axis.
 
     Same contraction as `build_histogram_batched_inline` but with the bin
@@ -298,6 +300,12 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
     leaf_blocks:   [nb, block] int32
     slot_leaf_ids: [K] int32 (-1 = dead slot)
     impl: "xla" (lax.scan + dot_general) or "pallas" (fused VMEM kernel)
+    live_columns: STATIC count of leading columns that carry data (default:
+        all F).  The rest are the learner's alignment padding, whose
+        histograms nothing reads: the perfeature kernel ("pallas2",
+        "fused") contracts only the live ones and returns exact zeros for
+        the padding; "xla" and the flat kernel contract every column, so
+        padding comes back as whatever its bins say (all rows in bin 0).
     Returns [K, F, B, 3] f32.
     """
     if impl in ("pallas", "pallas2", "fused"):
@@ -309,7 +317,7 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
             bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             num_bins, precision,
             variant="flat" if impl == "pallas" else "perfeature",
-            packed_rows=packed_rows)
+            packed_rows=packed_rows, live_columns=live_columns)
     if packed_rows:
         raise ValueError("packed (4-bit) bins require a pallas impl")
     nb, num_features, block = bins_t_blocks.shape
@@ -440,7 +448,8 @@ def unpack2d(b2):
 
 def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                  num_bins: int, precision: str, variant: str,
-                 packed_rows: bool = False) -> jnp.ndarray:
+                 packed_rows: bool = False,
+                 live_columns: Optional[int] = None) -> jnp.ndarray:
     """Pallas kernel: fused one-hot + slot-expansion + MXU contraction.
 
     The TPU answer to the reference GPU kernel's workgroup-local
@@ -457,9 +466,9 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
       caps the block at 256 rows before VMEM overflows, putting ~4k grid
       steps of accumulator read-modify-write on the critical path.
     * "perfeature" (impl "pallas2", the auto default on a TPU at
-      8192-row blocks; its speed on the current installation is not
-      measured): the one-hot is generated per feature ([Bp, blk],
-      statically-unrolled dots), so the largest temporary shrinks from
+      8192-row blocks; PERF.md §5 has its times on a v5e): the one-hot
+      is generated per feature ([Bp, blk], statically-unrolled dots),
+      so the largest temporary shrinks from
       [F*B, blk] to [Bp, blk], blocks of 2-8k rows fit, and the grid
       shrinks ~16x.  Each feature's bin rows live at a sublane-aligned
       Bp = ceil(B/8)*8 offset in the accumulator.  When the full [F*Bp,
@@ -468,11 +477,19 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
       in the largest divisor-of-F chunk whose [fblk*Bp, K*S] out block
       fits, and the row-block axis iterates innermost so each feature
       chunk's accumulator stays VMEM-resident across its row sweep.
+      Only the first `live_columns` columns get a one-hot and a dot; the
+      accumulator rows of the rest (the learner's padding to the bins
+      dtype's sublane tile) are written as zeros once, so the output
+      keeps its [K, F, B, 3] shape.  The count is static because the
+      column loop is unrolled: it is part of the program's key.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     nb, F, bins_block = bins_t_blocks.shape
+    live = F if live_columns is None else int(live_columns)
+    if not 0 < live <= F:
+        raise ValueError(f"live_columns={live_columns} outside 1..{F}")
     # packed 4-bit storage (the reference dense_nbits_bin.hpp analog,
     # max_bin<=16): each uint8 byte holds TWO rows of one block — row j in
     # the low nibble, row j + block/2 in the high nibble — so the kernel's
@@ -538,12 +555,14 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             precision=dot_prec, preferred_element_type=acc_dtype)
         accumulate(i, out_ref, slice(None), acc)
 
-    def kernel_perfeature_chunk(fblk):
+    def kernel_perfeature_chunk(fblk, nf):
         def kernel(bins_ref, stats_ref, leaf_ref, slots_ref, out_ref):
-            i = pl.program_id(1)  # row-block axis (innermost)
+            fi = pl.program_id(0)  # feature-chunk axis
+            i = pl.program_id(1)   # row-block axis (innermost)
             sexp = expand_slots(stats_ref, leaf_ref, slots_ref)
             iota_b = jax.lax.broadcasted_iota(jnp.int32, (Bp, block), 0)
-            for f in range(fblk):
+
+            def contract(f):
                 if packed_rows:
                     b_f = unpack2d(bins_ref[0, f])          # [blk]
                 else:
@@ -554,6 +573,24 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                     precision=dot_prec,
                     preferred_element_type=acc_dtype)
                 accumulate(i, out_ref, slice(f * Bp, (f + 1) * Bp), acc)
+
+            def zero_from_first_block(cond, rows):
+                @pl.when(cond & (i == 0))
+                def _():
+                    out_ref[rows, :] = jnp.zeros(
+                        (rows.stop - rows.start, K * S), acc_dtype)
+
+            for f in range(min(fblk, live)):
+                # position f holds a live column in chunks 0..last
+                last = (live - 1 - f) // fblk
+                if last >= nf - 1:
+                    contract(f)
+                else:
+                    pl.when(fi <= last)(functools.partial(contract, f))
+                    zero_from_first_block(
+                        fi > last, slice(f * Bp, (f + 1) * Bp))
+            if live < fblk:  # positions that are padding in every chunk
+                zero_from_first_block(True, slice(live * Bp, fblk * Bp))
         return kernel
 
     # Mosaic block-shape rule: the last two dims of every block must be
@@ -586,7 +623,8 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         # aligned divisor that fits (e.g. F = 2000 = 2^4 * 5^3 for uint8
         # bins), the kernel stays single-chunk — identical to the
         # pre-chunking behavior; the learner pads the column axis to a
-        # 32-multiple for pallas2 precisely to unlock chunking.
+        # 32-multiple for pallas2 precisely to unlock chunking, and says
+        # how many of the columns are real (`live_columns`).
         ks_pad = -(-(K * S) // 128) * 128
         budget = _PERFEATURE_OUT_BUDGET
         # sublane tile of the bins dtype: 32 rows for uint8, 16 for
@@ -622,7 +660,7 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         # feature chunk's accumulator block stays resident while the row
         # sweep accumulates into it
         raw = pl.pallas_call(
-            kernel_perfeature_chunk(fblk),
+            kernel_perfeature_chunk(fblk, nf),
             grid=(nf, nb),
             in_specs=[
                 pl.BlockSpec((1, fblk, bins_block), lambda fi, i: (i, fi, 0)),

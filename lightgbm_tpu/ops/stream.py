@@ -123,7 +123,8 @@ def _hist_geometry(params: GrowerParams, rows: int):
 
 
 @functools.lru_cache(maxsize=16)
-def _build_stream_programs(params: GrowerParams, G: int, n_pad: int):
+def _build_stream_programs(params: GrowerParams, G: int, n_pad: int,
+                           live_columns: Optional[int] = None):
     """The bounded jitted-program family for one (params, shape) pair.
 
     Memoized like `_build_grower` so a ladder rebuild at the same shape
@@ -230,12 +231,12 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int):
             # so fused degrades to pallas2-equivalent hist + the shared
             # select() — bit-identical by int32 associativity
             if params.hist_impl in ("pallas", "pallas2", "fused"):
-                root_slots = jnp.full(K, -1, jnp.int32).at[0].set(0)
                 part = build_histogram_batched_t(
                     bins_blocks, stats_blocks,
-                    jnp.zeros((nbi, block), jnp.int32), root_slots, B,
+                    jnp.zeros((nbi, block), jnp.int32),
+                    jnp.zeros(1, jnp.int32), B,
                     precision, impl=params.hist_impl,
-                    packed_rows=False)[0]
+                    packed_rows=False, live_columns=live_columns)[0]
             else:
                 part = build_histogram_t(bins_blocks, stats_blocks, B,
                                          precision)
@@ -273,7 +274,7 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int):
             part = build_histogram_batched_t(
                 bins_blocks, stats_blocks, new_leaf.reshape(nbi, block),
                 smaller_ids, B, precision, impl=params.hist_impl,
-                packed_rows=False)
+                packed_rows=False, live_columns=live_columns)
         return acc + part, leaf_ids
 
     # ---- root finish: state init from the accumulated root hist -------
@@ -516,7 +517,8 @@ class StreamGrower:
     def __init__(self, params: GrowerParams, num_columns: int,
                  n_pad: int, stream_rows: int,
                  double_buffer: bool = True,
-                 goss_top: float = 0.0, goss_other: float = 0.0):
+                 goss_top: float = 0.0, goss_other: float = 0.0,
+                 live_columns: Optional[int] = None):
         reason = stream_supported(params)
         if reason is not None:
             raise NotImplementedError(
@@ -542,7 +544,8 @@ class StreamGrower:
         self.goss_top = float(goss_top)
         self.goss_other = float(goss_other)
         self.goss_on = self.goss_top > 0.0 or self.goss_other > 0.0
-        self._progs = _build_stream_programs(params, self.G, self.n_pad)
+        self._progs = _build_stream_programs(params, self.G, self.n_pad,
+                                             live_columns)
         # per-tree telemetry, harvested by the learner / bench / probes
         self.last_stats: Dict[str, float] = {}
         self._h2d_rate: Optional[float] = None  # seconds per byte

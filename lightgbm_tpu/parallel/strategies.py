@@ -97,9 +97,12 @@ def make_strategy_grower(params: GrowerParams, num_features: int,
                          voting_k: int = 20,
                          num_columns: Optional[int] = None,
                          debug_hist: bool = False,
-                         external_pool: bool = False):
+                         external_pool: bool = False,
+                         live_columns: Optional[int] = None):
     """Grower for `strategy`; num_features is the GLOBAL (padded) count;
-    num_columns the bin-matrix column count (< num_features under EFB).
+    num_columns the bin-matrix column count (< num_features under EFB);
+    live_columns how many of the bin matrix's leading columns carry data
+    (ops/grower.py make_grower; None under a feature axis).
 
     debug_hist adds a "root_hist" output (the GPU_DEBUG_COMPARE analog,
     reference gpu_tree_learner.cpp:995-1020): per-shard LOCAL in voting
@@ -114,7 +117,7 @@ def make_strategy_grower(params: GrowerParams, num_features: int,
     reuse compiled executables instead of re-tracing."""
     return _build_strategy_grower(params, num_features, strategy, mesh,
                                   voting_k, num_columns, debug_hist,
-                                  external_pool)
+                                  external_pool, live_columns)
 
 
 def _strategy_jit(fn, strategy: str, external_pool: bool):
@@ -130,11 +133,12 @@ def _strategy_jit(fn, strategy: str, external_pool: bool):
 @functools.lru_cache(maxsize=64)
 def _build_strategy_grower(params, num_features, strategy, mesh,
                            voting_k, num_columns, debug_hist,
-                           external_pool):
+                           external_pool, live_columns):
     if strategy == "serial" or mesh is None:
         return make_grower(params, num_features, num_columns=num_columns,
                            debug_hist=debug_hist,
-                           external_pool=external_pool)
+                           external_pool=external_pool,
+                           live_columns=live_columns)
 
     meta_spec = {k: P() for k in META_KEYS}
     base_out = {"records": P(), "leaf_output": P(), "leaf_cnt": P(),
@@ -167,7 +171,8 @@ def _build_strategy_grower(params, num_features, strategy, mesh,
             params, num_features, data_axis=ROW_AXES,
             voting_k=(voting_k if strategy == "voting" else 0),
             num_shards=nshards, jit=False, num_columns=num_columns,
-            debug_hist=debug_hist, external_pool=external_pool)
+            debug_hist=debug_hist, external_pool=external_pool,
+            live_columns=live_columns)
         out_specs = {**base_out, "leaf_ids": P(ROW_AXES)}
         if external_pool:
             out_specs["pool"] = pool_spec
@@ -200,7 +205,8 @@ def _build_strategy_grower(params, num_features, strategy, mesh,
         f_local = num_features // nshards
         grow = make_grower(params, f_local, feature_axis=FEATURE,
                            jit=False, debug_hist=debug_hist,
-                           external_pool=external_pool)
+                           external_pool=external_pool,
+                           live_columns=live_columns)
         # bins REPLICATED (P()), like the reference feature-parallel mode
         # where every machine holds all data (feature_parallel_tree_
         # learner.cpp:55-71): each shard histograms only its own feature
@@ -232,7 +238,8 @@ def _build_strategy_grower(params, num_features, strategy, mesh,
         grow = make_grower(params, f_local, data_axis=ROW_AXES,
                            feature_axis=FEATURE, num_shards=d_shards,
                            jit=False, debug_hist=debug_hist,
-                           external_pool=external_pool)
+                           external_pool=external_pool,
+                           live_columns=live_columns)
         # rows shard over (hosts, data); the bin matrix is [F_global,
         # n_local] per device (features replicated within a row shard so
         # the partition reads the full matrix, like the 1-D feature

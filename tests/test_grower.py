@@ -358,6 +358,104 @@ class TestBatchedHistogramImpls:
                                         slots, B, "hilo", impl="pallas2")
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    @pytest.mark.parametrize("precision,K,F,live,chunked", [
+        ("hilo", 1, 32, 28, False), ("hilo", 25, 32, 28, False),
+        ("int8", 1, 32, 28, False), ("int8", 25, 32, 28, False),
+        # fblk=32 (test_pallas2_feature_chunked_grid's budget): the live
+        # count falls inside the second of two / three chunks
+        ("hilo", 5, 64, 40, True), ("int8", 5, 96, 40, True)])
+    def test_pallas2_live_columns(self, monkeypatch, precision, K, F, live,
+                                  chunked):
+        # a live count below the padded width: the kernel equals xla on
+        # the live columns and is exactly zero on the padding, which xla
+        # (contracting every column) fills with bin 0's mass
+        from lightgbm_tpu.ops import histogram as H
+        rng = np.random.default_rng(8)
+        nb, block, B = 2, 256, 16
+        if chunked:
+            monkeypatch.setattr(H, "_PERFEATURE_OUT_BUDGET",
+                                32 * 16 * 128 * 4)
+        n = nb * block
+        bins = rng.integers(0, B, size=(nb, F, block)).astype(np.uint8)
+        bins[:, live:] = 0
+        g = jnp.asarray(rng.normal(size=n).astype(np.float32))
+        h = jnp.abs(g) + 0.3
+        mask = jnp.ones(n, jnp.float32)
+        if precision == "int8":
+            g = H.quantize_values(g, jnp.max(jnp.abs(g)) / 127, 127,
+                                  "nearest")
+            h = H.quantize_values(h, jnp.max(h) / 127, 127, "nearest")
+        stats = H.pack_stats(g, h, mask, precision)
+        args = (jnp.asarray(bins), stats.reshape(stats.shape[0], nb, block),
+                jnp.asarray(rng.integers(0, K + 2, size=(nb, block)),
+                            dtype=jnp.int32),
+                jnp.asarray(rng.permutation(K + 2)[:K], dtype=jnp.int32),
+                B, precision)
+        a = np.asarray(H.build_histogram_batched_t(*args, impl="xla"))
+        b = np.asarray(H.build_histogram_batched_t(
+            *args, impl="pallas2", live_columns=live))
+        np.testing.assert_array_equal(a[:, :live], b[:, :live])
+        assert a[:, live:].any() and not b[:, live:].any()
+        with pytest.raises(ValueError, match="live_columns"):
+            H.build_histogram_batched_t(*args, impl="pallas2",
+                                        live_columns=F + 1)
+
+    @pytest.mark.parametrize("layout", ["sparse", "streamed", "data"])
+    def test_pallas2_layouts_match_xla_end_to_end(self, layout):
+        """Every layout that hands the kernel a live count below its padded
+        width grows the xla model: a padding column's histogram is read by
+        no search, and leaf totals come from a live column."""
+        import lightgbm_tpu as lgb
+        rng = np.random.default_rng(16)
+        n = 2048
+        X = rng.normal(size=(n, 6))
+        extra = {"sparse": {"tpu_sparse_threshold": 0.2,
+                            "enable_bundle": False},
+                 "streamed": {"tpu_stream_mode": "streamed",
+                              "tpu_stream_block_rows": 1024,
+                              "tpu_hist_precision": "int8"},
+                 "data": {"tree_learner": "data", "num_machines": 4}}[layout]
+        if layout == "sparse":
+            X[:, 3:] = np.where(rng.random((n, 3)) < 0.05, X[:, 3:], 0.0)
+        y = X[:, 0] - X[:, 1] + 2 * X[:, 4] + 0.1 * rng.normal(size=n)
+        live = 3 if layout == "sparse" else 6
+
+        def dump(impl):
+            params = {"objective": "regression", "num_leaves": 15,
+                      "min_data_in_leaf": 5, "max_bin": 32,
+                      "tpu_hist_impl": impl, "tpu_block_rows": 256,
+                      "verbosity": -1, **extra}
+            ds = lgb.Dataset(X, label=y, params=params)
+            bst = lgb.train(params, ds, num_boost_round=3,
+                            keep_training_booster=True)
+            assert bst._driver.learner.live_columns == (
+                live if impl == "pallas2" else None)
+            return bst.model_to_string().split("parameters", 1)[0]
+
+        assert dump("pallas2") == dump("xla")
+
+    def test_learner_reports_live_and_padding_columns(self):
+        """28 columns pad to 32 for pallas2: the registry says what the
+        grower was built with, and that the root runs at one slot."""
+        import lightgbm_tpu as lgb
+        from lightgbm_tpu import obs
+        rng = np.random.default_rng(17)
+        X = rng.normal(size=(512, 28))
+        y = X[:, 0] + 0.1 * rng.normal(size=512)
+        want = {"pallas2": (28, 4, 1), "xla": (32, 0, 0)}
+        for impl, (live, padding, root_slots) in want.items():
+            params = {"objective": "regression", "num_leaves": 4,
+                      "max_bin": 16, "tpu_hist_impl": impl,
+                      "tpu_block_rows": 256, "verbosity": -1}
+            bst = lgb.train(params, lgb.Dataset(X, label=y, params=params),
+                            num_boost_round=1, keep_training_booster=True)
+            lrn = bst._driver.learner
+            assert lrn.bins_t.shape[0] == live + padding == 32
+            got = (obs.REGISTRY.value("lgbm_hist_columns", kind="live"),
+                   obs.REGISTRY.value("lgbm_hist_columns", kind="padding"),
+                   obs.REGISTRY.value("lgbm_hist_root_slots"))
+            assert got == (live, padding, root_slots)
+
     def test_grower_pallas2_matches_xla_end_to_end(self):
         import lightgbm_tpu as lgb
         rng = np.random.default_rng(12)
