@@ -392,6 +392,10 @@ class BinMapper:
         self.default_bin: int = 0      # bin of value 0.0
         self.most_freq_bin: int = 0
         self.sparse_rate: float = 0.0
+        # the sample held no more distinct values than bins were offered:
+        # the boundary search gave every value (of min_data_in_bin rows) a
+        # bin of its own instead of cutting equal-count bins
+        self.distinct_path: bool = False
 
     # ------------------------------------------------------------------
     def find_bin(self, sample_values: np.ndarray, total_sample_cnt: int,
@@ -465,6 +469,8 @@ class BinMapper:
         num_distinct = len(distinct_values)
         forced = list(forced_bounds) if forced_bounds else []
 
+        self.distinct_path = (bin_type == BinType.NUMERICAL
+                              and num_distinct <= max_bin)
         if bin_type == BinType.NUMERICAL:
             self._find_bin_numerical(distinct_values, counts, num_distinct, max_bin,
                                      total_sample_cnt, min_data_in_bin, na_cnt, forced)

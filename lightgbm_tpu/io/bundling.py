@@ -45,6 +45,8 @@ class BundlePlan(NamedTuple):
     bin_offset: np.ndarray      # [F] int32 (0 for singleton columns)
     needs_fix: np.ndarray       # [F] bool: bin 0 must be reconstructed
     num_bin: np.ndarray         # [G] int32 bins per bundle column
+    # features the greedy tried to place (mostly-zero, few enough bins)
+    candidates: int = 0
 
     @property
     def num_columns(self) -> int:
@@ -137,7 +139,7 @@ def find_bundles(bins: np.ndarray, num_bin: np.ndarray,
         g_bins[gi] = off + 1
     return BundlePlan(groups=final, bundle_idx=bundle_idx,
                       bin_offset=bin_offset, needs_fix=needs_fix,
-                      num_bin=g_bins)
+                      num_bin=g_bins, candidates=len(candidates))
 
 
 def find_bundles_multihost(local_bins: np.ndarray, num_bin: np.ndarray,

@@ -676,6 +676,26 @@ class TrainingData:
         if reference.num_total_features != self.num_total_features:
             raise ValueError("validation data feature count mismatch")
 
+    def _note_columns(self) -> None:
+        """What the sketch decided, for the telemetry: the used columns by
+        missing type, and how many of them took the bin search's
+        distinct-value path."""
+        from .. import obs
+
+        used = [self.mappers[c] for c in self.used_feature_idx]
+        for kind in MissingType:
+            obs.REGISTRY.set_gauge(
+                "lgbm_dataset_columns",
+                sum(m.missing_type == kind for m in used),
+                missing=kind.name.lower(),
+                help="used columns of the last constructed dataset by "
+                     "missing type")
+        obs.REGISTRY.set_gauge(
+            "lgbm_dataset_distinct_path_columns",
+            sum(m.distinct_path for m in used),
+            help="used columns of the last constructed dataset whose sample "
+                 "held no more distinct values than bins were offered")
+
     def _find_mappers_maybe_distributed(self, X, config, categorical,
                                         forced_bins,
                                         total_rows: Optional[int] = None
@@ -699,9 +719,10 @@ class TrainingData:
                 feature_names=self.feature_names)
             self.used_feature_idx = [i for i, m in enumerate(self.mappers)
                                      if not m.is_trivial]
-            return
-        self._find_mappers(X, config, categorical, forced_bins,
-                           total_rows=total_rows)
+        else:
+            self._find_mappers(X, config, categorical, forced_bins,
+                               total_rows=total_rows)
+        self._note_columns()
 
     def _find_mappers(self, X: np.ndarray, config: Config,
                       categorical_features: Sequence[int],

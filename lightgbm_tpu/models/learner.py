@@ -22,7 +22,7 @@ from ..io.bin_mapper import MissingType
 from ..io.dataset import TrainingData
 from ..ops.grower import (GrowerParams, canonical_params, mode_flags_np,
                           pad_rows, pool_dtype, resolve_split_batch)
-from ..ops.histogram import hashed_uniform, key_words
+from ..ops.histogram import hashed_uniform, key_words, perfeature_chunks
 from ..parallel.mesh import put_global, put_local
 from ..parallel.strategies import (bins_sharding, make_strategy_grower,
                                    pool_partition_spec,
@@ -264,6 +264,15 @@ class TPUTreeLearner:
                         zero_frac >= float(config.sparse_threshold),
                         float(config.max_conflict_rate), B,
                         sample_rows=EFB_SAMPLE_ROWS)
+                obs.REGISTRY.set_gauge(
+                    "lgbm_efb_candidates", cand_plan.candidates,
+                    help="features the EFB greedy tried to bundle (mostly "
+                         "zero, few enough bins)")
+                obs.REGISTRY.set_gauge(
+                    "lgbm_efb_bundles",
+                    sum(len(g) > 1 for g in cand_plan.groups),
+                    help="bundles of two or more features the EFB greedy "
+                         "formed")
                 if not cand_plan.is_trivial:
                     plan = cand_plan
                     B = max(B, int(plan.num_bin.max()))
@@ -652,6 +661,35 @@ class TPUTreeLearner:
             # decode the wrong rows silently
             local_rows = self.n_pad // self.d_shards
             eff_block = min(block, local_rows)
+            # the grid the perfeature kernel will run over this matrix at
+            # the round loop's slot count, and how many of the one-hot rows
+            # it builds any row can hit: the kernel's own arithmetic
+            # (ops/histogram.perfeature_chunks); zeros off that kernel
+            grid, hist_bins = (0, 0, 0), (0, 0)
+            if self.live_columns is not None:
+                fblk, nf = perfeature_chunks(
+                    bins_t.shape[0], B,
+                    *self._kernel_slots_planes(config, precision),
+                    bins_t.dtype.itemsize)
+                grid = (nf, fblk, local_rows // eff_block)
+                live_bins = (plan.num_bin if plan is not None
+                             else meta_np["num_bin"]
+                             if self._sparse_mask is None
+                             else meta_np["num_bin"][~self._sparse_mask])
+                hist_bins = (int(live_bins.sum()),
+                             self.live_columns * (-(-B // 8) * 8))
+            for axis, count in zip(("feature_chunks", "columns_per_chunk",
+                                    "row_blocks"), grid):
+                obs.REGISTRY.set_gauge(
+                    "lgbm_hist_grid", count, axis=axis,
+                    help="grid of the perfeature histogram kernel: feature "
+                         "chunks x row blocks, and the columns in a chunk")
+            for kind, count in zip(("live", "stored"), hist_bins):
+                obs.REGISTRY.set_gauge(
+                    "lgbm_hist_bins", count, kind=kind,
+                    help="one-hot rows of the live columns: those a row "
+                         "can hit (live, the columns' own bin counts) and "
+                         "those the kernel builds (stored, bins padded to 8)")
             self.packed_bins = (
                 bool(config.tpu_pack_bins) and B <= 16
                 and not self.stream_layout
@@ -941,6 +979,15 @@ class TPUTreeLearner:
         return "psum"
 
     @staticmethod
+    def _kernel_slots_planes(config: Config, precision: str) -> Tuple[int, int]:
+        """(leaf slots of the round loop's histogram call, statistic planes):
+        the lane axis of the perfeature kernel's accumulator."""
+        leaves = max(int(config.num_leaves), 2)
+        k = min(resolve_split_batch(int(config.tpu_split_batch), leaves),
+                leaves - 1)  # the grower's own clamp (make_grower)
+        return k, 5 if precision == "hilo" else 3
+
+    @staticmethod
     def _resolve_hist_impl(config: Config, num_bins: int, precision: str,
                            tuned: Optional[dict] = None) -> Tuple[str, int]:
         """Resolve (tpu_hist_impl, tpu_block_rows), honoring "auto"/0.
@@ -969,22 +1016,17 @@ class TPUTreeLearner:
             if block <= 0 and int(tuned.get("block_rows", 0) or 0) > 0:
                 block = int(tuned["block_rows"])
         if impl == "auto":
-            from ..ops.histogram import (_PERFEATURE_OUT_BUDGET,
-                                         PERFEATURE_AUTO_PRECISIONS)
+            from ..ops.histogram import (PERFEATURE_AUTO_PRECISIONS,
+                                         perfeature_chunk_fits)
 
-            leaves = max(int(config.num_leaves), 2)
-            k = min(resolve_split_batch(int(config.tpu_split_batch), leaves),
-                    leaves - 1)  # the grower's own clamp (make_grower)
-            s = 5 if precision == "hilo" else 3
-            ks_pad = -(-(k * s) // 128) * 128
-            bp = -(-num_bins // 8) * 8
             # smallest feature chunk the kernel can retreat to: the
             # sublane tile of the bins dtype (uint8 for <=256 bins, else
-            # int32 — learner.py bin_dtype / _hist_pallas's step table),
-            # so a [step*Bp, K*S] accumulator block must fit the budget;
-            # the learner's 32-multiple column pad keeps either divisible
-            step = 32 if num_bins <= 256 else 8
-            chunk_fits = step * bp * ks_pad * 4 <= _PERFEATURE_OUT_BUDGET
+            # int32 — learner.py bin_dtype / perfeature_chunks' step
+            # table), whose accumulator block must fit the budget; the
+            # learner's 32-multiple column pad keeps either divisible
+            chunk_fits = perfeature_chunk_fits(
+                32 if num_bins <= 256 else 8, num_bins,
+                *TPUTreeLearner._kernel_slots_planes(config, precision))
             # an explicit row block must stay Mosaic-lane-aligned for the
             # kernel's [.., block] grid specs; the [Bp, block] one-hot and
             # [K*S, block] expanded stats scale with the block (the kernel

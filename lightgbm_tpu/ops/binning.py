@@ -49,6 +49,11 @@ _NAN_KEY = np.int64(np.iinfo(np.int64).max)
 _NAN_KEY_HI = np.int32(_NAN_KEY >> 32)
 _NAN_KEY_LO = np.uint32(_NAN_KEY & 0xFFFFFFFF)
 _NAN_CAT = -2  # host-side category sentinel for NaN values
+# host key prep: a chunk over glibc's largest mmap threshold (freed blocks
+# above it go back to the kernel) is keyed in slabs of this many values
+# (2 MB of f64); see DeviceBinner._prep_chunk
+_PREP_WHOLE_BYTES = 32 << 20
+_PREP_SLAB_VALUES = 1 << 18
 # per-feature / total category-LUT capacity: features with larger raw
 # category ids fall back to host binning (pandas codes and typical int
 # categories sit far below this)
@@ -184,7 +189,27 @@ class DeviceBinner:
     def _prep_chunk(self, block: np.ndarray):
         """Raw f64 [rows, F] -> host key planes (+ category codes)."""
         vals = np.ascontiguousarray(block, dtype=np.float64)
-        vhi, vlo = split_keys(sort_keys(vals))
+        if vals.nbytes <= _PREP_WHOLE_BYTES:
+            vhi, vlo = split_keys(sort_keys(vals))
+        else:
+            # `sort_keys` / `split_keys` make half a dozen int64
+            # temporaries the size of their input.  Past 32 MiB (65536 rows
+            # x 67 columns are 35 MB) glibc no longer reuses a freed block
+            # and maps fresh pages for every one, and how long those page
+            # faults take is the host's mood: 9 s or 31 s of staging for one
+            # 13M-row table, run by run (PR 27, on the chip's host).  Keyed
+            # in slabs the temporaries stay small and the time steady (15 s);
+            # the planes are the same bit for bit.  Smaller chunks are not
+            # slabbed, on one measurement that is not understood: alone the
+            # slabs key a 28-column chunk three times as fast, but inside
+            # `bin_stream` they cost a 27M-row table +6 s in 3 runs of 4
+            # (PERF.md section 7)
+            vhi = np.empty(vals.shape, np.int32)
+            vlo = np.empty(vals.shape, np.uint32)
+            step = max(1, _PREP_SLAB_VALUES // vals.shape[1])
+            for lo in range(0, vals.shape[0], step):
+                vhi[lo:lo + step], vlo[lo:lo + step] = split_keys(
+                    sort_keys(vals[lo:lo + step]))
         cv = None
         if self.has_cat:
             # int(v) truncation toward zero; NaN -> sentinel; clip keeps

@@ -507,7 +507,7 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                            acc_scale=acc_scale)
 
             def fin_plain(bi):
-                res = finalize_split(pf, bi, sg, sh,
+                res = finalize_split(pf, bi,
                                      l1=params.l1, l2=params.l2,
                                      max_delta_step=params.max_delta_step,
                                      min_constraint=min_c,
@@ -531,7 +531,7 @@ def _build_grower(params, num_features, data_axis, feature_axis,
         gain = jnp.where(is_cat, pfc.gain, pf.gain)
 
         def fin(bi):
-            resn = finalize_split(pf, bi, sg, sh,
+            resn = finalize_split(pf, bi,
                                   l1=params.l1, l2=params.l2,
                                   max_delta_step=params.max_delta_step,
                                   min_constraint=min_c, max_constraint=max_c)
@@ -543,6 +543,12 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                 left_sum_g=jnp.where(c, pfc.left_sum_g[bi], resn.left_sum_g),
                 left_sum_h=jnp.where(c, pfc.left_sum_h[bi], resn.left_sum_h),
                 left_count=jnp.where(c, pfc.left_count[bi], resn.left_count),
+                # a categorical split's left bins are no prefix: its
+                # right side stays the leaf's total less the left
+                right_sum_g=jnp.where(c, sg - pfc.left_sum_g[bi],
+                                      resn.right_sum_g),
+                right_sum_h=jnp.where(c, sh - pfc.left_sum_h[bi],
+                                      resn.right_sum_h),
                 left_output=jnp.where(c, pfc.left_output[bi],
                                       resn.left_output),
                 right_output=jnp.where(c, pfc.right_output[bi],
@@ -733,6 +739,8 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                 left_sum_g=res.left_sum_g,
                 left_sum_h=res.left_sum_h,
                 left_count=res.left_count,
+                right_sum_g=res.right_sum_g,
+                right_sum_h=res.right_sum_h,
                 left_output=res.left_output,
                 right_output=res.right_output,
                 is_cat=res.is_cat.astype(jnp.int32),
@@ -747,6 +755,8 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                 left_sum_g=w["left_sum_g"],
                 left_sum_h=w["left_sum_h"],
                 left_count=w["left_count"],
+                right_sum_g=w["right_sum_g"],
+                right_sum_h=w["right_sum_h"],
                 left_output=w["left_output"],
                 right_output=w["right_output"],
                 is_cat=w["is_cat"] > 0,
@@ -1163,6 +1173,8 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             "bs_lg": jnp.zeros(L, jnp.float32).at[0].set(root_split.left_sum_g),
             "bs_lh": jnp.zeros(L, jnp.float32).at[0].set(root_split.left_sum_h),
             "bs_lc": jnp.zeros(L, jnp.float32).at[0].set(root_split.left_count),
+            "bs_rg": jnp.zeros(L, jnp.float32).at[0].set(root_split.right_sum_g),
+            "bs_rh": jnp.zeros(L, jnp.float32).at[0].set(root_split.right_sum_h),
             "bs_lo": jnp.zeros(L, jnp.float32).at[0].set(root_split.left_output),
             "bs_ro": jnp.zeros(L, jnp.float32).at[0].set(root_split.right_output),
             # categorical best-split carry: flag + bins-going-left mask
@@ -1216,7 +1228,7 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             return jnp.where(fixed, jnp.where(in_rng, rel, 0), raw)
 
         def exec_round(state, sel, vals, do_k, sel_feat, sel_thr, sel_dleft,
-                       sel_iscat, cmask_sel, lg, lh, lc, lo, ro):
+                       sel_iscat, cmask_sel, lg, lh, lc, rg, rh, lo, ro):
             """Execute up to Kr splits (slot k: leaf sel[k] on feature
             sel_feat[k]) — partition, batched child histograms, child
             search, state/record updates.  Shared by the best-gain round
@@ -1230,10 +1242,11 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             # promote to int64 and break the while_loop carry contract
             num_do = jnp.sum(do_k, dtype=jnp.int32)
             new_ids = state["n_splits"] + 1 + kar
-            pg = state["leaf_sum_g"][sel]
             ph = state["leaf_sum_h"][sel]
             pc = state["leaf_cnt"][sel]
-            rg, rh, rc = pg - lg, ph - lh, pc - lc
+            # a child's totals are what the scan summed for it on its own
+            # side (split.per_feature_best_split), not parent minus left
+            rc = pc - lc
 
             # ---- partition all K splits at once (reference dense_bin.hpp
             # Split / SplitCategorical semantics).  With feature sharding
@@ -1547,11 +1560,11 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                     # so first-max == the serial lowest-feature tie-break)
                     # and the same finalize_split the unfused fin_plain
                     # applies — select() never sees these children
-                    def child_from_records(rec_c, sgc, shc, min_c, max_c):
+                    def child_from_records(rec_c, min_c, max_c):
                         pf = unpack_pf_records(rec_c)
                         bf = jnp.argmax(pf.gain).astype(jnp.int32)
                         res = finalize_split(
-                            pf, bf, sgc, shc, l1=params.l1, l2=params.l2,
+                            pf, bf, l1=params.l1, l2=params.l2,
                             max_delta_step=params.max_delta_step,
                             min_constraint=min_c, max_constraint=max_c)
                         return res._replace(
@@ -1559,9 +1572,7 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                             cat_mask=jnp.zeros(CB, jnp.float32))
 
                     ch = jax.vmap(child_from_records)(
-                        srecs, jnp.concatenate([lg, rg]),
-                        jnp.concatenate([lh, rh]),
-                        jnp.concatenate([l_min, r_min]),
+                        srecs, jnp.concatenate([l_min, r_min]),
                         jnp.concatenate([l_max, r_max]))
                 else:
                     ch = vselect(
@@ -1588,7 +1599,10 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                             ("bs_thr", ch.threshold),
                             ("bs_dleft", ch.default_left),
                             ("bs_lg", ch.left_sum_g), ("bs_lh", ch.left_sum_h),
-                            ("bs_lc", ch.left_count), ("bs_lo", ch.left_output),
+                            ("bs_lc", ch.left_count),
+                            ("bs_rg", ch.right_sum_g),
+                            ("bs_rh", ch.right_sum_h),
+                            ("bs_lo", ch.left_output),
                             ("bs_ro", ch.right_output),
                             ("bs_iscat", ch.is_cat),
                             ("bs_catmask", ch.cat_mask)):
@@ -1631,7 +1645,8 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                 state["bs_dleft"][sel], state["bs_iscat"][sel],
                 state["bs_catmask"][sel],
                 state["bs_lg"][sel], state["bs_lh"][sel],
-                state["bs_lc"][sel], state["bs_lo"][sel],
+                state["bs_lc"][sel], state["bs_rg"][sel],
+                state["bs_rh"][sel], state["bs_lo"][sel],
                 state["bs_ro"][sel])
 
         def forced_round(state, ok, parent, feat, thr):
@@ -1716,7 +1731,8 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                 jnp.broadcast_to(dleft0, (K,)),
                 jnp.zeros(K, jnp.bool_),
                 jnp.zeros((K, CB), jnp.float32),
-                bcast(lg0), bcast(lh0), bcast(lc0), bcast(lo0), bcast(ro0))
+                bcast(lg0), bcast(lh0), bcast(lc0), bcast(rg0), bcast(rh0),
+                bcast(lo0), bcast(ro0))
             return new_state, do0
 
         # forced splits run first as statically-unrolled rounds (the

@@ -426,6 +426,43 @@ _PERFEATURE_OUT_BUDGET = 6 * 1024 * 1024
 PERFEATURE_AUTO_PRECISIONS = ("hilo", "bf16", "int8")
 
 
+def perfeature_chunk_fits(columns: int, num_bins: int, slots: int,
+                          planes: int) -> bool:
+    """Whether a [columns * Bp, K*S] f32 accumulator block of the perfeature
+    kernel (bins padded to 8 rows, slots x planes to 128 lanes) fits its
+    VMEM budget."""
+    bp = -(-num_bins // 8) * 8
+    ks_pad = -(-(slots * planes) // 128) * 128
+    return columns * bp * ks_pad * 4 <= _PERFEATURE_OUT_BUDGET
+
+
+def perfeature_chunks(columns: int, num_bins: int, slots: int, planes: int,
+                      bins_itemsize: int = 1) -> Tuple[int, int]:
+    """(columns per chunk, chunks) of the perfeature kernel's feature grid.
+
+    Feature chunking: the largest divisor of `columns` whose accumulator
+    block fits the VMEM budget.  Mosaic block-shape rules constrain the
+    candidates: the bins block's second-minor dim (the chunk width) must be
+    sublane-tile-aligned for the bins dtype unless it equals the array dim
+    (32 rows for uint8, 16 for 2-byte, 8 for int32).  When the width has no
+    aligned divisor that fits (e.g. 2000 = 2^4 * 5^3 for uint8 bins), the
+    kernel stays single-chunk; the learner pads the column axis to a
+    32-multiple for pallas2 precisely to unlock chunking, and says how many
+    of the columns are real (`live_columns`).  The one place this is
+    decided: `_hist_pallas` runs the grid it returns and the learner's
+    `lgbm_hist_grid` gauge reports it.
+    """
+    step = {1: 32, 2: 16, 4: 8}[bins_itemsize]
+    fblk = columns
+    if not perfeature_chunk_fits(columns, num_bins, slots, planes):
+        cands = [c for c in range(step, columns, step)
+                 if columns % c == 0
+                 and perfeature_chunk_fits(c, num_bins, slots, planes)]
+        if cands:
+            fblk = max(cands)
+    return fblk, columns // fblk
+
+
 def pallas_interpret() -> bool:
     """Whether `pallas_call` runs its kernels in interpret mode — the ONE
     place that is decided: compiled by Mosaic whenever the platform is
@@ -615,32 +652,9 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
           slot_leaf_ids.reshape(K, 1))
     else:
-        # feature chunking: largest divisor of F whose out block fits the
-        # VMEM budget.  Mosaic block-shape rules constrain the candidates:
-        # the bins block's second-minor dim (fblk) must be sublane-tile-
-        # aligned for the bins dtype unless it equals the array dim F, and
-        # the accumulator's lane width pads to 128.  When F has no
-        # aligned divisor that fits (e.g. F = 2000 = 2^4 * 5^3 for uint8
-        # bins), the kernel stays single-chunk — identical to the
-        # pre-chunking behavior; the learner pads the column axis to a
-        # 32-multiple for pallas2 precisely to unlock chunking, and says
-        # how many of the columns are real (`live_columns`).
         ks_pad = -(-(K * S) // 128) * 128
-        budget = _PERFEATURE_OUT_BUDGET
-        # sublane tile of the bins dtype: 32 rows for uint8, 16 for
-        # 2-byte, 8 for int32 — the chunk width must stay tile-aligned
-        step = {1: 32, 2: 16, 4: 8}[bins_t_blocks.dtype.itemsize]
-
-        def fits(c):
-            return c * Bp * ks_pad * 4 <= budget
-
-        fblk = F
-        if not fits(F):
-            cands = [c for c in range(step, F, step)
-                     if F % c == 0 and fits(c)]
-            if cands:
-                fblk = max(cands)
-        nf = F // fblk
+        fblk, nf = perfeature_chunks(F, B, K, S,
+                                     bins_t_blocks.dtype.itemsize)
         # scoped-VMEM ceiling, from the shapes: the compiler's default
         # (16 MiB on a v5e) is under what the block-scaled temporaries
         # need at 16384 rows (int8 there: "Scoped allocation with size

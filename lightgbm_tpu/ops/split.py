@@ -105,6 +105,8 @@ class SplitResult(NamedTuple):
     left_sum_g: jnp.ndarray
     left_sum_h: jnp.ndarray
     left_count: jnp.ndarray    # f32
+    right_sum_g: jnp.ndarray   # summed from the right side's own bins,
+    right_sum_h: jnp.ndarray   # never parent minus left (see the scan)
     left_output: jnp.ndarray
     right_output: jnp.ndarray
     is_cat: Optional[jnp.ndarray] = None    # categorical split? (None = no)
@@ -119,6 +121,8 @@ class PerFeatureBest(NamedTuple):
     left_sum_g: jnp.ndarray  # [F]
     left_sum_h: jnp.ndarray  # [F]
     left_count: jnp.ndarray  # [F]
+    right_sum_g: jnp.ndarray  # [F]
+    right_sum_h: jnp.ndarray  # [F]
 
 
 # flat f32 device-record layout of a PerFeatureBest row: the fused grow
@@ -127,13 +131,12 @@ class PerFeatureBest(NamedTuple):
 # round-trips f32 exactly: gains/sums are f32 already, thresholds are bin
 # indices < 2^24, default_left is 0.0/1.0.
 PF_REC_GAIN, PF_REC_THRESHOLD, PF_REC_DEFAULT_LEFT, PF_REC_LEFT_G, \
-    PF_REC_LEFT_H, PF_REC_LEFT_C = range(6)
-PF_RECORD_WIDTH = 8  # padded to a lane-friendly width; fields 6-7 spare
+    PF_REC_LEFT_H, PF_REC_LEFT_C, PF_REC_RIGHT_G, PF_REC_RIGHT_H = range(8)
+PF_RECORD_WIDTH = 8  # a lane-friendly width, all of it used
 
 
 def pack_pf_records(pf: PerFeatureBest) -> jnp.ndarray:
     """[F, PF_RECORD_WIDTH] f32 device records from per-feature bests."""
-    F = pf.gain.shape[0]
     return jnp.stack(
         [pf.gain.astype(jnp.float32),
          pf.threshold.astype(jnp.float32),
@@ -141,7 +144,8 @@ def pack_pf_records(pf: PerFeatureBest) -> jnp.ndarray:
          pf.left_sum_g.astype(jnp.float32),
          pf.left_sum_h.astype(jnp.float32),
          pf.left_count.astype(jnp.float32),
-         jnp.zeros(F, jnp.float32), jnp.zeros(F, jnp.float32)], axis=1)
+         pf.right_sum_g.astype(jnp.float32),
+         pf.right_sum_h.astype(jnp.float32)], axis=1)
 
 
 def unpack_pf_records(rec: jnp.ndarray) -> PerFeatureBest:
@@ -152,7 +156,9 @@ def unpack_pf_records(rec: jnp.ndarray) -> PerFeatureBest:
         default_left=rec[:, PF_REC_DEFAULT_LEFT] > 0.5,
         left_sum_g=rec[:, PF_REC_LEFT_G],
         left_sum_h=rec[:, PF_REC_LEFT_H],
-        left_count=rec[:, PF_REC_LEFT_C])
+        left_count=rec[:, PF_REC_LEFT_C],
+        right_sum_g=rec[:, PF_REC_RIGHT_G],
+        right_sum_h=rec[:, PF_REC_RIGHT_H])
 
 
 def per_feature_best_split(
@@ -200,22 +206,54 @@ def per_feature_best_split(
     ah = jnp.where(acc_mask, hh, zero)
     ac = jnp.where(acc_mask, hc, zero)
 
+    # Each side of a threshold is summed from its OWN bins: the prefix
+    # over the bins at or under it, the suffix over the bins above it, and
+    # the missing mass (the NaN bin, or the zero bin under zero_as_missing)
+    # added to whichever side the direction sends it.  "Right = the leaf's
+    # total minus left" is the reference's rule, in double.  In f32 it
+    # loses a small child of a large leaf: a cumulative sum near the total
+    # rounds by as much as the child holds (13M rows at hessian 0.034: an
+    # ulp of 0.03 against a 20-row child's 0.68), and the leaf's total
+    # comes from the rows' f32 values while the bins hold their hi + lo
+    # bf16 halves, which on a first tree (one hessian for every row) are
+    # off from them by one systematic 2^-17.  A hessian that comes out too
+    # small inflates the gain, so the search picked just those candidates
+    # (PR 27, on the chip: a leaf value off by 7.3, its count exact).  The
+    # leaf's totals now enter `gain_shift` only.
+    def suffix_after(a):
+        """out[:, t] = sum of a[:, t+1:], accumulated from the top bin."""
+        rev = jnp.cumsum(a[:, ::-1], axis=1)[:, ::-1]
+        return jnp.concatenate([rev[:, 1:], jnp.zeros_like(rev[:, :1])],
+                               axis=1)
+
+    def missing_mass(h):
+        return jnp.sum(jnp.where(acc_mask, zero, h), axis=1, keepdims=True)
+
     cg = jnp.cumsum(ag, axis=1)                                  # [F, B]
     ch = jnp.cumsum(ah, axis=1)
     cc = jnp.cumsum(ac, axis=1)
+    rg, rh = suffix_after(ag), suffix_after(ah)
+    mg, mh, mc = missing_mass(hg), missing_mass(hh), missing_mass(hc)
+    # direction +1 sends the missing mass right, direction -1 left; added
+    # in the histogram's dtype, so exactly under the int precisions
+    right_g_p1, right_h_p1 = rg + mg, rh + mh
+    left_g_m1, left_h_m1, left_c_m1 = cg + mg, ch + mh, cc + mc
     if acc_scale is not None:
-        # int32 prefix sums are exact; dequantize at the scan boundary
-        cg = cg.astype(jnp.float32) * acc_scale[0]
-        ch = ch.astype(jnp.float32) * acc_scale[1]
-        cc = cc.astype(jnp.float32) * acc_scale[2]
+        # int32 sums are exact; dequantize at the scan boundary
+        def dequantized(plane, *sums):
+            return (x.astype(jnp.float32) * acc_scale[plane] for x in sums)
+
+        cg, rg, right_g_p1, left_g_m1 = dequantized(
+            0, cg, rg, right_g_p1, left_g_m1)
+        ch, rh, right_h_p1, left_h_m1 = dequantized(
+            1, ch, rh, right_h_p1, left_h_m1)
+        cc, left_c_m1 = dequantized(2, cc, left_c_m1)
 
     gain_shift = leaf_split_gain(sum_g, sum_h + 2 * K_EPSILON,
                                  l1, l2, max_delta_step)
     min_gain_shift = gain_shift + min_gain_to_split
 
-    def eval_dir(left_g, left_h, left_c, thr_valid):
-        right_g = sum_g - left_g
-        right_h = sum_h - left_h
+    def eval_dir(left_g, left_h, left_c, right_g, right_h, thr_valid):
         right_c = num_data - left_c
         ok = (thr_valid
               & (left_c >= min_data_in_leaf) & (right_c >= min_data_in_leaf)
@@ -237,16 +275,17 @@ def per_feature_best_split(
     # ---- direction +1: left = prefix, missing goes right ----------------
     thr_ok_p1 = (bin_iota <= nb - 2) & (~skip_bin) & \
         jnp.where(is_nan_missing, bin_iota <= nb - 2, True)
-    gain_p1, lo_p1, ro_p1 = eval_dir(cg, ch, cc, thr_ok_p1)
+    gain_p1, lo_p1, ro_p1 = eval_dir(cg, ch, cc, right_g_p1, right_h_p1,
+                                     thr_ok_p1)
 
     # ---- direction -1: right = suffix, missing goes left ----------------
-    # right stats at threshold t = total_acc - prefix[t]
-    tg, th, tc = cg[:, -1:], ch[:, -1:], cc[:, -1:]
-    left_g_m1 = sum_g - (tg - cg)
-    left_h_m1 = sum_h - (th - ch)
-    left_c_m1 = num_data - (tc - cc)
+    # A feature with no missing mass in this leaf gets the same sums, so
+    # the same gains, in both directions, and the strict `>` below keeps
+    # direction -1 (default_left) as the reference does, which runs only
+    # that direction for such a feature.
     thr_ok_m1 = (bin_iota <= nb - 2 - is_nan_missing.astype(jnp.int32)) & (~skip_bin)
-    gain_m1, lo_m1, ro_m1 = eval_dir(left_g_m1, left_h_m1, left_c_m1, thr_ok_m1)
+    gain_m1, lo_m1, ro_m1 = eval_dir(left_g_m1, left_h_m1, left_c_m1, rg, rh,
+                                     thr_ok_m1)
 
     # ---- per-feature best with reference tie-breaking -------------------
     # dir=-1: largest threshold wins ties -> argmax over reversed bins
@@ -281,12 +320,17 @@ def per_feature_best_split(
                    ch[f_iota, feat_thr])
     lc = jnp.where(feat_dleft, left_c_m1[f_iota, feat_thr],
                    cc[f_iota, feat_thr])
+    right_g = jnp.where(feat_dleft, rg[f_iota, feat_thr],
+                        right_g_p1[f_iota, feat_thr])
+    right_h = jnp.where(feat_dleft, rh[f_iota, feat_thr],
+                        right_h_p1[f_iota, feat_thr])
     return PerFeatureBest(gain=out_gain, threshold=feat_thr,
                           default_left=feat_dleft,
-                          left_sum_g=lg, left_sum_h=lh, left_count=lc)
+                          left_sum_g=lg, left_sum_h=lh, left_count=lc,
+                          right_sum_g=right_g, right_sum_h=right_h)
 
 
-def finalize_split(pf: PerFeatureBest, best_f, sum_g, sum_h,
+def finalize_split(pf: PerFeatureBest, best_f,
                    *, l1: float, l2: float, max_delta_step: float,
                    min_constraint=-1e30, max_constraint=1e30) -> SplitResult:
     """SplitResult for the chosen feature index (post argmax/vote/gather)."""
@@ -296,9 +340,11 @@ def finalize_split(pf: PerFeatureBest, best_f, sum_g, sum_h,
     lg = pf.left_sum_g[best_f]
     lh = pf.left_sum_h[best_f]
     lc = pf.left_count[best_f]
+    rg = pf.right_sum_g[best_f]
+    rh = pf.right_sum_h[best_f]
     lo = jnp.clip(leaf_output(lg, lh, l1, l2, max_delta_step),
                   min_constraint, max_constraint)
-    ro = jnp.clip(leaf_output(sum_g - lg, sum_h - lh, l1, l2, max_delta_step),
+    ro = jnp.clip(leaf_output(rg, rh, l1, l2, max_delta_step),
                   min_constraint, max_constraint)
     # the grower's stored-split state is f32; under deterministic f64 the
     # candidate math above runs in f64 and must downcast HERE, at the one
@@ -311,6 +357,7 @@ def finalize_split(pf: PerFeatureBest, best_f, sum_g, sum_h,
         threshold=thr,
         default_left=dleft,
         left_sum_g=f32(lg), left_sum_h=f32(lh), left_count=f32(lc),
+        right_sum_g=f32(rg), right_sum_h=f32(rh),
         left_output=f32(lo), right_output=f32(ro))
 
 
@@ -506,7 +553,7 @@ def find_best_split_all_features(
         min_gain_to_split=min_gain_to_split,
         min_constraint=min_constraint, max_constraint=max_constraint)
     best_f = jnp.argmax(pf.gain, axis=0).astype(jnp.int32)
-    return finalize_split(pf, best_f, sum_g, sum_h,
+    return finalize_split(pf, best_f,
                           l1=l1, l2=l2, max_delta_step=max_delta_step,
                           min_constraint=min_constraint,
                           max_constraint=max_constraint)

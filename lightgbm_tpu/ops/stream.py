@@ -164,7 +164,7 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int,
             min_constraint=min_c, max_constraint=max_c,
             acc_scale=acc, **split_kw)
         bf = jnp.argmax(pf.gain).astype(jnp.int32)
-        res = finalize_split(pf, bf, sg, sh,
+        res = finalize_split(pf, bf,
                              l1=params.l1, l2=params.l2,
                              max_delta_step=params.max_delta_step,
                              min_constraint=min_c, max_constraint=max_c)
@@ -306,6 +306,10 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int,
                 root_split.left_sum_h),
             "bs_lc": jnp.zeros(L, jnp.float32).at[0].set(
                 root_split.left_count),
+            "bs_rg": jnp.zeros(L, jnp.float32).at[0].set(
+                root_split.right_sum_g),
+            "bs_rh": jnp.zeros(L, jnp.float32).at[0].set(
+                root_split.right_sum_h),
             "bs_lo": jnp.zeros(L, jnp.float32).at[0].set(
                 root_split.left_output),
             "bs_ro": jnp.zeros(L, jnp.float32).at[0].set(
@@ -344,6 +348,7 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int,
             sel_feat=state["bs_feat"][sel], sel_thr=state["bs_thr"][sel],
             sel_dleft=state["bs_dleft"][sel],
             lg=state["bs_lg"][sel], lh=state["bs_lh"][sel], lc=lc,
+            rg=state["bs_rg"][sel], rh=state["bs_rh"][sel],
             lo=state["bs_lo"][sel], ro=state["bs_ro"][sel])
         acc0 = jnp.zeros((K, G, B, 3), hist_t)
         return head, acc0
@@ -351,12 +356,11 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int,
     # ---- round update: everything after the histogram seam ------------
     def round_update(state, acc, sel, vals, do_k, new_ids,
                      sel_feat, sel_thr, sel_dleft,
-                     lg, lh, lc, lo, ro, fmask, qscale, meta):
+                     lg, lh, lc, rg, rh, lo, ro, fmask, qscale, meta):
         num_do = jnp.sum(do_k, dtype=jnp.int32)
-        pg = state["leaf_sum_g"][sel]
         ph = state["leaf_sum_h"][sel]
         pc = state["leaf_cnt"][sel]
-        rg, rh, rc = pg - lg, ph - lh, pc - lc
+        rc = pc - lc
         smaller_is_left = lc <= rc
         hist_small = acc                              # [K, G, B, 3]
         parent_hist = state["pool"][sel]
@@ -403,6 +407,8 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int,
                          ("bs_lg", ch.left_sum_g),
                          ("bs_lh", ch.left_sum_h),
                          ("bs_lc", ch.left_count),
+                         ("bs_rg", ch.right_sum_g),
+                         ("bs_rh", ch.right_sum_h),
                          ("bs_lo", ch.left_output),
                          ("bs_ro", ch.right_output)):
             arr = _scatter_set(new_state[key_], sel, cv[:K], do_k)
@@ -715,7 +721,8 @@ class StreamGrower:
                 state, acc_k, head["sel"], head["vals"], head["do_k"],
                 head["new_ids"], head["sel_feat"], head["sel_thr"],
                 head["sel_dleft"], head["lg"], head["lh"], head["lc"],
-                head["lo"], head["ro"], feature_mask, qscale, meta)
+                head["rg"], head["rh"], head["lo"], head["ro"],
+                feature_mask, qscale, meta)
         t_hist = time.perf_counter() - t_hist
 
         out = dict(P.finish(state, leaf_ids, g, h, meta["mode_flags"]))

@@ -26,6 +26,7 @@ split search, the row-partition kernel, and the persisted autotuner.
    buffers plus the autotune probe scratch.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -179,16 +180,32 @@ class TestDeviceRecordsOracle:
         for j in range(C):
             k = j % K
             hs = small[k] if ctx_np[j, 5] > 0 else parent[k] - small[k]
-            pf = SP.per_feature_best_split(
+            # compiled, as the kernel's scan is: op by op the host would
+            # round a multiply-add twice where compiled code fuses it
+            pf = jax.jit(functools.partial(
+                SP.per_feature_best_split, **SPLIT_KW))(
                 hs, ctx_np[j, 0], ctx_np[j, 1], ctx_np[j, 2],
                 meta_i[:, 0], meta_i[:, 1], meta_i[:, 2], meta_i[:, 3],
                 meta_f[:, 0], meta_f[:, 1],
                 min_constraint=ctx_np[j, 3], max_constraint=ctx_np[j, 4],
-                acc_scale=qs, **SPLIT_KW)
+                acc_scale=qs)
             expect = SP.pack_pf_records(pf)
             np.testing.assert_array_equal(np.asarray(recs[j]),
                                           np.asarray(expect),
                                           err_msg=f"child {j}")
+            # and against the scan run op by op on the host, so that the
+            # oracle is not compiled code alone: the same thresholds and
+            # directions, the sums and gains to a few f32 roundings (one
+            # multiply-add rounded twice is 2^-23 = 1.2e-7 relative)
+            eager = SP.pack_pf_records(SP.per_feature_best_split(
+                hs, ctx_np[j, 0], ctx_np[j, 1], ctx_np[j, 2],
+                meta_i[:, 0], meta_i[:, 1], meta_i[:, 2], meta_i[:, 3],
+                meta_f[:, 0], meta_f[:, 1],
+                min_constraint=ctx_np[j, 3], max_constraint=ctx_np[j, 4],
+                acc_scale=qs, **SPLIT_KW))
+            np.testing.assert_allclose(np.asarray(recs[j]),
+                                       np.asarray(eager), rtol=1e-6,
+                                       atol=0, err_msg=f"child {j}, eager")
             # unpack round-trips the exact fields select() consumes
             back = SP.unpack_pf_records(recs[j])
             np.testing.assert_array_equal(np.asarray(back.gain),
