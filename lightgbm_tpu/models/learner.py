@@ -22,7 +22,8 @@ from ..io.bin_mapper import MissingType
 from ..io.dataset import TrainingData
 from ..ops.grower import (GrowerParams, canonical_params, mode_flags_np,
                           pad_rows, pool_dtype, resolve_split_batch)
-from ..ops.histogram import hashed_uniform, key_words, perfeature_chunks
+from ..ops.histogram import (hashed_uniform, key_words, perfeature_chunks,
+                             perfeature_columns_per_dot)
 from ..parallel.mesh import put_global, put_local
 from ..parallel.strategies import (bins_sharding, make_strategy_grower,
                                    pool_partition_spec,
@@ -664,14 +665,17 @@ class TPUTreeLearner:
             # the grid the perfeature kernel will run over this matrix at
             # the round loop's slot count, and how many of the one-hot rows
             # it builds any row can hit: the kernel's own arithmetic
-            # (ops/histogram.perfeature_chunks); zeros off that kernel
-            grid, hist_bins = (0, 0, 0), (0, 0)
+            # (ops/histogram.perfeature_chunks, perfeature_columns_per_dot);
+            # zeros off that kernel
+            grid, hist_bins = (0, 0, 0, 0), (0, 0)
             if self.live_columns is not None:
                 fblk, nf = perfeature_chunks(
                     bins_t.shape[0], B,
                     *self._kernel_slots_planes(config, precision),
                     bins_t.dtype.itemsize)
-                grid = (nf, fblk, local_rows // eff_block)
+                grid = (nf, fblk, local_rows // eff_block,
+                        perfeature_columns_per_dot(
+                            B, eff_block, precision, fblk, self.live_columns))
                 live_bins = (plan.num_bin if plan is not None
                              else meta_np["num_bin"]
                              if self._sparse_mask is None
@@ -679,11 +683,12 @@ class TPUTreeLearner:
                 hist_bins = (int(live_bins.sum()),
                              self.live_columns * (-(-B // 8) * 8))
             for axis, count in zip(("feature_chunks", "columns_per_chunk",
-                                    "row_blocks"), grid):
+                                    "row_blocks", "columns_per_dot"), grid):
                 obs.REGISTRY.set_gauge(
                     "lgbm_hist_grid", count, axis=axis,
                     help="grid of the perfeature histogram kernel: feature "
-                         "chunks x row blocks, and the columns in a chunk")
+                         "chunks x row blocks, the columns in a chunk, and "
+                         "the columns whose one-hots one dot stacks")
             for kind, count in zip(("live", "stored"), hist_bins):
                 obs.REGISTRY.set_gauge(
                     "lgbm_hist_bins", count, kind=kind,

@@ -36,7 +36,6 @@ Precision modes (`tpu_hist_precision`):
 
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -419,6 +418,14 @@ def build_histogram_sparse(sidx: jnp.ndarray, sbin: jnp.ndarray,
 # pallas kernel; the remaining ~10 MB of VMEM holds the [Bp, blk] one-hot,
 # the [K*S, blk] expanded stats, and the double-buffered input DMAs
 _PERFEATURE_OUT_BUDGET = 6 * 1024 * 1024
+# a group of the perfeature kernel (`perfeature_columns_per_dot`): the lanes
+# (table rows) one dot of a group contracts, the most columns a group stacks,
+# and the VMEM budget of its stacked [G * Bp, lanes] one-hot.  Measured on a
+# v5e (PERF.md §5, PR 28): 1024 lanes beat 512 and tie 2048 and 4096 at a
+# third of the compile time; 2 to 4 columns beat 7 and more at 255 bins
+_PERFEATURE_GROUP_LANES = 1024
+_PERFEATURE_GROUP_COLUMNS = 4
+_PERFEATURE_GROUP_BUDGET = 2 * 1024 * 1024
 # the precisions `tpu_hist_impl=auto` (and the autotuner) may hand to the
 # perfeature kernel: each compiled and ran at full Higgs width on a v5e,
 # at 8192- and 16384-row blocks, equal to the xla contraction (PR 21).
@@ -463,6 +470,38 @@ def perfeature_chunks(columns: int, num_bins: int, slots: int, planes: int,
     return fblk, columns // fblk
 
 
+def perfeature_dot_lanes(block: int) -> int:
+    """Lanes (table rows) one dot of a group contracts: a lane sub-block of
+    the row block where the block is a whole number of them, else the block."""
+    return (_PERFEATURE_GROUP_LANES if block % _PERFEATURE_GROUP_LANES == 0
+            else block)
+
+
+def perfeature_columns_per_dot(num_bins: int, block: int, precision: str,
+                               columns: int, live: int) -> int:
+    """G, the adjacent live columns whose one-hots one dot of the perfeature
+    kernel stacks into a [G * Bp, lanes] operand (a group).
+
+    Every dot hands the MXU the [K*S, lanes] slot operand anew, and a
+    column's own dot streams only its Bp one-hot rows past it; a group
+    streams G * Bp rows per load (PERF.md §5).  G is the most columns whose
+    stacked one-hot fits its VMEM budget, at most `_PERFEATURE_GROUP_COLUMNS`
+    and the columns a chunk can hold live; 1, the ungrouped kernel, where
+    the budget holds one column only (bins in the thousands) or where Bp is
+    not a whole number of the dot dtype's sublane tiles (a column's one-hot
+    could not be stored at its row offset of the group).  The one place
+    this is decided: `_hist_pallas` forms its groups by it and the learner's
+    `lgbm_hist_grid{axis="columns_per_dot"}` gauge reports it.
+    """
+    bp = -(-num_bins // 8) * 8
+    itemsize = jnp.dtype(_dot_spec(precision)[0]).itemsize
+    if bp % (32 // itemsize):  # sublane tile: 8 rows of f32, 16 bf16, 32 int8
+        return 1
+    fits = _PERFEATURE_GROUP_BUDGET // (
+        bp * perfeature_dot_lanes(block) * itemsize)
+    return max(1, min(fits, _PERFEATURE_GROUP_COLUMNS, columns, live))
+
+
 def pallas_interpret() -> bool:
     """Whether `pallas_call` runs its kernels in interpret mode — the ONE
     place that is decided: compiled by Mosaic whenever the platform is
@@ -504,8 +543,8 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
       steps of accumulator read-modify-write on the critical path.
     * "perfeature" (impl "pallas2", the auto default on a TPU at
       8192-row blocks; PERF.md §5 has its times on a v5e): the one-hot
-      is generated per feature ([Bp, blk], statically-unrolled dots),
-      so the largest temporary shrinks from
+      is generated per feature ([Bp, blk] at most, statically-unrolled
+      dots), so the largest temporary shrinks from
       [F*B, blk] to [Bp, blk], blocks of 2-8k rows fit, and the grid
       shrinks ~16x.  Each feature's bin rows live at a sublane-aligned
       Bp = ceil(B/8)*8 offset in the accumulator.  When the full [F*Bp,
@@ -519,6 +558,21 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
       dtype's sublane tile) are written as zeros once, so the output
       keeps its [K, F, B, 3] shape.  The count is static because the
       column loop is unrolled: it is part of the program's key.
+      The dots are per GROUP: G adjacent live columns (of those live in
+      the same feature chunks) write their one-hots to Bp-aligned rows of
+      one [G*Bp, lanes] VMEM scratch and are contracted against the slot
+      operand in one dot, whose [G*Bp, K*S] result lands on the group's
+      rows of the accumulator, contiguous as they are.  A group's dots run
+      over `perfeature_dot_lanes` rows of the block at a time (a
+      `fori_loop` over lane sub-blocks; the 4-bit stride layout takes the
+      block whole); a block's sub-blocks are summed in a second scratch
+      and added once to the accumulator, which the first row block
+      zeroes, so the f32 accumulator rounds once per block as it did when
+      a dot spanned the block.  G comes from `perfeature_columns_per_dot`, a function
+      of the shapes alone (Bp, block, dot dtype, columns of a chunk, live
+      count) that the learner's gauge calls too; where it answers 1 the
+      kernel is the ungrouped one: a [Bp, blk] one-hot and a dot per
+      column over the whole block, the first block's dot storing.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -550,12 +604,13 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         dot_prec = (jax.lax.Precision.HIGHEST if precision == "f32"
                     else jax.lax.Precision.DEFAULT)
 
-    def expand_slots(stats_ref, leaf_ref, slots_ref):
-        """[K*S, blk] per-slot stats: slot one-hot x packed stat rows."""
-        s = stats_ref[0]                        # [S, blk]
-        l = leaf_ref[0]                         # [1, blk] i32
+    def expand_slots(stats_ref, leaf_ref, slots_ref, at=slice(None)):
+        """[K*S, lanes] per-slot stats of the block's rows `at`: slot
+        one-hot x packed stat rows."""
+        s = stats_ref[0, :, at]                 # [S, lanes]
+        l = leaf_ref[0, :, at]                  # [1, lanes] i32
         slots = slots_ref[:]                    # [K, 1] i32
-        hit = slots == l                                    # [K, blk]
+        hit = slots == l                                    # [K, lanes]
         if precision in _INT_STAT_DTYPES:
             # the VPU has no narrow-int multiply (Mosaic on a v5e: "failed
             # to legalize operation 'arith.muli'" on i8 vectors): select
@@ -566,7 +621,7 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         else:
             sexp = (hit.astype(dot_dtype)[:, None, :]
                     * s[None, :, :].astype(dot_dtype))
-        return sexp.reshape(K * S, block)
+        return sexp.reshape(K * S, s.shape[-1])
 
     def accumulate(i, out_ref, rows, acc):
         @pl.when(i == 0)
@@ -592,24 +647,62 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             precision=dot_prec, preferred_element_type=acc_dtype)
         accumulate(i, out_ref, slice(None), acc)
 
-    def kernel_perfeature_chunk(fblk, nf):
-        def kernel(bins_ref, stats_ref, leaf_ref, slots_ref, out_ref):
+    def kernel_perfeature_chunk(fblk, nf, G, lanes):
+        # position f holds a live column in chunks 0..last(f), which only
+        # falls as f rises: a run is the adjacent positions live in the same
+        # chunks, under one guard, a group up to G adjacent positions of a run
+        positions = {}
+        for f in range(min(fblk, live)):
+            positions.setdefault((live - 1 - f) // fblk, []).append(f)
+        # (last chunk, [(first position, columns) of each group])
+        runs = [(last, [(f, min(G, fs[-1] + 1 - f))
+                        for f in range(fs[0], fs[-1] + 1, G)])
+                for last, fs in positions.items()]
+
+        def kernel(bins_ref, stats_ref, leaf_ref, slots_ref, out_ref,
+                   *scratch):
             fi = pl.program_id(0)  # feature-chunk axis
             i = pl.program_id(1)   # row-block axis (innermost)
-            sexp = expand_slots(stats_ref, leaf_ref, slots_ref)
-            iota_b = jax.lax.broadcasted_iota(jnp.int32, (Bp, block), 0)
 
-            def contract(f):
-                if packed_rows:
-                    b_f = unpack2d(bins_ref[0, f])          # [blk]
-                else:
-                    b_f = bins_ref[0, f].astype(jnp.int32)  # [blk]
-                onehot = (b_f[None, :] == iota_b).astype(dot_dtype)
-                acc = jax.lax.dot_general(
-                    onehot, sexp, (((1,), (1,)), ((), ())),
-                    precision=dot_prec,
-                    preferred_element_type=acc_dtype)
-                accumulate(i, out_ref, slice(f * Bp, (f + 1) * Bp), acc)
+            def sweep(at, land):
+                """Every live column's dot over the block's rows `at`;
+                `land(rows, acc)` takes a dot's result for those rows of
+                the accumulator."""
+                sexp = expand_slots(stats_ref, leaf_ref, slots_ref, at)
+                iota_b = jax.lax.broadcasted_iota(jnp.int32, (Bp, lanes), 0)
+
+                def onehot_of(f):
+                    if packed_rows:
+                        b_f = unpack2d(bins_ref[0, f])              # [blk]
+                    else:
+                        b_f = bins_ref[0, f, at].astype(jnp.int32)  # [lanes]
+                    return (b_f[None, :] == iota_b).astype(dot_dtype)
+
+                def contract(f, g):
+                    if G == 1:
+                        onehot = onehot_of(f)
+                    else:
+                        # built in dot layout: each column's narrowed
+                        # one-hot goes to its Bp-aligned rows of the
+                        # scratch, so the 32-bit iota and compare stay one
+                        # column wide
+                        stack = scratch[0]
+                        for j in range(g):
+                            stack[j * Bp:(j + 1) * Bp, :] = onehot_of(f + j)
+                        onehot = stack[:g * Bp, :]
+                    land(slice(f * Bp, (f + g) * Bp), jax.lax.dot_general(
+                        onehot, sexp, (((1,), (1,)), ((), ())),
+                        precision=dot_prec,
+                        preferred_element_type=acc_dtype))
+
+                for last, groups in runs:
+                    def run(groups=groups):
+                        for f, g in groups:
+                            contract(f, g)
+                    if last >= nf - 1:
+                        run()
+                    else:
+                        pl.when(fi <= last)(run)
 
             def zero_from_first_block(cond, rows):
                 @pl.when(cond & (i == 0))
@@ -617,17 +710,46 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                     out_ref[rows, :] = jnp.zeros(
                         (rows.stop - rows.start, K * S), acc_dtype)
 
-            for f in range(min(fblk, live)):
-                # position f holds a live column in chunks 0..last
-                last = (live - 1 - f) // fblk
-                if last >= nf - 1:
-                    contract(f)
-                else:
-                    pl.when(fi <= last)(functools.partial(contract, f))
-                    zero_from_first_block(
-                        fi > last, slice(f * Bp, (f + 1) * Bp))
-            if live < fblk:  # positions that are padding in every chunk
-                zero_from_first_block(True, slice(live * Bp, fblk * Bp))
+            if G == 1:
+                # the ungrouped kernel: a column's first dot stores, dead
+                # positions are zeroed where they are dead
+                for last, fs in positions.items():
+                    if last < nf - 1:
+                        zero_from_first_block(
+                            fi > last, slice(fs[0] * Bp, (fs[-1] + 1) * Bp))
+                if live < fblk:  # positions that are padding in every chunk
+                    zero_from_first_block(True, slice(live * Bp, fblk * Bp))
+                sweep(slice(None), lambda rows, acc: accumulate(
+                    i, out_ref, rows, acc))
+                return
+            # every dot adds: dead and padding rows stay as zeroed
+            zero_from_first_block(True, slice(0, fblk * Bp))
+            if lanes == block:
+                def add(rows, acc):
+                    out_ref[rows, :] += acc
+                sweep(slice(None), add)
+                return
+            # a block's sub-blocks are summed apart, in `part`, and meet the
+            # accumulator once, as a whole block's dot does: its sums run
+            # into the hundreds of thousands, where every f32 add rounds
+            part = scratch[1]
+
+            def first(rows, acc):
+                part[rows, :] = acc
+
+            def middle(rows, acc):
+                part[rows, :] += acc
+
+            def final(rows, acc):
+                out_ref[rows, :] += part[rows, :] + acc
+
+            def body(sb, carry):
+                sweep(pl.ds(pl.multiple_of(sb * lanes, lanes), lanes), middle)
+                return carry
+
+            sweep(pl.ds(0, lanes), first)
+            jax.lax.fori_loop(1, block // lanes - 1, body, 0)
+            sweep(pl.ds(block - lanes, lanes), final)
         return kernel
 
     # Mosaic block-shape rule: the last two dims of every block must be
@@ -655,26 +777,37 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         ks_pad = -(-(K * S) // 128) * 128
         fblk, nf = perfeature_chunks(F, B, K, S,
                                      bins_t_blocks.dtype.itemsize)
+        G = perfeature_columns_per_dot(B, block, precision, fblk, live)
+        # a group's dots run over lane sub-blocks of the row block; the
+        # 4-bit stride layout spans the block, and one column at a time is
+        # the kernel as it was
+        lanes = (block if G == 1 or packed_rows
+                 else perfeature_dot_lanes(block))
+        dot_bytes = jnp.dtype(dot_dtype).itemsize
         # scoped-VMEM ceiling, from the shapes: the compiler's default
         # (16 MiB on a v5e) is under what the block-scaled temporaries
         # need at 16384 rows (int8 there: "Scoped allocation with size
         # 18.45M and limit 16.00M exceeded scoped vmem limit").  An upper
         # bound, not a reservation: double-buffered in/out blocks (the
         # [S, blk] stats and [1, blk] leaf ids each pad to one 32-byte
-        # sublane tile per row) plus the [Bp, blk] iota, compare and
-        # one-hot and the [K*S, blk] slot expansion at 32 bits and
-        # narrowed, all live at once
+        # sublane tile per row) plus the [Bp, lanes] iota, compare and
+        # one-hot and the [K*S, lanes] slot expansion at 32 bits and
+        # narrowed, all live at once; a group adds its stacked one-hot
+        # (the scratch and the dot's read of it) and its [G*Bp, K*S]
+        # result, lane sub-blocks their partial accumulator
         pipelined = 2 * (fblk * Bp * ks_pad * 4
                          + fblk * bins_block * bins_t_blocks.dtype.itemsize
                          + (32 + 32) * block)
-        temporaries = block * (Bp * (4 + 4 + jnp.dtype(dot_dtype).itemsize)
+        temporaries = lanes * (Bp * (4 + 4 + dot_bytes)
                                + ks_pad * (4 + 4))
-        vmem_limit = pipelined + temporaries
+        stacked = (G > 1) * G * Bp * (2 * lanes * dot_bytes + ks_pad * 4)
+        part = (lanes < block) * fblk * Bp * ks_pad * 4
+        vmem_limit = pipelined + temporaries + stacked + part
         # grid order: the row-block axis is LAST (innermost), so each
         # feature chunk's accumulator block stays resident while the row
         # sweep accumulates into it
         raw = pl.pallas_call(
-            kernel_perfeature_chunk(fblk, nf),
+            kernel_perfeature_chunk(fblk, nf, G, lanes),
             grid=(nf, nb),
             in_specs=[
                 pl.BlockSpec((1, fblk, bins_block), lambda fi, i: (i, fi, 0)),
@@ -685,6 +818,10 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             out_specs=pl.BlockSpec((fblk * Bp, K * S),
                                    lambda fi, i: (fi, 0)),
             out_shape=jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
+            scratch_shapes=(
+                [pltpu.VMEM((G * Bp, lanes), dot_dtype)] * (G > 1)
+                + [pltpu.VMEM((fblk * Bp, K * S), acc_dtype)]
+                * (lanes < block)),
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=vmem_limit),
             interpret=interpret,

@@ -400,6 +400,91 @@ class TestBatchedHistogramImpls:
             H.build_histogram_batched_t(*args, impl="pallas2",
                                         live_columns=F + 1)
 
+    @pytest.mark.parametrize("precision", ["hilo", "int8"])
+    @pytest.mark.parametrize("K", [1, 25])
+    @pytest.mark.parametrize("F,live", [(32, 3), (32, 28), (32, 32),
+                                        (96, 67)])
+    @pytest.mark.parametrize("B", [15, 63, 255])
+    def test_pallas2_column_groups(self, monkeypatch, B, F, live, K,
+                                   precision):
+        # groups of up to G = 4 columns, one dot each: 3 live is one short
+        # group, 28 and 32 whole groups (the last beside padding, or none);
+        # 96 stored run in three chunks of 32, where {0, 1, 2} are live in
+        # every chunk and 3..31 under the chunk guard, seven groups of four
+        # and one of one, all dead (zeroed) in the last chunk.  A group's
+        # dots run over three 128-lane sub-blocks of the 384-row block (the
+        # first stores the block's partial sums, the loop's one adds, the
+        # last adds them to the accumulator); 15 bins are the packed 4-bit
+        # rows, whose groups take the block whole
+        # and whose Bp = 16 is half an int8 sublane tile: int8 there stays
+        # ungrouped, the kernel as it was
+        from lightgbm_tpu.ops import histogram as H
+        rng = np.random.default_rng(28)
+        nb, block = 2, 384
+        packed = B == 15
+        Bp = -(-B // 8) * 8
+        S, itemsize = (5, 2) if precision == "hilo" else (3, 1)
+        monkeypatch.setattr(H, "_PERFEATURE_GROUP_LANES", 128)
+        assert H.perfeature_dot_lanes(block) == 128
+        monkeypatch.setattr(H, "_PERFEATURE_OUT_BUDGET",
+                            32 * Bp * 128 * 4)   # fblk = 32 at any B
+        assert H.perfeature_chunks(F, B, K, S, 1) == (32, F // 32)
+        want_g = 1 if packed and precision == "int8" else min(4, live)
+        assert H.perfeature_columns_per_dot(
+            B, block, precision, 32, live) == want_g
+        n = nb * block
+        bins = rng.integers(0, B, size=(nb, F, block)).astype(np.uint8)
+        bins[:, live:] = 0
+        g = jnp.asarray(rng.normal(size=n).astype(np.float32))
+        h = jnp.abs(g) + 0.3
+        if precision == "int8":
+            g = H.quantize_values(g, jnp.max(jnp.abs(g)) / 127, 127,
+                                  "nearest")
+            h = H.quantize_values(h, jnp.max(h) / 127, 127, "nearest")
+        stats = H.pack_stats(g, h, jnp.ones(n, jnp.float32), precision)
+        rest = (stats.reshape(S, nb, block),
+                jnp.asarray(rng.integers(0, K + 2, size=(nb, block)),
+                            dtype=jnp.int32),
+                jnp.asarray(rng.permutation(K + 2)[:K], dtype=jnp.int32),
+                B, precision)
+        a = np.asarray(H.build_histogram_batched_t(
+            jnp.asarray(bins), *rest, impl="xla"))
+        if packed:  # row j in the low nibble, row j + block/2 in the high
+            bins = bins[..., :block // 2] | (bins[..., block // 2:] << 4)
+        b = np.asarray(H.build_histogram_batched_t(
+            jnp.asarray(bins), *rest, impl="pallas2", packed_rows=packed,
+            live_columns=live))
+        if precision == "hilo" and want_g > 1 and not packed:
+            # three f32 partial sums per block where xla has one: a few
+            # ulp of a bin's sum (int8 accumulates exactly in any order)
+            np.testing.assert_allclose(a[:, :live], b[:, :live],
+                                       rtol=1e-5, atol=1e-5)
+        else:
+            np.testing.assert_array_equal(a[:, :live], b[:, :live])
+        assert not b[:, live:].any()
+
+    @pytest.mark.parametrize("args,want", [
+        # the three cells: 8192-row blocks, hilo, one chunk of 32 columns
+        # (28 live) on Higgs, chunks of 32 with 67 live on the Criteo shard
+        ((255, 8192, "hilo", 32, 28), 4), ((63, 8192, "hilo", 32, 28), 4),
+        ((255, 8192, "hilo", 32, 67), 4),
+        # never more columns than a chunk holds live
+        ((63, 8192, "hilo", 32, 3), 3), ((15, 8192, "hilo", 32, 28), 4),
+        ((63, 8192, "int8", 32, 28), 4), ((255, 16384, "int8", 32, 28), 4),
+        # what the 2 MiB of a stacked [G * Bp, 1024] one-hot hold: f32 is
+        # twice as wide as bf16, 511 bins twice as tall as 255, and a block
+        # that is no whole number of 1024-lane sub-blocks is taken whole
+        ((255, 8192, "f32", 32, 28), 2), ((511, 8192, "hilo", 8, 8), 2),
+        ((255, 8320, "hilo", 32, 28), 1), ((15, 8320, "hilo", 32, 28), 4),
+        # one column only, the kernel as it was: 1,023 and 4,095 bins
+        # (int32 storage), and a Bp that is no whole sublane tile of the
+        # dot's dtype
+        ((1023, 8192, "hilo", 8, 8), 1), ((4095, 8192, "hilo", 8, 8), 1),
+        ((20, 8192, "hilo", 32, 28), 1), ((15, 8192, "int8", 32, 28), 1)])
+    def test_perfeature_columns_per_dot(self, args, want):
+        from lightgbm_tpu.ops import histogram as H
+        assert H.perfeature_columns_per_dot(*args) == want
+
     @pytest.mark.parametrize("layout", ["sparse", "streamed", "data"])
     def test_pallas2_layouts_match_xla_end_to_end(self, layout):
         """Every layout that hands the kernel a live count below its padded

@@ -5,8 +5,12 @@ u8 bins in 8192-row blocks, hilo statistics: what `%hist_build*` costs per
 call inside the grow program, without the program around it.  The sweep is
 the cells' own axes: B in {63, 255}; 32 stored columns of which 28 or all 32
 are live (`live_columns`, ops/histogram.py); K in {1, 4, 16, 25} slots, the
-ramp's widths and the loop's.  PERF.md §5 keeps the last table: the per-slot
-term it shows is what the next kernel change starts from.
+ramp's widths and the loop's; and the Criteo shard's shape, 67 live of 96
+stored columns at 255 bins over 13 x 2^20 rows, which the kernel runs as
+three feature chunks.  Each point says how many columns one dot of the
+kernel stacks there (`perfeature_columns_per_dot`).  PERF.md §5 keeps the
+last table: the per-slot term it shows is what the next kernel change
+starts from.
 
     chiprun -- python tools/hist_microbench.py [--rows N] [--iters N]
 
@@ -26,18 +30,24 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.lib.peaks import PEAKS
-from lightgbm_tpu.ops.histogram import build_histogram_batched_t, pack_stats
+from lightgbm_tpu.ops.histogram import (build_histogram_batched_t, pack_stats,
+                                        perfeature_chunks,
+                                        perfeature_columns_per_dot)
 
 BLOCK = 8192
-STORED = 32
+# (bins, stored columns, live columns, rows; None: --rows): the Higgs cells'
+# table at both bin counts, every column live beside it, the Criteo shard
+SHAPES = ((63, 32, 28, None), (63, 32, 32, None), (255, 32, 28, None),
+          (255, 32, 32, None), (255, 96, 67, 13 << 20))
+SLOTS = (1, 4, 16, 25)
 
 
-def operands(rows: int, bins: int, live: int):
-    """Device-made operands of one call: [nb, 32, 8192] u8 bins whose
+def operands(rows: int, bins: int, stored: int, live: int):
+    """Device-made operands of one call: [nb, stored, 8192] u8 bins whose
     columns past `live` are the learner's padding (constant bin 0)."""
     nb = rows // BLOCK
     kb, kg, kl = jax.random.split(jax.random.PRNGKey(0), 3)
-    b = jax.random.randint(kb, (nb, STORED, BLOCK), 0, bins, jnp.uint8)
+    b = jax.random.randint(kb, (nb, stored, BLOCK), 0, bins, jnp.uint8)
     b = b.at[:, live:].set(0)
     g = jax.random.normal(kg, (nb * BLOCK,), jnp.float32)
     stats = pack_stats(g, jnp.abs(g) + 0.1,
@@ -63,50 +73,54 @@ def time_call(ops, bins: int, live: int, slots: int, iters: int):
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--rows", type=int, default=26 << 20,
-                    help="rows per call, cut to whole 8192-row blocks")
+                    help="rows per call of the 32-column shapes (and at "
+                         "most this many of the Criteo shard's), cut to "
+                         "whole 8192-row blocks")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--out", default="chiprun_out/hist_microbench.jsonl")
     a = ap.parse_args()
     dev = jax.devices()[0]
-    rows = a.rows // BLOCK * BLOCK
     print(f"platform={dev.platform} device_kind={dev.device_kind} "
-          f"rows={rows} block={BLOCK} stored_columns={STORED} hilo u8",
-          flush=True)
+          f"block={BLOCK} hilo u8", flush=True)
     # the MXU's time for the contraction as run (live columns, bins padded
     # to the sublane tile, one 128-lane tile of slots x planes), where the
     # device's peak is published; a CPU rehearsal has none
     peak = PEAKS.get(dev.device_kind, {}).get("bf16_flops")
-    points = []
-    for bins in (63, 255):
+    points, table = [], []
+    for bins, stored, live, rows in SHAPES:
+        rows = min(rows or a.rows, a.rows) // BLOCK * BLOCK
         bp = -(-bins // 8) * 8
-        for live in (28, STORED):
-            ops = operands(rows, bins, live)
-            mxu_ms = peak and 2.0 * rows * live * bp * 128 / peak * 1e3
-            for slots in (1, 4, 16, 25):
-                ms, first_s = time_call(ops, bins, live, slots, a.iters)
-                points.append({
-                    "platform": dev.platform, "device_kind": dev.device_kind,
-                    "rows": rows, "bins": bins, "live_columns": live,
-                    "slots": slots, "ms_per_call": ms,
-                    "first_call_s": first_s, "mxu_tile_ms": mxu_ms})
-                mxu_txt = f"{mxu_ms:.1f} ms" if peak else "n/a"
-                print(f"B={bins:3d} live={live:2d}/{STORED} K={slots:2d}: "
-                      f"{ms:8.2f} ms/call  (MXU tile {mxu_txt}, "
-                      f"first call {first_s:5.1f} s)", flush=True)
+        ops = operands(rows, bins, stored, live)
+        mxu_ms = peak and 2.0 * rows * live * bp * 128 / peak * 1e3
+        mxu_txt = f"{mxu_ms:.1f} ms" if peak else "n/a"
+        ms = {}
+        for slots in SLOTS:
+            fblk, chunks = perfeature_chunks(stored, bins, slots, 5)
+            per_dot = perfeature_columns_per_dot(bins, BLOCK, "hilo", fblk,
+                                                 live)
+            ms[slots], first_s = time_call(ops, bins, live, slots, a.iters)
+            points.append({
+                "platform": dev.platform, "device_kind": dev.device_kind,
+                "rows": rows, "bins": bins, "stored_columns": stored,
+                "live_columns": live, "slots": slots,
+                "feature_chunks": chunks, "columns_per_dot": per_dot,
+                "ms_per_call": ms[slots], "first_call_s": first_s,
+                "mxu_tile_ms": mxu_ms})
+            print(f"B={bins:3d} live={live:2d}/{stored} K={slots:2d} "
+                  f"chunks={chunks} G={per_dot:2d}: {ms[slots]:8.2f} ms/call"
+                  f"  (MXU tile {mxu_txt}, first call {first_s:5.1f} s)",
+                  flush=True)
+        table.append(f"| {bins} | {live} of {stored} | {rows} | {per_dot} | "
+                     + " | ".join(f"{ms[k]:.1f}" for k in SLOTS)
+                     + f" | {(ms[25] - ms[1]) / 24:.2f} |")
     os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
     with open(a.out, "a") as f:
         for p in points:
             f.write(json.dumps(p) + "\n")
-    print("\n| B | live | K=1 | K=4 | K=16 | K=25 | ms per slot (25 vs 1) |")
-    print("|---|---|---|---|---|---|---|")
-    for bins in (63, 255):
-        for live in (28, STORED):
-            ms = {p["slots"]: p["ms_per_call"] for p in points
-                  if (p["bins"], p["live_columns"]) == (bins, live)}
-            print(f"| {bins} | {live} of {STORED} | "
-                  + " | ".join(f"{ms[k]:.1f}" for k in (1, 4, 16, 25))
-                  + f" | {(ms[25] - ms[1]) / 24:.2f} |")
-
+    print("\n| B | live | rows | G | " + " | ".join(f"K={k}" for k in SLOTS)
+          + " | ms per slot (25 vs 1) |")
+    print("|---|---|---|---|" + "---|" * (len(SLOTS) + 1))
+    print("\n".join(table))
 
 if __name__ == "__main__":
     main()
