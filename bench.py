@@ -82,31 +82,6 @@ def hist_rows_per_sec(bins_np, num_bins, precision, reps=3):
     return rates
 
 
-def autotune_resolve_ms_probe(num_bins):
-    """Wall ms of the steady-state autotune path: load the persisted
-    profile and resolve one shape bucket (the cost every learner
-    construction under tpu_autotune=load pays).  The measurement tunes a
-    throwaway profile first so the timed part is pure load+resolve."""
-    import tempfile
-
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.utils.autotune import resolve_autotune
-
-    with tempfile.TemporaryDirectory() as td:
-        path = os.path.join(td, "autotune_profile.json")
-        cfg_tune = Config({"objective": "binary", "tpu_autotune": "tune",
-                           "tpu_autotune_profile": path})
-        resolve_autotune(cfg_tune, 8192, 8, num_bins, "int8")
-        cfg_load = Config({"objective": "binary", "tpu_autotune": "load",
-                           "tpu_autotune_profile": path})
-        t0 = time.time()
-        entry = resolve_autotune(cfg_load, 8192, 8, num_bins, "int8")
-        ms = (time.time() - t0) * 1e3
-        if entry is None:
-            raise RuntimeError("autotune round-trip lost its own entry")
-    return ms
-
-
 def spread(rates):
     """(median, min) of a repeat series — every timed metric reports its
     own variance (VERDICT item 7) instead of a single unqualified
@@ -512,8 +487,6 @@ def run(n_rows, num_leaves, max_bin, bench_iters):
         hist_rows_per_sec(bins_np, hist_bins, "int8"))
     hist_hilo, hist_hilo_min = spread(
         hist_rows_per_sec(bins_np, hist_bins, "hilo"))
-    # ISSUE 18: the autotune profile round-trip cost
-    autotune_ms = autotune_resolve_ms_probe(hist_bins)
     n_programs = LEDGER.n_programs()
     ledger_sites = {a["site"]: a["programs"] for a in LEDGER.report()}
 
@@ -577,10 +550,8 @@ def run(n_rows, num_leaves, max_bin, bench_iters):
         "hist_int8_rows_per_sec_min": round(hist_int8_min, 0),
         "hist_hilo_rows_per_sec": round(hist_hilo, 0),
         "hist_hilo_rows_per_sec_min": round(hist_hilo_min, 0),
-        # ISSUE 18: per-iteration grow wall and the steady-state autotune
-        # profile load+resolve cost
+        # ISSUE 18: per-iteration grow wall
         "grow_iter_ms": round(1000.0 * train_s / max(bench_iters, 1), 2),
-        "autotune_resolve_ms": round(autotune_ms, 2),
         "ingest_rows_per_sec": round(ingest_rows_per_sec, 0),
         # ISSUE 16: out-of-core streaming — throughput at 4x the base
         # row count, overlap achieved, and the full scaling curve

@@ -496,25 +496,8 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     # JAX_COMPILATION_CACHE_DIR is set — the environment places the
     # cache (utils/backend.py)
     "tpu_compile_cache_dir": ("str", "", ()),
-    # persisted perf autotuning (utils/autotune.py): off | load | tune.
-    #   off  - every "auto" resolves from the built-in heuristics
-    #   load - resolve "auto" (hist impl x block, hist_agg) from the
-    #          measured profile file when a matching (backend, topology,
-    #          shape-bucket) entry exists; a profile recorded on a
-    #          DIFFERENT platform or device count is refused loudly
-    #          (AutotuneStaleProfile), never silently applied
-    #   tune - run the measurement sweep for this dataset's shape bucket
-    #          first (tools/perf_probe.py's hist sweep), persist the
-    #          winners, then resolve like load.  `perf_probe tune` runs
-    #          the same sweep standalone
-    "tpu_autotune": ("str", "off", ()),
-    # autotune profile path; empty = autotune_profile.json beside the
-    # persistent XLA compile cache (tpu_compile_cache_dir), or the
-    # in-repo .lgbtpu_autotune.json when no cache dir is set
-    "tpu_autotune_profile": ("str", "", ()),
-    # rows per histogram scan block (device-side); 0 = auto (256 for the
-    # pallas backend — its VMEM-resident accumulator wants short blocks —
-    # 16384 for the xla scan, tuned for HBM streaming)
+    # rows per histogram scan block (device-side); 0 = auto (8192 for the
+    # pallas2 kernel, 16384 for the xla scan, tuned for HBM streaming)
     "tpu_block_rows": ("int", 0, ()),
     # leaves split per grower round: >1 batches histogram work onto the MXU
     # (K*5 stat lanes -> 128-lane systolic tiles); 1 = strict reference
@@ -524,17 +507,11 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     # tracks strict best-first closely even while histogramming K leaves
     # per pass
     "tpu_split_batch": ("int", 0, ()),
-    # batched-histogram backend: auto | xla | pallas | pallas2 | fused.
-    # auto is a fixed rule (learner._resolve_hist_impl): pallas2 on a TPU
-    # at hilo/bf16/int8 when its VMEM working set fits, xla everywhere
-    # else (CPU, f32/f64, int16).  pallas2 = per-feature one-hot variant
-    # running 2-8k-row blocks.  fused = the grow megakernel
-    # (ops/fused.py): pallas2's accumulator PLUS in-VMEM sibling
-    # subtraction and the split gain scan, on serial quantized
-    # (int8/int16) plain dense training.  fused is explicit-only and auto
-    # never picks it: Mosaic does not lower its in-kernel scan on a TPU
-    # (cumsum), so there it raises the compiler's error; it runs in
-    # interpret mode on CPU
+    # batched-histogram backend: auto | xla | pallas2.  auto is a fixed
+    # rule (learner._resolve_hist_impl): pallas2 on a TPU at hilo/bf16/int8
+    # when its VMEM working set fits, xla everywhere else (CPU, f32/f64,
+    # int16).  xla = lax.scan + dot_general; pallas2 = the per-feature
+    # one-hot VMEM kernel (ops/histogram.py _hist_pallas) at 8192-row blocks
     "tpu_hist_impl": ("str", "auto", ()),
     # data-axis histogram aggregation (tree_learner=data / voting /
     # data_feature): psum | scatter | auto.
@@ -562,19 +539,12 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     # only batch leaves whose gain >= alpha * the round's best gain (near
     # ties); keeps batched split order close to strict best-first
     "tpu_split_batch_alpha": ("float", 0.0, ()),
-    # row-partition lowering: select | vselect | gather | kernel
-    # (ops/grower.py GrowerParams.partition_impl; honored by every tree
-    # learner).  vselect fuses the K unrolled select passes into one
-    # [K, n] block — fewer program points, but its CATEGORICAL path
-    # gathers per-row from a tiny table (the pattern select avoids);
-    # prefer select on categorical-heavy data until vselect is
-    # hardware-timed there.  kernel = the pallas row->leaf partition
-    # (ops/fused.py partition_rows): vselect's exact integer math as one
-    # VMEM pass over the row blocks instead of a separate XLA program
-    # point — plain dense numerical columns only (no categoricals, EFB,
-    # sparse storage, or 4-bit packing).  CPU (interpret mode) only so
-    # far: Mosaic on a v5e refuses the kernel ("Unsupported target
-    # bitwidth for truncation", an i8->i1 trunci)
+    # row-partition lowering: select | vselect (ops/grower.py
+    # GrowerParams.partition_impl; honored by every tree learner).  select
+    # unrolls one scalar-broadcast pass per split; vselect fuses the K
+    # passes into one [K, n] block — fewer program points, but its
+    # CATEGORICAL path gathers per-row from a tiny table (the pattern
+    # select avoids)
     "tpu_partition_impl": ("str", "select", ()),
     # frontier ramp: unrolled K'=1,2,4,... pre-rounds before the full-K
     # loop (bit-identical trees, removes early rounds' dead-slot MXU

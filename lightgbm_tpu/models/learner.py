@@ -196,17 +196,25 @@ class TPUTreeLearner:
                 raise ValueError("row shards must split evenly across "
                                  "processes for pre_partition")
 
-        for key, allowed in (("tpu_partition_impl", ("select", "vselect",
-                                                     "gather", "kernel")),
-                             ("tpu_hist_impl", ("auto", "xla", "pallas",
-                                                "pallas2", "fused")),
+        # values whose implementations were deleted (the chip's compiler
+        # refused them, or they won nowhere) and what serves in their place
+        removed = {("tpu_hist_impl", "pallas"): "pallas2",
+                   ("tpu_hist_impl", "fused"): "pallas2",
+                   ("tpu_partition_impl", "gather"): "select",
+                   ("tpu_partition_impl", "kernel"): "select"}
+        for key, allowed in (("tpu_partition_impl", ("select", "vselect")),
+                             ("tpu_hist_impl", ("auto", "xla", "pallas2")),
                              ("tpu_hist_precision", ("hilo", "bf16", "f32",
                                                      "f64", "int8", "int16")),
                              ("tpu_quant_round", ("stochastic", "nearest")),
                              ("tpu_hist_agg", ("auto", "psum", "scatter")),
-                             ("tpu_bucket_policy", ("fine", "wide")),
-                             ("tpu_autotune", ("off", "load", "tune"))):
-            if str(getattr(config, key)) not in allowed:
+                             ("tpu_bucket_policy", ("fine", "wide"))):
+            value = str(getattr(config, key))
+            if (key, value) in removed:
+                raise ValueError(
+                    f"{key}={value} was removed; use {key}="
+                    f"{removed[key, value]} (or leave the default)")
+            if value not in allowed:
                 raise ValueError(f"{key}={getattr(config, key)!r}; "
                                  f"expected one of {allowed}")
         self.hist_agg = self._resolve_hist_agg(config, strategy,
@@ -370,19 +378,8 @@ class TPUTreeLearner:
             # while the padded row count below depends on the resolved block.
             # (The perfeature kernel chunks the feature axis itself, so the
             # VMEM fit depends only on the bin count, not the feature width.)
-            # persisted autotune profile (utils/autotune.py): measured winners
-            # for this (platform, device count, shape bucket) override the
-            # "auto" heuristics below; a stale profile (other topology) raises
-            # AutotuneStaleProfile here rather than training on wrong winners
-            self._autotune_entry = None
-            if str(config.tpu_autotune) != "off":
-                from ..utils.autotune import resolve_autotune
-
-                self._autotune_entry = resolve_autotune(
-                    config, n, self.num_features, B, precision)
-            hist_impl, block = self._resolve_hist_impl(
-                config, B, precision, tuned=self._autotune_entry)
-            if hist_impl in ("pallas2", "fused"):
+            hist_impl, block = self._resolve_hist_impl(config, B, precision)
+            if hist_impl == "pallas2":
                 # the perfeature kernel chunks its feature grid in
                 # sublane-aligned (multiple-of-32) divisors (ops/histogram.py
                 # _hist_pallas); pad the histogram column axis so every width
@@ -635,10 +632,13 @@ class TPUTreeLearner:
             # what the histogram kernel is told to contract: the live
             # columns are the matrix's first `live`, the padding its tail.
             # Only the perfeature kernel reads the count; feature shards run
-            # one program on slices whose live counts differ, so they keep
-            # the full extent
-            self.live_columns = (live if hist_impl == "pallas2"
-                                 and self.f_shards == 1 else None)
+            # one program on slices whose live counts differ, so a grower
+            # with a feature axis (of any size: data_feature on two devices
+            # has one feature shard) keeps the full extent
+            self.live_columns = (
+                live if hist_impl == "pallas2"
+                and self.strategy not in ("feature", "data_feature")
+                else None)
             contracted = self.live_columns or bins_t.shape[0]
             for kind, count in (("live", contracted),
                                 ("padding", bins_t.shape[0] - contracted)):
@@ -648,7 +648,7 @@ class TPUTreeLearner:
                          "(live) and skips (padding)")
             obs.REGISTRY.set_gauge(
                 "lgbm_hist_root_slots",
-                1 if hist_impl in ("pallas", "pallas2", "fused") else 0,
+                1 if hist_impl == "pallas2" else 0,
                 help="leaf slots of the root histogram pass (0: the xla "
                      "root scan has no slot axis)")
 
@@ -698,9 +698,8 @@ class TPUTreeLearner:
             self.packed_bins = (
                 bool(config.tpu_pack_bins) and B <= 16
                 and not self.stream_layout
-                and hist_impl in ("pallas", "pallas2") and plan is None
+                and hist_impl == "pallas2" and plan is None
                 and self._sparse_arrays is None and not self._partitioned
-                and str(config.tpu_partition_impl) in ("select", "vselect")
                 and eff_block % 256 == 0 and local_rows % eff_block == 0)
             if self.packed_bins:
                 x = bins_t.reshape(self.g_pad, self.n_pad // eff_block, 2,
@@ -993,33 +992,23 @@ class TPUTreeLearner:
         return k, 5 if precision == "hilo" else 3
 
     @staticmethod
-    def _resolve_hist_impl(config: Config, num_bins: int, precision: str,
-                           tuned: Optional[dict] = None) -> Tuple[str, int]:
+    def _resolve_hist_impl(config: Config, num_bins: int, precision: str
+                           ) -> Tuple[str, int]:
         """Resolve (tpu_hist_impl, tpu_block_rows), honoring "auto"/0.
-
-        `tuned` is the autotune profile entry for this shape bucket
-        (utils/autotune.resolve_autotune): its measured winners replace
-        the heuristics below wherever the config says "auto"/0 — an
-        explicit impl or block always wins over the profile.
 
         Auto is a rule over what the code can observe, never the outcome
         of running a kernel: the perfeature pallas kernel ("pallas2") on a
         TPU at hilo/bf16/int8 — its largest VMEM temporary is a [Bp, block]
-        one-hot (not the flat kernel's [F*B, block]), so multi-k-row blocks
-        fit and the kernel self-chunks the feature axis when the
-        accumulator would overflow — and the xla scan everywhere else: CPU,
-        f32/f64, int16, bin counts too tall for even the minimum
-        dtype-tile-wide feature chunk (32 features for uint8 bins, 8 for
-        int32), or an explicit row block the kernel's grid cannot take.
-        "fused", flat "pallas" and int16 "pallas2" are explicit-only.
+        one-hot, so multi-k-row blocks fit and the kernel self-chunks the
+        feature axis when the accumulator would overflow — and the xla
+        scan everywhere else: CPU, f32/f64, int16, bin counts too tall for
+        even the minimum dtype-tile-wide feature chunk (32 features for
+        uint8 bins, 8 for int32), or an explicit row block the kernel's
+        grid cannot take.
+        int16 "pallas2" is explicit-only.
         """
         impl = str(config.tpu_hist_impl)
         block = int(config.tpu_block_rows)
-        if tuned:
-            if impl == "auto" and tuned.get("hist_impl"):
-                impl = str(tuned["hist_impl"])
-            if block <= 0 and int(tuned.get("block_rows", 0) or 0) > 0:
-                block = int(tuned["block_rows"])
         if impl == "auto":
             from ..ops.histogram import (PERFEATURE_AUTO_PRECISIONS,
                                          perfeature_chunk_fits)
@@ -1043,8 +1032,7 @@ class TPUTreeLearner:
             impl = ("pallas2" if on_tpu and chunk_fits and block_ok
                     and precision in PERFEATURE_AUTO_PRECISIONS else "xla")
         if block <= 0:
-            block = {"pallas": 256, "pallas2": 8192,
-                     "fused": 8192}.get(impl, 16384)
+            block = 8192 if impl == "pallas2" else 16384
         return impl, block
 
     @staticmethod
@@ -1064,7 +1052,7 @@ class TPUTreeLearner:
         if precision in ("int8", "int16"):
             return precision
         jax.config.update("jax_enable_x64", True)
-        if str(config.tpu_hist_impl) in ("pallas", "pallas2", "fused"):
+        if str(config.tpu_hist_impl) == "pallas2":
             raise ValueError(
                 "deterministic=true requires tpu_hist_impl=xla")
         return "f64"

@@ -13,7 +13,7 @@ BATCHED ROUNDS, each splitting up to `split_batch` leaves at once:
   reference's strict best-first order at split_batch=1), partitions all K
   leaves' rows in one vectorized pass, and histograms all K smaller
   children in ONE [F*B, n] x [n, K*S] MXU contraction
-  (ops/histogram.py build_histogram_batched_inline).  Batching exists for
+  (ops/histogram.py build_histogram_batched_t).  Batching exists for
   the MXU: a single-leaf histogram is an M=8 matmul (~3% MFU measured);
   K leaves widen the small axis to K*S >= 128 lanes, the whole systolic
   array lights up, and a tree takes ~254/K passes instead of 254;
@@ -99,14 +99,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..utils.compile_ledger import ledger_jit
-from .fused import fused_hist_scan, partition_rows
 from .histogram import (build_histogram_batched_t, build_histogram_sparse,
                         build_histogram_t, key_words, pack_stats,
                         quant_limit, quantize_values, unpack2d)
 from .split import (K_MIN_SCORE, SplitResult, argbest, finalize_split,
                     leaf_output, leaf_split_gain, numeric_go_left,
                     per_feature_best_split,
-                    per_feature_best_split_categorical, unpack_pf_records,
+                    per_feature_best_split_categorical,
                     MISSING_NAN, MISSING_ZERO)
 
 
@@ -173,17 +172,14 @@ class GrowerParams(NamedTuple):
     # 607-769): static BFS-ordered tuple of (parent_leaf, feature, thr_bin)
     # applied as unrolled rounds before best-gain growth
     forced: tuple = ()
-    # batched-histogram backend: "xla" (scan + dot_general), "pallas"
-    # or "pallas2" (fused VMEM kernels — ops/histogram.py _hist_pallas
-    # with variant="flat" / "perfeature")
+    # batched-histogram backend: "xla" (scan + dot_general) or "pallas2"
+    # (the perfeature VMEM kernel — ops/histogram.py _hist_pallas)
     hist_impl: str = "xla"
     # row-partition lowering: "select" unrolls K scalar-broadcast passes
     # (one dynamic row slice + elementwise compare per split — no per-row
     # table gathers, which XLA serializes on TPU); "vselect" fuses those
     # K passes into one [K, n] block (fewer program points; NOTE its
-    # categorical path per-row-gathers from the [K, CB] mask table);
-    # "gather" resolves each row's slot through [L]/[K] table lookups
-    # (one pass, but gather-bound)
+    # categorical path per-row-gathers from the [K, CB] mask table)
     partition_impl: str = "select"
     # EFB (reference FindGroups/FastFeatureBundling, dataset.cpp:91-263):
     # bins_t holds G <= F bundle columns; meta carries bundle_idx /
@@ -373,31 +369,19 @@ def _build_grower(params, num_features, data_axis, feature_axis,
         raise ValueError("EFB bundling does not compose with forced splits; "
                          "set enable_bundle=false")
     if params.packed_bins and (
-            params.has_bundles
-            or params.partition_impl not in ("select", "vselect")
-            or not params.hist_impl.startswith("pallas")):
+            params.has_bundles or params.hist_impl != "pallas2"):
         raise ValueError(
-            "packed 4-bit bins require the pallas histogram impl, a "
-            "select-family partition lowering, and no EFB bundling")
+            "packed 4-bit bins require the pallas2 histogram impl and no "
+            "EFB bundling")
     if params.has_sparse and (
-            feature_axis or params.has_bundles
-            or params.packed_bins
-            or params.partition_impl not in ("select", "vselect")):
+            feature_axis or params.has_bundles or params.packed_bins):
         # EFB/packing already reshape the dense matrix the sparse split
         # composes with; feature sharding replicates rows — serial,
         # data-parallel, and voting only
         raise ValueError(
             "sparse train-time storage (tpu_sparse_threshold) requires "
-            "tree_learner=serial/data/voting, a select-family partition "
-            "lowering, and no EFB bundling / 4-bit packing")
-    if params.partition_impl == "kernel" and (
-            params.has_cat or params.has_bundles or params.has_sparse
-            or params.packed_bins):
-        raise ValueError(
-            "tpu_partition_impl=kernel (the pallas row-partition) covers "
-            "plain dense numerical columns only — categorical splits, EFB "
-            "bundles, sparse storage, and 4-bit packing keep the "
-            "select-family lowerings")
+            "tree_learner=serial/data/voting and no EFB bundling / 4-bit "
+            "packing")
     precision = params.precision
     # quantized-gradient mode (tpu_hist_precision=int16|int8): stats ride
     # the MXU as narrow ints, histograms/pool/psum/subtraction stay in
@@ -558,23 +542,6 @@ def _build_grower(params, num_features, data_axis, feature_axis,
         return gain, fin
 
     bynode = params.feature_fraction_bynode < 1.0
-
-    # in-kernel split scan (hist_impl="fused"): the frontier megakernel
-    # runs sibling subtraction + the gain scan in VMEM and the round body
-    # consumes its per-feature best records instead of calling select().
-    # It engages only where its records provably reproduce select() bit
-    # for bit: the serial learner on plain dense quantized columns (the
-    # int32 cumsums are exact; every excluded feature — sharding, voting,
-    # per-node masks, categorical/EFB/sparse/CEGB/forced, packed bins —
-    # reshapes the search itself).  Everywhere else "fused" still rides
-    # the perfeature VMEM histogram accumulator and the device-resident
-    # select(), so the mode degrades, never errors.
-    fused_scan = (params.hist_impl == "fused" and quantized
-                  and data_axis is None and feature_axis is None
-                  and not voting_k and not bynode
-                  and not params.has_cat and not params.has_bundles
-                  and not params.has_sparse and not params.has_cegb
-                  and not params.forced and not params.packed_bins)
 
     def grow(bins_t: jnp.ndarray,       # [G, n_pad] uint8/int32 (rows on
              #                            lanes; cols >= n zero-filled)
@@ -1024,23 +991,6 @@ def _build_grower(params, num_features, data_axis, feature_axis,
         bins_blocks = jnp.moveaxis(bins_hist_t.reshape(Gd, nb, bcols), 1, 0)
         stats_blocks = stats.reshape(S, nb, block)
 
-        if fused_scan:
-            # static per-feature tables for the megakernel's in-VMEM scan
-            # (ops/fused.py layout); feature_mask is baked in because the
-            # fused predicate excludes per-node masks
-            zi = jnp.zeros(F, jnp.int32)
-            fmeta_i = jnp.stack(
-                [meta["num_bin"].astype(jnp.int32),
-                 meta["missing_type"].astype(jnp.int32),
-                 meta["default_bin"].astype(jnp.int32),
-                 meta["monotone"].astype(jnp.int32),
-                 zi, zi, zi, zi], axis=1)
-            zf = jnp.zeros(F, jnp.float32)
-            fmeta_f = jnp.stack(
-                [meta["penalty"].astype(jnp.float32),
-                 feature_mask.astype(jnp.float32),
-                 zf, zf, zf, zf, zf, zf], axis=1)
-
         if params.has_sparse:
             sp_idx_t = meta["sparse_idx"]
             sp_bin_t = meta["sparse_bin"]
@@ -1072,7 +1022,7 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             merged = jnp.concatenate([dense_h, sp], axis=-3)
             return jnp.take(merged, meta["hist_perm"], axis=-3)
         with jax.named_scope("hist_build"):
-            if params.hist_impl in ("pallas", "pallas2", "fused"):
+            if params.hist_impl == "pallas2":
                 # reuse the batched VMEM kernel at ONE slot (the all-zero
                 # root leaf ids), the shape the ramp's first pre-round
                 # compiles anyway: the xla scan at pallas-sized short
@@ -1312,13 +1262,12 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                     new_leaf = jnp.where(in_k & (~go_left_k),
                                          new_ids[k], new_leaf)
                 leaf_ids = new_leaf
-            elif params.partition_impl == "vselect":
-                # vectorized single-block form of "select": ONE [K, n]
+            else:
+                # "vselect", the vectorized single-block form: ONE [K, n]
                 # row gather + one fused elementwise block instead of K
                 # unrolled passes — K fewer program points for launch
-                # overhead at ~3 [K, n] intermediates of HBM traffic.
-                # Candidate for the non-contraction time (PERF_NOTES
-                # round-4); same math as "select" bit-for-bit.
+                # overhead at ~3 [K, n] intermediates of HBM traffic;
+                # same math as "select" bit-for-bit.
                 feat_rows = (meta["bundle_idx"][sel_feat]
                              if params.has_bundles else
                              meta["dense_col"][sel_feat]
@@ -1366,56 +1315,9 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                 moved_to = jnp.max(
                     jnp.where(move, new_ids[:, None], -1), axis=0)
                 leaf_ids = jnp.where(moved_to >= 0, moved_to, leaf_ids)
-            elif params.partition_impl == "kernel":
-                # pallas row-partition (ops/fused.py): one VMEM pass over
-                # the row blocks with the exact "vselect" integer math —
-                # plain dense numerical columns only (validated at build)
-                cols = bins_t[sel_feat]                      # [K, n_pad]
-                leaf_ids = partition_rows(
-                    cols, leaf_ids, sel, new_ids, sel_thr, sel_dleft,
-                    meta["missing_type"][sel_feat],
-                    meta["num_bin"][sel_feat],
-                    meta["default_bin"][sel_feat], do_k, nb, block)
-            else:
-                # single-pass gather form: row->slot via an [L]-table
-                # lookup, then [K]-table lookups per row
-                leaf_to_slot = jnp.full(L, -1, jnp.int32).at[
-                    jnp.where(do_k, sel, L)].set(kar, mode="drop")
-                k_of_r = leaf_to_slot[leaf_ids]                  # [n]
-                valid_r = k_of_r >= 0
-                kk_r = jnp.maximum(k_of_r, 0)
-                f_r = sel_feat[kk_r]
-                if params.has_bundles:
-                    g_r = meta["bundle_idx"][f_r]
-                    c_r = jnp.take_along_axis(bins_t, g_r[None, :],
-                                              axis=0)[0]
-                    col_r = fix_bundle_col(
-                        c_r, meta["bin_offset"][f_r],
-                        meta["num_bin"][f_r],
-                        meta["needs_fix"][f_r] > 0)
-                else:
-                    col_r = jnp.take_along_axis(
-                        bins_t, f_r[None, :], axis=0)[0]
-                nb_k = meta["num_bin"][sel_feat]
-                db_k = meta["default_bin"][sel_feat]
-                go_left = numeric_go_left(
-                    col_r, meta["missing_type"][sel_feat][kk_r],
-                    nb_k[kk_r], db_k[kk_r],
-                    sel_thr[kk_r], sel_dleft[kk_r])
-                if params.has_cat:
-                    # bitset membership: bins in the stored mask go left,
-                    # everything else (incl. the NaN bin) goes right
-                    # (reference CategoricalDecisionInner, tree.h:307-318)
-                    cm_r = cmask_sel.reshape(-1)[kk_r * CB + col_r]
-                    go_left = jnp.where(sel_iscat[kk_r], cm_r > 0.5,
-                                        go_left)
-                leaf_ids = jnp.where(valid_r & (~go_left), new_ids[kk_r],
-                                     leaf_ids)
 
             # ---- monotone constraint propagation -----------------------
-            # (reference serial_tree_learner.cpp:840-851); computed before
-            # the histograms because the fused megakernel's in-VMEM scan
-            # needs the child constraint bounds in its ctx operand
+            # (reference serial_tree_learner.cpp:840-851)
             p_min = state["leaf_min"][sel]
             p_max = state["leaf_max"][sel]
             mono_k = meta["monotone"][sel_feat]
@@ -1431,51 +1333,24 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             smaller_ids = jnp.where(
                 do_k, jnp.where(smaller_is_left, sel, new_ids), -1)
             parent_hist = state["pool"][sel]             # [K, F/P, B, 3]
-            if fused_scan:
-                # megakernel: histogram build + sibling subtraction + the
-                # split gain scan leave the kernel as [2K, F, RW] records;
-                # dead slots (do_k false) carry garbage records that the
-                # do_k-gated scatters below drop, exactly like the unfused
-                # path's garbage SplitResults
-                Cr = 2 * Kr
-                use_small = jnp.concatenate(
-                    [smaller_is_left, ~smaller_is_left]).astype(jnp.float32)
-                ctx = jnp.zeros((Cr + 1, 8), jnp.float32)
-                ctx = (ctx.at[:Cr, 0].set(jnp.concatenate([lg, rg]))
-                       .at[:Cr, 1].set(jnp.concatenate([lh, rh]))
-                       .at[:Cr, 2].set(jnp.concatenate([lc, rc]))
-                       .at[:Cr, 3].set(jnp.concatenate([l_min, r_min]))
-                       .at[:Cr, 4].set(jnp.concatenate([l_max, r_max]))
-                       .at[:Cr, 5].set(use_small)
-                       .at[Cr, 0].set(qscale[0])
-                       .at[Cr, 1].set(qscale[1])
-                       .at[Cr, 2].set(qscale[2]))
-                with jax.named_scope("fused_grow"):
-                    h_local, srecs = fused_hist_scan(
-                        bins_blocks, stats_blocks,
-                        leaf_ids.reshape(nb, block), smaller_ids,
-                        parent_hist, ctx, fmeta_i, fmeta_f, B, precision,
-                        split_kw=split_kw)
-                hist_small = h_local        # serial: agg_hist is identity
-            else:
-                # named_scope: the telemetry span names (hist_build /
-                # split_search) appear inside xprof device traces too —
-                # trace-time metadata, zero runtime cost
-                with jax.named_scope("hist_build"):
-                    h_local = build_histogram_batched_t(
-                        bins_blocks, stats_blocks,
-                        leaf_ids.reshape(nb, block),
-                        smaller_ids, B, precision,
-                        impl=params.hist_impl,
-                        packed_rows=params.packed_bins,
-                        live_columns=live_columns)           # [K, F, B, 3]
-                    h_local = merge_sparse_hist(h_local, leaf_ids,
-                                                smaller_ids)
-                    if sparse_tot:
-                        tot_small = preduce_scalar(jnp.sum(
-                            h_local[:, meta["dense_ref"][0]],
-                            axis=1))                         # [K, 3]
-                    hist_small = agg_hist(h_local)       # [K, F/P, B, 3]
+            # named_scope: the telemetry span names (hist_build /
+            # split_search) appear inside xprof device traces too —
+            # trace-time metadata, zero runtime cost
+            with jax.named_scope("hist_build"):
+                h_local = build_histogram_batched_t(
+                    bins_blocks, stats_blocks,
+                    leaf_ids.reshape(nb, block),
+                    smaller_ids, B, precision,
+                    impl=params.hist_impl,
+                    packed_rows=params.packed_bins,
+                    live_columns=live_columns)           # [K, F, B, 3]
+                h_local = merge_sparse_hist(h_local, leaf_ids,
+                                            smaller_ids)
+                if sparse_tot:
+                    tot_small = preduce_scalar(jnp.sum(
+                        h_local[:, meta["dense_ref"][0]],
+                        axis=1))                         # [K, 3]
+                hist_small = agg_hist(h_local)       # [K, F/P, B, 3]
             hist_large = parent_hist - hist_small
             sl = smaller_is_left[:, None, None, None]
             hist_left = jnp.where(sl, hist_small, hist_large)
@@ -1554,35 +1429,14 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             else:
                 delta = None
             with jax.named_scope("split_search"):
-                if fused_scan:
-                    # consume the megakernel's device records: per child,
-                    # plain argmax over per-feature gains (features ascend,
-                    # so first-max == the serial lowest-feature tie-break)
-                    # and the same finalize_split the unfused fin_plain
-                    # applies — select() never sees these children
-                    def child_from_records(rec_c, min_c, max_c):
-                        pf = unpack_pf_records(rec_c)
-                        bf = jnp.argmax(pf.gain).astype(jnp.int32)
-                        res = finalize_split(
-                            pf, bf, l1=params.l1, l2=params.l2,
-                            max_delta_step=params.max_delta_step,
-                            min_constraint=min_c, max_constraint=max_c)
-                        return res._replace(
-                            is_cat=jnp.asarray(False),
-                            cat_mask=jnp.zeros(CB, jnp.float32))
-
-                    ch = jax.vmap(child_from_records)(
-                        srecs, jnp.concatenate([l_min, r_min]),
-                        jnp.concatenate([l_max, r_max]))
-                else:
-                    ch = vselect(
-                        jnp.concatenate([hist_left, hist_right], axis=0),
-                        jnp.concatenate([lg, rg]),
-                        jnp.concatenate([lh, rh]),
-                        jnp.concatenate([lc, rc]),
-                        jnp.concatenate([l_min, r_min]),
-                        jnp.concatenate([l_max, r_max]),
-                        child_masks, delta, tot_children)
+                ch = vselect(
+                    jnp.concatenate([hist_left, hist_right], axis=0),
+                    jnp.concatenate([lg, rg]),
+                    jnp.concatenate([lh, rh]),
+                    jnp.concatenate([lc, rc]),
+                    jnp.concatenate([l_min, r_min]),
+                    jnp.concatenate([l_max, r_max]),
+                    child_masks, delta, tot_children)
 
             new_state["leaf_ids"] = leaf_ids
             new_state["pool"] = pool

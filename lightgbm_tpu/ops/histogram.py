@@ -287,8 +287,17 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
                               ) -> jnp.ndarray:
     """Transposed-layout batched histogram: rows on the lane axis.
 
-    Same contraction as `build_histogram_batched_inline` but with the bin
-    matrix stored [F, n] so every operand keeps rows in the 128-lane minor
+    Histograms of K leaves in ONE contraction: the single-leaf formulation
+    ([S, n] x [n, F*B]) is an M=8 matmul that lights at most 8/128 of the
+    MXU's rows; batching K leaf slots widens the small axis to K*S lanes,
+
+        hist[(f,b), (k,s)] = sum_r onehot[r, (f,b)] * stats[s, r]
+                                    * (leaf_ids[r] == slot_leaf_ids[k])
+
+    and the tree takes ~254/K passes instead of 254 (the TPU analog of the
+    reference GPU kernel histogramming many features per workgroup,
+    reference src/treelearner/ocl/histogram256.cl:78-120).  The bin matrix
+    is stored [F, n] so every operand keeps rows in the 128-lane minor
     dimension (bins [F, blk], stats [S, blk], leaf [1, blk]) — no 28-lane
     padding waste and no layout changes between the one-hot generation and
     the MXU feed.
@@ -298,27 +307,25 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
     stats_blocks:  [S, nb, block]
     leaf_blocks:   [nb, block] int32
     slot_leaf_ids: [K] int32 (-1 = dead slot)
-    impl: "xla" (lax.scan + dot_general) or "pallas" (fused VMEM kernel)
+    impl: "xla" (lax.scan + dot_general) or "pallas2" (the perfeature
+        VMEM kernel, `_hist_pallas`)
     live_columns: STATIC count of leading columns that carry data (default:
         all F).  The rest are the learner's alignment padding, whose
-        histograms nothing reads: the perfeature kernel ("pallas2",
-        "fused") contracts only the live ones and returns exact zeros for
-        the padding; "xla" and the flat kernel contract every column, so
-        padding comes back as whatever its bins say (all rows in bin 0).
+        histograms nothing reads: "pallas2" contracts only the live ones
+        and returns exact zeros for the padding; "xla" contracts every
+        column, so padding comes back as whatever its bins say (all rows in
+        bin 0).
     Returns [K, F, B, 3] f32.
     """
-    if impl in ("pallas", "pallas2", "fused"):
-        # "fused" rides the perfeature VMEM accumulator here: the in-kernel
-        # split scan lives in ops/fused.py and only engages on the grower's
-        # frontier step — every other call site (root pass, streamed
-        # blocks, probes) builds plain histograms with the same kernel
+    if impl == "pallas2":
         return _hist_pallas(
             bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             num_bins, precision,
-            variant="flat" if impl == "pallas" else "perfeature",
             packed_rows=packed_rows, live_columns=live_columns)
+    if impl != "xla":
+        raise ValueError(f"unknown histogram impl {impl!r}")
     if packed_rows:
-        raise ValueError("packed (4-bit) bins require a pallas impl")
+        raise ValueError("packed (4-bit) bins require impl pallas2")
     nb, num_features, block = bins_t_blocks.shape
     S = stats_blocks.shape[0]
     K = slot_leaf_ids.shape[0]
@@ -426,8 +433,7 @@ _PERFEATURE_OUT_BUDGET = 6 * 1024 * 1024
 _PERFEATURE_GROUP_LANES = 1024
 _PERFEATURE_GROUP_COLUMNS = 4
 _PERFEATURE_GROUP_BUDGET = 2 * 1024 * 1024
-# the precisions `tpu_hist_impl=auto` (and the autotuner) may hand to the
-# perfeature kernel: each compiled and ran at full Higgs width on a v5e,
+# the precisions `tpu_hist_impl=auto` may hand to the perfeature kernel: each compiled and ran at full Higgs width on a v5e,
 # at 8192- and 16384-row blocks, equal to the xla contraction (PR 21).
 # f32 runs too but took 157 s to compile; Mosaic refuses int16 dots
 PERFEATURE_AUTO_PRECISIONS = ("hilo", "bf16", "int8")
@@ -523,7 +529,7 @@ def unpack2d(b2):
 
 
 def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
-                 num_bins: int, precision: str, variant: str,
+                 num_bins: int, precision: str,
                  packed_rows: bool = False,
                  live_columns: Optional[int] = None) -> jnp.ndarray:
     """Pallas kernel: fused one-hot + slot-expansion + MXU contraction.
@@ -533,46 +539,39 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     the accumulator stays resident in VMEM across the row-block grid, and
     neither the one-hot nor the expanded stats ever round-trip to HBM.
 
-    Two kernel-body variants share this scaffolding:
+    This is impl "pallas2", the auto default on a TPU at 8192-row blocks
+    (PERF.md §5 has its times on a v5e).  The one-hot is generated per
+    feature ([Bp, blk] at most, statically-unrolled dots), so the largest
+    temporary is [Bp, blk] and blocks of 2-8k rows fit.  Each feature's
+    bin rows live at a sublane-aligned Bp = ceil(B/8)*8 offset in the
+    accumulator.  When the full [F*Bp, K*S] accumulator would overflow
+    VMEM (wide data: Epsilon/Bosch F*B shapes), the grid gains a FEATURE
+    axis: features are processed in the largest divisor-of-F chunk whose
+    [fblk*Bp, K*S] out block fits, and the row-block axis iterates
+    innermost so each feature chunk's accumulator stays VMEM-resident
+    across its row sweep.
 
-    * "flat" (impl "pallas"): one [F*B, blk] one-hot dot per grid step.
-      Compiles and runs on a v5e at 256-row blocks (PR 21); the
-      monolithic one-hot costs a multi-MB VMEM retiling copy per step
-      (merging the [F, B, blk] iota-compare into dot operand layout) and
-      caps the block at 256 rows before VMEM overflows, putting ~4k grid
-      steps of accumulator read-modify-write on the critical path.
-    * "perfeature" (impl "pallas2", the auto default on a TPU at
-      8192-row blocks; PERF.md §5 has its times on a v5e): the one-hot
-      is generated per feature ([Bp, blk] at most, statically-unrolled
-      dots), so the largest temporary shrinks from
-      [F*B, blk] to [Bp, blk], blocks of 2-8k rows fit, and the grid
-      shrinks ~16x.  Each feature's bin rows live at a sublane-aligned
-      Bp = ceil(B/8)*8 offset in the accumulator.  When the full [F*Bp,
-      K*S] accumulator would overflow VMEM (wide data: Epsilon/Bosch
-      F*B shapes), the grid gains a FEATURE axis: features are processed
-      in the largest divisor-of-F chunk whose [fblk*Bp, K*S] out block
-      fits, and the row-block axis iterates innermost so each feature
-      chunk's accumulator stays VMEM-resident across its row sweep.
-      Only the first `live_columns` columns get a one-hot and a dot; the
-      accumulator rows of the rest (the learner's padding to the bins
-      dtype's sublane tile) are written as zeros once, so the output
-      keeps its [K, F, B, 3] shape.  The count is static because the
-      column loop is unrolled: it is part of the program's key.
-      The dots are per GROUP: G adjacent live columns (of those live in
-      the same feature chunks) write their one-hots to Bp-aligned rows of
-      one [G*Bp, lanes] VMEM scratch and are contracted against the slot
-      operand in one dot, whose [G*Bp, K*S] result lands on the group's
-      rows of the accumulator, contiguous as they are.  A group's dots run
-      over `perfeature_dot_lanes` rows of the block at a time (a
-      `fori_loop` over lane sub-blocks; the 4-bit stride layout takes the
-      block whole); a block's sub-blocks are summed in a second scratch
-      and added once to the accumulator, which the first row block
-      zeroes, so the f32 accumulator rounds once per block as it did when
-      a dot spanned the block.  G comes from `perfeature_columns_per_dot`, a function
-      of the shapes alone (Bp, block, dot dtype, columns of a chunk, live
-      count) that the learner's gauge calls too; where it answers 1 the
-      kernel is the ungrouped one: a [Bp, blk] one-hot and a dot per
-      column over the whole block, the first block's dot storing.
+    Only the first `live_columns` columns get a one-hot and a dot; the
+    accumulator rows of the rest (the learner's padding to the bins
+    dtype's sublane tile) are written as zeros once, so the output keeps
+    its [K, F, B, 3] shape.  The count is static because the column loop
+    is unrolled: it is part of the program's key.
+
+    The dots are per GROUP: G adjacent live columns (of those live in the
+    same feature chunks) write their one-hots to Bp-aligned rows of one
+    [G*Bp, lanes] VMEM scratch and are contracted against the slot operand
+    in one dot, whose [G*Bp, K*S] result lands on the group's rows of the
+    accumulator, contiguous as they are.  A group's dots run over
+    `perfeature_dot_lanes` rows of the block at a time (a `fori_loop` over
+    lane sub-blocks; the 4-bit stride layout takes the block whole); a
+    block's sub-blocks are summed in a second scratch and added once to
+    the accumulator, which the first row block zeroes, so the f32
+    accumulator rounds once per block as it did when a dot spanned the
+    block.  G comes from `perfeature_columns_per_dot`, a function of the
+    shapes alone (Bp, block, dot dtype, columns of a chunk, live count)
+    that the learner's gauge calls too; where it answers 1 the kernel is
+    the ungrouped one: a [Bp, blk] one-hot and a dot per column over the
+    whole block, the first block's dot storing.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -591,8 +590,7 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     S = stats_blocks.shape[0]
     K = slot_leaf_ids.shape[0]
     B = num_bins
-    # sublane-aligned per-feature row offset (perfeature variant only)
-    Bp = -(-B // 8) * 8 if variant == "perfeature" else B
+    Bp = -(-B // 8) * 8  # sublane-aligned per-feature row offset
     # int accumulator twins: narrow-int operands, exact int32 VMEM
     # accumulator — the [3, n] int8 stats plane is 2-4x leaner than
     # hilo's [5, n] bf16, so larger row blocks fit the same VMEM budget
@@ -631,21 +629,6 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         @pl.when(i > 0)
         def _():
             out_ref[rows, :] += acc
-
-    def kernel_flat(bins_ref, stats_ref, leaf_ref, slots_ref, out_ref):
-        i = pl.program_id(0)
-        # explicit upcast: bins may arrive uint8 (narrow dense storage) and
-        # Mosaic's compare wants a full-width integer operand
-        b_t = (unpack2d(bins_ref[0]) if packed_rows
-               else bins_ref[0].astype(jnp.int32))   # [F, blk]
-        sexp = expand_slots(stats_ref, leaf_ref, slots_ref)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (F, B, block), 1)
-        onehot = (b_t[:, None, :] == iota).astype(dot_dtype)
-        onehot = onehot.reshape(F * B, block)
-        acc = jax.lax.dot_general(
-            onehot, sexp, (((1,), (1,)), ((), ())),
-            precision=dot_prec, preferred_element_type=acc_dtype)
-        accumulate(i, out_ref, slice(None), acc)
 
     def kernel_perfeature_chunk(fblk, nf, G, lanes):
         # position f holds a live column in chunks 0..last(f), which only
@@ -758,80 +741,56 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     # dims exactly; the S/leaf axes ride along whole.
     stats_nb = jnp.moveaxis(stats_blocks, 1, 0)             # [nb, S, blk]
     interpret = pallas_interpret()
-    if variant == "flat":
-        raw = pl.pallas_call(
-            kernel_flat,
-            grid=(nb,),
-            in_specs=[
-                pl.BlockSpec((1, F, bins_block), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, S, block), lambda i: (i, 0, 0)),
-                pl.BlockSpec((1, 1, block), lambda i: (i, 0, 0)),
-                pl.BlockSpec((K, 1), lambda i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((F * B, K * S), lambda i: (0, 0)),
-            out_shape=jax.ShapeDtypeStruct((F * B, K * S), acc_dtype),
-            interpret=interpret,
-        )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
-          slot_leaf_ids.reshape(K, 1))
-    else:
-        ks_pad = -(-(K * S) // 128) * 128
-        fblk, nf = perfeature_chunks(F, B, K, S,
-                                     bins_t_blocks.dtype.itemsize)
-        G = perfeature_columns_per_dot(B, block, precision, fblk, live)
-        # a group's dots run over lane sub-blocks of the row block; the
-        # 4-bit stride layout spans the block, and one column at a time is
-        # the kernel as it was
-        lanes = (block if G == 1 or packed_rows
-                 else perfeature_dot_lanes(block))
-        dot_bytes = jnp.dtype(dot_dtype).itemsize
-        # scoped-VMEM ceiling, from the shapes: the compiler's default
-        # (16 MiB on a v5e) is under what the block-scaled temporaries
-        # need at 16384 rows (int8 there: "Scoped allocation with size
-        # 18.45M and limit 16.00M exceeded scoped vmem limit").  An upper
-        # bound, not a reservation: double-buffered in/out blocks (the
-        # [S, blk] stats and [1, blk] leaf ids each pad to one 32-byte
-        # sublane tile per row) plus the [Bp, lanes] iota, compare and
-        # one-hot and the [K*S, lanes] slot expansion at 32 bits and
-        # narrowed, all live at once; a group adds its stacked one-hot
-        # (the scratch and the dot's read of it) and its [G*Bp, K*S]
-        # result, lane sub-blocks their partial accumulator
-        pipelined = 2 * (fblk * Bp * ks_pad * 4
-                         + fblk * bins_block * bins_t_blocks.dtype.itemsize
-                         + (32 + 32) * block)
-        temporaries = lanes * (Bp * (4 + 4 + dot_bytes)
-                               + ks_pad * (4 + 4))
-        stacked = (G > 1) * G * Bp * (2 * lanes * dot_bytes + ks_pad * 4)
-        part = (lanes < block) * fblk * Bp * ks_pad * 4
-        vmem_limit = pipelined + temporaries + stacked + part
-        # grid order: the row-block axis is LAST (innermost), so each
-        # feature chunk's accumulator block stays resident while the row
-        # sweep accumulates into it
-        raw = pl.pallas_call(
-            kernel_perfeature_chunk(fblk, nf, G, lanes),
-            grid=(nf, nb),
-            in_specs=[
-                pl.BlockSpec((1, fblk, bins_block), lambda fi, i: (i, fi, 0)),
-                pl.BlockSpec((1, S, block), lambda fi, i: (i, 0, 0)),
-                pl.BlockSpec((1, 1, block), lambda fi, i: (i, 0, 0)),
-                pl.BlockSpec((K, 1), lambda fi, i: (0, 0)),
-            ],
-            out_specs=pl.BlockSpec((fblk * Bp, K * S),
-                                   lambda fi, i: (fi, 0)),
-            out_shape=jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
-            scratch_shapes=(
-                [pltpu.VMEM((G * Bp, lanes), dot_dtype)] * (G > 1)
-                + [pltpu.VMEM((fblk * Bp, K * S), acc_dtype)]
-                * (lanes < block)),
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=vmem_limit),
-            interpret=interpret,
-        )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
-          slot_leaf_ids.reshape(K, 1))
-    if variant == "perfeature":
-        raw = jnp.transpose(raw.reshape(F, Bp, K, S)[:, :B], (2, 3, 0, 1))
-        raw = raw.reshape(K, S, F * B)
-    else:
-        raw = jnp.transpose(raw.reshape(F * B, K, S), (1, 2, 0))
+    ks_pad = -(-(K * S) // 128) * 128
+    fblk, nf = perfeature_chunks(F, B, K, S, bins_t_blocks.dtype.itemsize)
+    G = perfeature_columns_per_dot(B, block, precision, fblk, live)
+    # a group's dots run over lane sub-blocks of the row block; the
+    # 4-bit stride layout spans the block, and one column at a time is
+    # the kernel as it was
+    lanes = block if G == 1 or packed_rows else perfeature_dot_lanes(block)
+    dot_bytes = jnp.dtype(dot_dtype).itemsize
+    # scoped-VMEM ceiling, from the shapes: the compiler's default
+    # (16 MiB on a v5e) is under what the block-scaled temporaries
+    # need at 16384 rows (int8 there: "Scoped allocation with size
+    # 18.45M and limit 16.00M exceeded scoped vmem limit").  An upper
+    # bound, not a reservation: double-buffered in/out blocks (the
+    # [S, blk] stats and [1, blk] leaf ids each pad to one 32-byte
+    # sublane tile per row) plus the [Bp, lanes] iota, compare and
+    # one-hot and the [K*S, lanes] slot expansion at 32 bits and
+    # narrowed, all live at once; a group adds its stacked one-hot
+    # (the scratch and the dot's read of it) and its [G*Bp, K*S]
+    # result, lane sub-blocks their partial accumulator
+    pipelined = 2 * (fblk * Bp * ks_pad * 4
+                     + fblk * bins_block * bins_t_blocks.dtype.itemsize
+                     + (32 + 32) * block)
+    temporaries = lanes * (Bp * (4 + 4 + dot_bytes) + ks_pad * (4 + 4))
+    stacked = (G > 1) * G * Bp * (2 * lanes * dot_bytes + ks_pad * 4)
+    part = (lanes < block) * fblk * Bp * ks_pad * 4
+    vmem_limit = pipelined + temporaries + stacked + part
+    # grid order: the row-block axis is LAST (innermost), so each
+    # feature chunk's accumulator block stays resident while the row
+    # sweep accumulates into it
+    raw = pl.pallas_call(
+        kernel_perfeature_chunk(fblk, nf, G, lanes),
+        grid=(nf, nb),
+        in_specs=[
+            pl.BlockSpec((1, fblk, bins_block), lambda fi, i: (i, fi, 0)),
+            pl.BlockSpec((1, S, block), lambda fi, i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, block), lambda fi, i: (i, 0, 0)),
+            pl.BlockSpec((K, 1), lambda fi, i: (0, 0)),
+        ],
+        out_specs=pl.BlockSpec((fblk * Bp, K * S), lambda fi, i: (fi, 0)),
+        out_shape=jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
+        scratch_shapes=(
+            [pltpu.VMEM((G * Bp, lanes), dot_dtype)] * (G > 1)
+            + [pltpu.VMEM((fblk * Bp, K * S), acc_dtype)]
+            * (lanes < block)),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+    )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
+      slot_leaf_ids.reshape(K, 1))
+    raw = jnp.transpose(raw.reshape(F, Bp, K, S)[:, :B], (2, 3, 0, 1))
+    raw = raw.reshape(K, S, F * B)
     hist = jax.vmap(lambda r: _unpack_hist(r.reshape(S, F * B), precision))(
         raw)
     return hist.reshape(K, F, B, 3)
@@ -864,87 +823,3 @@ def build_histogram_t(bins_t_blocks, stats_blocks, num_bins: int,
         body, init, (bins_t_blocks, jnp.moveaxis(stats_blocks, 1, 0)))
     hist = _unpack_hist(raw.T, precision)
     return hist.reshape(num_features, num_bins, 3)
-
-
-def build_histogram_batched_inline(bins_blocks, stats_blocks, leaf_blocks,
-                                   slot_leaf_ids, num_bins: int,
-                                   precision: str = "hilo") -> jnp.ndarray:
-    """Histograms of K leaves in ONE contraction — the perf-critical kernel.
-
-    The single-leaf formulation ([S, n] x [n, F*B]) is an M=8 matmul: at most
-    8/128 of the MXU's systolic rows ever light up (~3% MFU measured on
-    v5e).  Batching K leaves widens the small axis to K*S = 128+ lanes:
-
-        hist[(f,b), (k,s)] = sum_r onehot[r, (f,b)] * stats[s, r]
-                                    * (leaf_ids[r] == slot_leaf_ids[k])
-
-    i.e. a [F*B, block] x [block, K*S] dot_general per row block — M=F*B,
-    N=K*S, both MXU-shaped.  Total FLOPs per tree are unchanged versus K
-    single-leaf passes (each row contributes to exactly one leaf slot; the
-    rest of the dense work was always wasted), but utilization rises ~10x
-    and the tree takes ~254/K passes instead of 254.  This is the TPU analog
-    of the reference GPU kernel histogramming many features per workgroup
-    (reference src/treelearner/ocl/histogram256.cl:78-120).
-
-    bins_blocks:   [nb, block, F] int32
-    stats_blocks:  [S, nb, block] packed rows from `pack_stats`
-    leaf_blocks:   [nb, block] int32 current leaf id per row
-    slot_leaf_ids: [K] int32 leaf id wanted in each slot (-1 = dead slot)
-    Returns [K, F, B, 3] f32.
-    """
-    nb, block, num_features = bins_blocks.shape
-    S = stats_blocks.shape[0]
-    K = slot_leaf_ids.shape[0]
-    dot_dtype, _, prec = _dot_spec(precision)
-    acc_dtype = (jnp.int32 if precision in _INT_STAT_DTYPES
-                 else jnp.float32)
-    iota = jnp.arange(num_bins, dtype=jnp.int32)
-
-    def body(acc, xs):
-        b_blk, s_blk, l_blk = xs  # [block, F], [S, block], [block]
-        onehot = (b_blk[:, :, None] == iota).astype(dot_dtype)
-        onehot = onehot.reshape(block, num_features * num_bins)
-        slot_oh = (l_blk[:, None] == slot_leaf_ids[None, :]).astype(dot_dtype)
-        sexp = (slot_oh[:, :, None]
-                * jnp.swapaxes(s_blk, 0, 1).astype(dot_dtype)[:, None, :])
-        sexp = sexp.reshape(block, K * S)
-        acc = acc + jax.lax.dot_general(
-            onehot, sexp, (((0,), (0,)), ((), ())),
-            precision=prec, preferred_element_type=acc_dtype)
-        return acc, None
-
-    init = jnp.zeros((num_features * num_bins, K * S), acc_dtype)
-    raw, _ = jax.lax.scan(
-        body, init, (bins_blocks, jnp.moveaxis(stats_blocks, 1, 0),
-                     leaf_blocks))
-    # [F*B, K*S] -> [K, S, F*B] -> unpack -> [K, F, B, 3]
-    raw = jnp.transpose(raw.reshape(num_features * num_bins, K, S), (1, 2, 0))
-    hist = jax.vmap(lambda r: _unpack_hist(r, precision))(raw)
-    return hist.reshape(K, num_features, num_bins, 3)
-
-
-def build_histogram_inline(bins_blocks, stats_blocks, num_bins: int,
-                           precision: str = "hilo") -> jnp.ndarray:
-    """Non-jitted variant for use INSIDE an outer jit/scan (the tree grower).
-
-    bins_blocks: [nb, block, F], stats_blocks: [S, nb, block] (already padded).
-    """
-    nb, block, num_features = bins_blocks.shape
-    dot_dtype, _, prec = _dot_spec(precision)
-    acc_dtype = (jnp.int32 if precision in _INT_STAT_DTYPES
-                 else jnp.float32)
-    iota = jnp.arange(num_bins, dtype=jnp.int32)
-
-    def body(acc, xs):
-        b_blk, s_blk = xs
-        onehot = (b_blk[:, :, None] == iota).astype(dot_dtype)
-        onehot = onehot.reshape(block, num_features * num_bins)
-        acc = acc + jnp.dot(s_blk.astype(dot_dtype), onehot,
-                            precision=prec,
-                            preferred_element_type=acc_dtype)
-        return acc, None
-
-    init = jnp.zeros((stats_blocks.shape[0], num_features * num_bins),
-                     acc_dtype)
-    raw, _ = jax.lax.scan(body, init, (bins_blocks, jnp.moveaxis(stats_blocks, 1, 0)))
-    return _unpack_hist(raw, precision).reshape(num_features, num_bins, 3)

@@ -41,9 +41,8 @@ MISSING_NAN = 2
 def numeric_go_left(col, mt, nbf, db, thr, dleft):
     """Numerical split decision incl. missing-value routing (reference
     dense_bin.hpp Split semantics); elementwise, the single source of
-    truth for every partition lowering — the grower's select/vselect/
-    gather passes and the fused row-partition kernel (ops/fused.py)
-    all route rows through this one function."""
+    truth for every partition lowering — the grower's select and vselect
+    passes route rows through this one function."""
     is_miss = jnp.where(
         mt == MISSING_NAN, col == nbf - 1,
         jnp.where(mt == MISSING_ZERO, col == db, False))
@@ -123,42 +122,6 @@ class PerFeatureBest(NamedTuple):
     left_count: jnp.ndarray  # [F]
     right_sum_g: jnp.ndarray  # [F]
     right_sum_h: jnp.ndarray  # [F]
-
-
-# flat f32 device-record layout of a PerFeatureBest row: the fused grow
-# kernel emits these per (child, feature) and the grower reconstructs the
-# candidates without the histograms ever leaving the device.  Every field
-# round-trips f32 exactly: gains/sums are f32 already, thresholds are bin
-# indices < 2^24, default_left is 0.0/1.0.
-PF_REC_GAIN, PF_REC_THRESHOLD, PF_REC_DEFAULT_LEFT, PF_REC_LEFT_G, \
-    PF_REC_LEFT_H, PF_REC_LEFT_C, PF_REC_RIGHT_G, PF_REC_RIGHT_H = range(8)
-PF_RECORD_WIDTH = 8  # a lane-friendly width, all of it used
-
-
-def pack_pf_records(pf: PerFeatureBest) -> jnp.ndarray:
-    """[F, PF_RECORD_WIDTH] f32 device records from per-feature bests."""
-    return jnp.stack(
-        [pf.gain.astype(jnp.float32),
-         pf.threshold.astype(jnp.float32),
-         pf.default_left.astype(jnp.float32),
-         pf.left_sum_g.astype(jnp.float32),
-         pf.left_sum_h.astype(jnp.float32),
-         pf.left_count.astype(jnp.float32),
-         pf.right_sum_g.astype(jnp.float32),
-         pf.right_sum_h.astype(jnp.float32)], axis=1)
-
-
-def unpack_pf_records(rec: jnp.ndarray) -> PerFeatureBest:
-    """Inverse of `pack_pf_records` ([F, PF_RECORD_WIDTH] -> candidates)."""
-    return PerFeatureBest(
-        gain=rec[:, PF_REC_GAIN],
-        threshold=rec[:, PF_REC_THRESHOLD].astype(jnp.int32),
-        default_left=rec[:, PF_REC_DEFAULT_LEFT] > 0.5,
-        left_sum_g=rec[:, PF_REC_LEFT_G],
-        left_sum_h=rec[:, PF_REC_LEFT_H],
-        left_count=rec[:, PF_REC_LEFT_C],
-        right_sum_g=rec[:, PF_REC_RIGHT_G],
-        right_sum_h=rec[:, PF_REC_RIGHT_H])
 
 
 def per_feature_best_split(

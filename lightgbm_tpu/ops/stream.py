@@ -226,11 +226,7 @@ def _build_stream_programs(params: GrowerParams, G: int, n_pad: int,
         stats_blk = jax.lax.dynamic_slice(stats, (0, row0), (S, rows))
         stats_blocks = stats_blk.reshape(S, nbi, block)
         with jax.named_scope("hist_build"):
-            # "fused" rides the same perfeature contraction here: the
-            # streamed round body keeps its own partition/scan structure,
-            # so fused degrades to pallas2-equivalent hist + the shared
-            # select() — bit-identical by int32 associativity
-            if params.hist_impl in ("pallas", "pallas2", "fused"):
+            if params.hist_impl == "pallas2":
                 part = build_histogram_batched_t(
                     bins_blocks, stats_blocks,
                     jnp.zeros((nbi, block), jnp.int32),

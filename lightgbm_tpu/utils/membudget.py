@@ -77,7 +77,7 @@ CHUNK_FLOOR = 4096
 #: device-resident binned matrix for the streamed layout (ops/stream.py)
 #: instead of raising MemoryLadderExhausted — slower, but the run
 #: completes (and stays bitwise for int8/int16 precisions)
-LADDER_STEPS = ("shrink_chunk_rows", "hist_agg_scatter", "fused_unfuse",
+LADDER_STEPS = ("shrink_chunk_rows", "hist_agg_scatter",
                 "bucket_policy_fine", "stream_layout")
 
 _OOM_RE = re.compile(
@@ -491,21 +491,6 @@ def plan_training(config, learner, num_class: int) -> MemoryPlan:
         int(config.get("num_iterations", 100)) * k,
         int(config.get("num_leaves", 31)))
     F = int(getattr(learner, "num_features", 0)) or 1
-    if str(getattr(learner.params, "hist_impl", "xla")) == "fused":
-        # fused frontier (ops/fused.py): the device split-record buffer
-        # ([2K, F, PF_RECORD_WIDTH] f32) plus the flattened parent-hist
-        # operand the kernel streams alongside the accumulator ([F*Bp,
-        # K*S] int32) — the in-kernel scan scratch itself is VMEM, not
-        # HBM, so these two HBM-visible pieces are the whole delta
-        kf = max(int(getattr(learner.params, "split_batch", 16)), 1)
-        bf = -(-int(getattr(learner.params, "num_bins", 256)) // 8) * 8
-        g_pad = int(getattr(learner, "g_pad", F)) or F
-        comps["fused_records"] = 2 * kf * g_pad * 8 * 4
-        comps["fused_parent_hist"] = g_pad * bf * kf * 3 * 4
-    if str(config.get("tpu_autotune", "off")) != "off":
-        # autotune probe scratch (utils/autotune.tune_entry): synthetic
-        # bins + packed stats + one probe histogram, capped tune rows
-        comps["autotune_scratch"] = min(n_pad or 131072, 131072) * (F + 16)
     # chunked ingest scratch: (hi, lo) key planes + the out matrix
     ingest_chunk = int(config.get("tpu_ingest_chunk_rows", 65536))
     comps["ingest_scratch"] = ingest_chunk * F * 9
@@ -630,15 +615,6 @@ class DegradationLadder:
             # 'auto' already resolves to scatter on a real data axis —
             # only an explicit psum pin has this step to give
             return "hist_agg_scatter", {"tpu_hist_agg": "scatter"}
-        if str(config.get("tpu_hist_impl", "auto")) == "fused":
-            # the fused frontier kernel carries the device split-record
-            # buffers and a wider VMEM working set than the plain
-            # perfeature contraction; unfusing to pallas2 + the host
-            # select() is bitwise-invisible (tests/test_fused_grow.py
-            # pins fused == unfused model bytes), so it is a legitimate
-            # ladder rung.  Only an explicit fused pin descends here —
-            # "auto" re-resolves per backend and never needs unpinning
-            return "fused_unfuse", {"tpu_hist_impl": "pallas2"}
         if str(config.get("tpu_bucket_policy", "wide")) == "wide":
             return "bucket_policy_fine", {"tpu_bucket_policy": "fine"}
         # the last rung: give up device residency of the binned matrix

@@ -86,9 +86,8 @@ class TestCAPIDataset:
         np.testing.assert_allclose(got, y)
         _check(lib, lib.LGBM_DatasetFree(h))
 
-    def test_create_from_file(self, lib):
-        path = os.path.join("/root/reference/examples/binary_classification",
-                            "binary.train")
+    def test_create_from_file(self, lib, binary_example):
+        path = binary_example["train_file"]
         h = ctypes.c_void_p()
         _check(lib, lib.LGBM_DatasetCreateFromFile(
             path.encode(), b"max_bin=255", None, ctypes.byref(h)))

@@ -178,7 +178,7 @@ class TestEndToEnd:
 
 
 class TestPartitionImpls:
-    """select- and gather-lowered partitions must grow identical trees."""
+    """select- and vselect-lowered partitions must grow identical trees."""
 
     def _train_dump(self, X, y, extra, impl):
         import lightgbm_tpu as lgb
@@ -196,7 +196,7 @@ class TestPartitionImpls:
         X = rng.normal(size=(3000, 6))
         y = X[:, 0] ** 2 + np.sin(3 * X[:, 1]) + 0.1 * rng.normal(size=3000)
         a = self._train_dump(X, y, {}, "select")
-        b = self._train_dump(X, y, {}, "gather")
+        b = self._train_dump(X, y, {}, "vselect")
         assert a == b
 
     def test_categorical_and_missing_identical(self):
@@ -210,7 +210,7 @@ class TestPartitionImpls:
             0.1 * rng.normal(size=n)
         extra = {"categorical_feature": [0]}
         a = self._train_dump(X, y, extra, "select")
-        b = self._train_dump(X, y, extra, "gather")
+        b = self._train_dump(X, y, extra, "vselect")
         assert a == b
 
     def test_bundled_identical(self):
@@ -226,64 +226,13 @@ class TestPartitionImpls:
             0.1 * rng.normal(size=n)
         extra = {"enable_bundle": True}
         a = self._train_dump(X, y, extra, "select")
-        b = self._train_dump(X, y, extra, "gather")
+        b = self._train_dump(X, y, extra, "vselect")
         assert a == b
 
 
 class TestBatchedHistogramImpls:
-    """xla and pallas backends of the batched kernel must agree bit-for-bit
-    (pallas runs in interpret mode on CPU)."""
-
-    def test_grower_pallas_matches_xla_end_to_end(self):
-        """Whole-tree growth (root pass + every batched round) through the
-        pallas backend must reproduce the xla backend's model exactly."""
-        import lightgbm_tpu as lgb
-        rng = np.random.default_rng(11)
-        X = rng.normal(size=(1024, 5))
-        y = X[:, 0] - 2 * X[:, 1] + 0.1 * rng.normal(size=1024)
-
-        def dump(impl):
-            params = {"objective": "regression", "num_leaves": 15,
-                      "min_data_in_leaf": 5, "max_bin": 32,
-                      "tpu_hist_impl": impl, "tpu_block_rows": 256,
-                      "verbosity": -1}
-            ds = lgb.Dataset(X, label=y, params={"max_bin": 32})
-            bst = lgb.train(params, ds, num_boost_round=3,
-                            verbose_eval=False)
-            return bst.model_to_string().split("parameters", 1)[0]
-
-        assert dump("pallas") == dump("xla")
-
-    def test_pallas_matches_xla(self):
-        from lightgbm_tpu.ops.histogram import (build_histogram_batched_t,
-                                                pack_stats)
-        rng = np.random.default_rng(3)
-        nb, F, block, B, K = 3, 4, 256, 16, 5
-        n = nb * block
-        bins_t = jnp.asarray(
-            rng.integers(0, B, size=(nb, F, block)), dtype=jnp.int32)
-        g = jnp.asarray(rng.normal(size=n).astype(np.float32))
-        h = jnp.abs(g) + 0.1
-        stats = pack_stats(g, h, jnp.ones(n, jnp.float32), "hilo")
-        stats_blocks = stats.reshape(stats.shape[0], nb, block)
-        leaf_blocks = jnp.asarray(
-            rng.integers(0, K + 2, size=(nb, block)), dtype=jnp.int32)
-        slots = jnp.asarray([0, 2, 4, -1, 5], dtype=jnp.int32)
-        a = build_histogram_batched_t(bins_t, stats_blocks, leaf_blocks,
-                                      slots, B, "hilo", impl="xla")
-        b = build_histogram_batched_t(bins_t, stats_blocks, leaf_blocks,
-                                      slots, B, "hilo", impl="pallas")
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-        # narrow dense storage: uint8 bins (the serial learner's default
-        # when bins fit) must produce identical histograms on both backends
-        bins_u8 = bins_t.astype(jnp.uint8)
-        a8 = build_histogram_batched_t(bins_u8, stats_blocks, leaf_blocks,
-                                       slots, B, "hilo", impl="xla")
-        b8 = build_histogram_batched_t(bins_u8, stats_blocks, leaf_blocks,
-                                       slots, B, "hilo", impl="pallas")
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(a8))
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b8))
-
+    """xla and pallas2 backends of the batched kernel must agree bit-for-bit
+    (pallas2 runs in interpret mode on CPU)."""
 
     def test_pallas_bp_padding_parity(self):
         # B=15 pads Bp->16 inside the kernel: the padded bin rows must not
@@ -308,15 +257,18 @@ class TestBatchedHistogramImpls:
                                       slots, B, "hilo", impl="xla")
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def test_pallas2_matches_xla(self):
-        # per-feature one-hot variant at its bigger native blocks
+    @pytest.mark.parametrize("bins_dtype", [jnp.uint8, jnp.int32])
+    def test_pallas2_matches_xla(self, bins_dtype):
+        # per-feature one-hot variant at its bigger native blocks, on the
+        # narrow dense storage (uint8, the learner's default when bins
+        # fit) and on int32 bins
         from lightgbm_tpu.ops.histogram import (build_histogram_batched_t,
                                                 pack_stats)
         rng = np.random.default_rng(6)
         nb, F, block, B, K = 2, 4, 512, 31, 6
         n = nb * block
         bins_t = jnp.asarray(
-            rng.integers(0, B, size=(nb, F, block)), dtype=jnp.uint8)
+            rng.integers(0, B, size=(nb, F, block)), dtype=bins_dtype)
         g = jnp.asarray(rng.normal(size=n).astype(np.float32))
         stats = pack_stats(g, jnp.abs(g) + 0.2, jnp.ones(n, jnp.float32),
                            "hilo")
@@ -652,14 +604,6 @@ class TestPackedBins:
             out[pack] = bst.model_to_string().split("\nparameters:")[0]
         assert out[True] == out[False]
 
-    def test_packed_flat_kernel_matches(self):
-        bst = self._train(tpu_hist_impl="pallas", tpu_block_rows=256)
-        ref = self._train(tpu_hist_impl="pallas", tpu_block_rows=256,
-                          tpu_pack_bins=False)
-        assert bst._driver.learner.packed_bins
-        assert bst.model_to_string().split("\nparameters:")[0] == \
-            ref.model_to_string().split("\nparameters:")[0]
-
     def test_packed_data_parallel_matches_unpacked(self):
         """The pack layout's blocks must coincide with the PER-SHARD
         grower blocks — a global-block layout split across data shards
@@ -679,9 +623,6 @@ class TestPackedBins:
         # xla impl
         assert not self._train(
             tpu_hist_impl="xla")._driver.learner.packed_bins
-        # gather partition lowering
-        assert not self._train(
-            tpu_partition_impl="gather")._driver.learner.packed_bins
         # odd effective block (sub-256 alignment)
         assert not self._train(
             tpu_block_rows=128)._driver.learner.packed_bins
@@ -759,15 +700,47 @@ class TestAutoHistResolution:
         assert block == 16384
 
     def test_explicit_impl_and_block_pass_through(self):
-        impl, block = self._resolve(tpu_hist_impl="pallas",
+        impl, block = self._resolve(tpu_hist_impl="pallas2",
                                     tpu_block_rows=128)
-        assert (impl, block) == ("pallas", 128)
+        assert (impl, block) == ("pallas2", 128)
         impl, block = self._resolve(tpu_hist_impl="xla")
         assert (impl, block) == ("xla", 16384)
 
-    def test_pallas_auto_block_defaults_to_256(self):
-        impl, block = self._resolve(tpu_hist_impl="pallas")
-        assert (impl, block) == ("pallas", 256)
+    def test_auto_is_a_rule_never_a_probe(self, monkeypatch):
+        # auto resolves from (platform, precision) alone: no kernel runs
+        # to decide it, int16 stays on xla
+        def resolve(prec):
+            return self._resolve(num_leaves=255, tpu_hist_precision=prec)[0]
+
+        for prec in ("hilo", "int8", "int16"):
+            assert resolve(prec) == "xla"            # cpu
+
+        class _Tpu:
+            platform = "tpu"
+
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Tpu()])
+        assert resolve("hilo") == "pallas2"
+        assert resolve("int8") == "pallas2"
+        assert resolve("int16") == "xla"
+        assert resolve("f32") == "xla"
+
+    @pytest.mark.parametrize("key,value,instead", [
+        ("tpu_hist_impl", "fused", "pallas2"),
+        ("tpu_hist_impl", "pallas", "pallas2"),
+        ("tpu_partition_impl", "kernel", "select"),
+        ("tpu_partition_impl", "gather", "select")])
+    def test_removed_impl_is_refused_by_name(self, key, value, instead):
+        # the values whose implementations were deleted: the error names
+        # the value and what serves in its place
+        import lightgbm_tpu as lgb
+        rng = np.random.default_rng(0)
+        X = rng.normal(size=(256, 4))
+        p = {"objective": "regression", "verbosity": -1, key: value}
+        with pytest.raises(
+                ValueError,
+                match=f"{key}={value} was removed; use {key}={instead}"):
+            lgb.train(p, lgb.Dataset(X, label=X[:, 0], params=p),
+                      num_boost_round=1)
 
     def test_auto_vmem_branch_on_faked_tpu(self, monkeypatch):
         # exercise the auto branch's VMEM arithmetic by faking the platform
@@ -798,7 +771,7 @@ class TestAutoHistResolution:
         impl, block = self._resolve(num_leaves=255,
                                     tpu_hist_precision="f32")
         assert impl == "xla"
-        # explicit non-lane-aligned block disables the pallas auto pick
+        # explicit non-lane-aligned block disables the pallas2 auto pick
         impl, block = self._resolve(num_leaves=255, tpu_block_rows=192)
         assert (impl, block) == ("xla", 192)
 

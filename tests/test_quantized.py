@@ -2,7 +2,7 @@
 
 Covers the ISSUE-4 acceptance matrix: float modes are bitwise no-ops
 under the new quant params, integer histograms match an np.int64 oracle
-EXACTLY on every backend (xla + both pallas variants), stochastic
+EXACTLY on both backends (xla and the pallas2 kernel), stochastic
 rounding is unbiased in expectation and deterministic given the seed,
 full trainings stay within 2e-3 of f32 quality on binary / multiclass /
 regression, data-parallel int8 split decisions are bit-identical across
@@ -107,7 +107,7 @@ class TestHistogramInt64Oracle:
         assert hist.dtype == np.int32
         np.testing.assert_array_equal(hist.astype(np.int64), oracle)
 
-    @pytest.mark.parametrize("impl", ["xla", "pallas", "pallas2"])
+    @pytest.mark.parametrize("impl", ["xla", "pallas2"])
     def test_batched_slots_exact(self, impl):
         n, F, B, K = 1024, 5, 16, 4
         bins, g, h, mask, _ = self._case("int8", n=n, F=F, B=B)

@@ -8,33 +8,36 @@ import pytest
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import TrainingData
 
-PATH = "/root/reference/examples/binary_classification/binary.train"
+
+@pytest.fixture(scope="module")
+def train_file(binary_example):
+    return binary_example["train_file"]
 
 
 class TestTwoRound:
-    def test_identical_when_sample_covers(self):
-        one = TrainingData.from_file(PATH, Config({}))
+    def test_identical_when_sample_covers(self, train_file):
+        one = TrainingData.from_file(train_file, Config({}))
         two = TrainingData._from_file_two_round(
-            PATH, Config({"two_round": True}), None)
+            train_file, Config({"two_round": True}), None)
         np.testing.assert_array_equal(one.bins, two.bins)
         np.testing.assert_array_equal(one.metadata.label, two.metadata.label)
         assert [m.to_dict() for m in one.mappers] == \
             [m.to_dict() for m in two.mappers]
 
-    def test_multichunk_identical(self):
+    def test_multichunk_identical(self, train_file):
         """Chunked streaming must not depend on the chunk size."""
         a = TrainingData._from_file_two_round(
-            PATH, Config({"two_round": True}), None, chunk_rows=613)
+            train_file, Config({"two_round": True}), None, chunk_rows=613)
         b = TrainingData._from_file_two_round(
-            PATH, Config({"two_round": True}), None)
+            train_file, Config({"two_round": True}), None)
         np.testing.assert_array_equal(a.bins, b.bins)
 
-    def test_reservoir_subsample_trains(self):
+    def test_reservoir_subsample_trains(self, train_file):
         """Sampled bin finding (sample < n) still yields a usable dataset
         and close bin boundaries."""
-        full = TrainingData.from_file(PATH, Config({}))
+        full = TrainingData.from_file(train_file, Config({}))
         sub = TrainingData._from_file_two_round(
-            PATH, Config({"two_round": True,
+            train_file, Config({"two_round": True,
                           "bin_construct_sample_cnt": 800}), None,
             chunk_rows=977)
         assert sub.bins.shape == full.bins.shape
@@ -44,9 +47,9 @@ class TestTwoRound:
         col2 = sub.bins[:, 0].astype(np.int64)
         assert np.corrcoef(col, col2)[0, 1] > 0.98
 
-    def test_dataset_api_two_round(self, tmp_path):
+    def test_dataset_api_two_round(self, train_file):
         import lightgbm_tpu as lgb
-        ds = lgb.Dataset(PATH, params={"two_round": True})
+        ds = lgb.Dataset(train_file, params={"two_round": True})
         bst = lgb.train({"objective": "binary", "num_leaves": 15},
                         ds, num_boost_round=5, verbose_eval=False)
         assert bst.num_trees() == 5

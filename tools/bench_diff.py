@@ -74,10 +74,8 @@ METRICS = {
     # (bitwise models, bounded programs) live in tests/test_stream.py
     "stream_rows_per_sec": (+1, 0.35),
     "stream_overlap_pct": (+1, 0.50),
-    # per-iteration grow wall and the steady-state autotune profile
-    # load+resolve cost (ISSUE 18)
+    # per-iteration grow wall (ISSUE 18)
     "grow_iter_ms": (-1, 0.30),
-    "autotune_resolve_ms": (-1, 0.50),
     # fleet serving (ISSUE 19): replicated-dispatch goodput across the
     # device set, cold-replica time-to-first-batch (AOT deserialization
     # path — wide slack, it embeds process/session startup wall), and

@@ -2,7 +2,7 @@
 
 Run on the real chip when tuning the grower:
     python tools/perf_probe.py                  # default sweep
-    K=25 BLOCK=16384 IMPL=pallas N=1000000 python tools/perf_probe.py one
+    K=25 BLOCK=8192 IMPL=pallas2 N=1000000 python tools/perf_probe.py one
 
 Reports ms/tree and train AUC for each configuration at the bench shape
 (Higgs-1M: 28 features, 255 leaves, 255 bins), so quality regressions
@@ -189,7 +189,7 @@ def run_predict_sweep(X, y, rounds=50, leaves=255, bins=255):
 
 def run_hist_sweep(X, y, bins=255, reps=4):
     """Histogram-kernel rows/s sweep: precision (hilo/f32/int16/int8) x
-    impl (xla/pallas/pallas2) x block size, on the grower's own batched
+    impl (xla/pallas2) x block size, on the grower's own batched
     contraction (build_histogram_batched_t, K=25 slots), plus the
     auto-selection table `tpu_hist_impl=auto` would pick per precision.
 
@@ -235,10 +235,9 @@ def run_hist_sweep(X, y, bins=255, reps=4):
             jax.block_until_ready(fn(bins_tb, stats, leaf_b))
         return n_use * reps / max(time.time() - t0, 1e-9), n_use
 
-    blocks = {"xla": (8192, 16384), "pallas": (256,),
-              "pallas2": (4096, 8192)}
+    blocks = {"xla": (8192, 16384), "pallas2": (4096, 8192)}
     for precision in ("hilo", "f32", "int16", "int8"):
-        for impl in ("xla", "pallas", "pallas2"):
+        for impl in ("xla", "pallas2"):
             for block in blocks[impl]:
                 label = f"prec={precision:<5s} impl={impl:<7s} block={block}"
                 try:
@@ -248,81 +247,6 @@ def run_hist_sweep(X, y, bins=255, reps=4):
                 except Exception as exc:
                     print(f"{label}: FAILED {_exc_inline(exc)}", flush=True)
 
-    # ---- frontier step (hist + split scan): the fused megakernel next
-    # to the exact unfused composition it replaces (perfeature hist +
-    # the vmapped 2K-child per-feature scan).  tpu_hist_impl=fused is
-    # explicit-only; a backend whose compiler refuses it prints FAILED
-    # with the compiler's message ----
-    def one_frontier(precision, impl, block):
-        from lightgbm_tpu.ops import fused as FU
-        from lightgbm_tpu.ops import split as SP
-
-        n_cap = n_all if (on_tpu or impl == "xla") \
-            else min(n_all, max(4096, block))
-        if n_cap < block:
-            raise ValueError(f"need >= {block} rows, have {n_cap}")
-        bins_tb, stats, n_use = bench_hist_operands(
-            bins_np[:n_cap], precision, block)
-        nb = n_use // block
-        leaf_b = jnp.asarray(
-            rng.integers(0, K, size=n_use).astype(np.int32)
-            .reshape(nb, block))
-        slots = jnp.arange(K, dtype=jnp.int32)
-        C = 2 * K
-        ctx_np = np.zeros((C + 1, 8), np.float32)
-        ctx_np[:C, 0] = 100.0
-        ctx_np[:C, 1] = 200.0
-        ctx_np[:C, 2] = float(n_use) / C
-        ctx_np[:C, 3] = -1e30
-        ctx_np[:C, 4] = 1e30
-        ctx_np[:C, 5] = (np.arange(C) % 2).astype(np.float32)
-        ctx_np[C, :3] = (0.5, 0.25, 1.0)
-        ctx = jnp.asarray(ctx_np)
-        meta_i = jnp.zeros((F, 8), jnp.int32).at[:, 0].set(B)
-        meta_f = jnp.ones((F, 8), jnp.float32)
-        parent = jnp.ones((K, F, B, 3), jnp.int32) * (n_use // K)
-        kw = dict(l1=0.0, l2=1.0, max_delta_step=0.0, min_data_in_leaf=1.0,
-                  min_sum_hessian=1e-3, min_gain_to_split=0.0)
-        if impl == "fused":
-            fn = jax.jit(lambda b, s, l: FU.fused_hist_scan(
-                b, s, l, slots, parent, ctx, meta_i, meta_f, B, precision,
-                split_kw=kw))
-        else:
-            def unfused(b, s, l):
-                hist = build_histogram_batched_t(b, s, l, slots, B,
-                                                 precision, impl=impl)
-
-                def child(j):
-                    k = j % K
-                    small = hist[k]
-                    hs = jnp.where(ctx[j, 5] > 0, small, parent[k] - small)
-                    return SP.per_feature_best_split(
-                        hs, ctx[j, 0], ctx[j, 1], ctx[j, 2],
-                        meta_i[:, 0], meta_i[:, 1], meta_i[:, 2],
-                        meta_i[:, 3], meta_f[:, 0], meta_f[:, 1],
-                        min_constraint=ctx[j, 3], max_constraint=ctx[j, 4],
-                        acc_scale=ctx[C, :3], **kw)
-                return hist, jax.vmap(child)(jnp.arange(C))
-            fn = jax.jit(unfused)
-        jax.block_until_ready(fn(bins_tb, stats, leaf_b))  # compile
-        t0 = time.time()
-        for _ in range(reps):
-            jax.block_until_ready(fn(bins_tb, stats, leaf_b))
-        return n_use * reps / max(time.time() - t0, 1e-9), n_use
-
-    print("\nfrontier step (hist + 2K-child split scan), fused vs "
-          "unfused:", flush=True)
-    for precision in ("int8", "int16"):
-        for impl, block in (("xla", 16384), ("pallas2", 8192),
-                            ("fused", 8192)):
-            label = f"prec={precision:<5s} impl={impl:<7s} block={block}"
-            try:
-                rps, n_use = one_frontier(precision, impl, block)
-                print(f"{label}: {rps:14.0f} rows/s ({n_use} rows)",
-                      flush=True)
-            except Exception as exc:
-                print(f"{label}: FAILED {_exc_inline(exc)}", flush=True)
-
     print("\nauto-selection (tpu_hist_impl=auto on this backend):",
           flush=True)
     for precision in ("hilo", "f32", "int16", "int8"):
@@ -330,45 +254,6 @@ def run_hist_sweep(X, y, bins=255, reps=4):
                       "max_bin": bins, "tpu_hist_precision": precision})
         impl, block = TPUTreeLearner._resolve_hist_impl(cfg, B, precision)
         print(f"  {precision:<5s} -> impl={impl} block={block}", flush=True)
-
-
-def run_tune(bins=255):
-    """Autotune round-trip: measure + persist the profile for the bench
-    shape bucket, then print what tpu_hist_impl=auto resolves to FROM
-    the profile — the durable form of the hist sweep's verdict.
-
-        N=131072 PROFILE=/tmp/at.json python tools/perf_probe.py tune
-    """
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.models.learner import TPUTreeLearner
-    from lightgbm_tpu.utils import autotune as AT
-
-    n = int(os.environ.get("N", 131072))
-    f = int(os.environ.get("F", 28))
-    B = bins + 1
-    cfg = None
-    for precision in ("int8", "int16", "hilo"):
-        params = {"objective": "binary", "num_leaves": 255,
-                  "max_bin": bins, "tpu_hist_precision": precision,
-                  "tpu_autotune": "tune"}
-        if os.environ.get("PROFILE"):
-            params["tpu_autotune_profile"] = os.environ["PROFILE"]
-        cfg = Config(params)
-        try:
-            entry = AT.resolve_autotune(cfg, n, f, B, precision)
-        except Exception as exc:
-            print(f"{precision:<5s}: FAILED {_exc_inline(exc)}", flush=True)
-            continue
-        print(f"{precision:<5s} bucket={AT.shape_bucket(n, f, B)} -> "
-              f"{entry['hist_impl']}:{entry['block_rows']} "
-              f"({entry['rows_per_sec']:.0f} rows/s)", flush=True)
-        for ck, rps in sorted(entry.get("table", {}).items()):
-            print(f"    {ck:<14s} {rps:14.0f} rows/s", flush=True)
-        impl, block = TPUTreeLearner._resolve_hist_impl(
-            cfg, B, precision, tuned=entry)
-        print(f"    resolved auto -> impl={impl} block={block}", flush=True)
-    if cfg is not None:
-        print(f"profile: {AT.profile_path(cfg)}", flush=True)
 
 
 def run_ingest_sweep(X, y, bins=255):
@@ -1240,9 +1125,6 @@ def main():
                        os.environ.get("HOSTS", "1").split(",")]
         run_comm_sweep(shard_counts, host_counts=host_counts)
         return
-    if arg == "tune":
-        run_tune(bins=int(os.environ.get("BINS", 255)))
-        return
     n = int(os.environ.get("N", 1_000_000))
     X, y = make_data(n)
     if arg == "hist":
@@ -1266,74 +1148,9 @@ def main():
                           alpha=float(os.environ.get("ALPHA", 0.0)))],
               iters=8, reraise=True)
         return
-    if arg == "round2":
-        # post-pallas leverage sweep (docs/PERF_NOTES.md "next
-        # experiments"): S=3 bf16 stats widen K at the same tile width;
-        # bigger K cuts rounds per tree
-        sweep(X, y, [
-            dict(k=25, block=256, impl="pallas", prec="hilo"),  # re-baseline
-            # pallas2: per-feature one-hot, 16x fewer grid steps
-            dict(k=25, block=4096, impl="pallas2", prec="hilo"),
-            dict(k=25, block=8192, impl="pallas2", prec="hilo"),
-            # S=3 bf16 stats widen K at the same tile width
-            dict(k=42, block=4096, impl="pallas2", prec="bf16"),
-            dict(k=84, block=4096, impl="pallas2", prec="bf16"),  # ~6 rounds
-            dict(k=84, block=4096, impl="pallas2", prec="bf16", ramp=True),
-            dict(k=25, block=4096, impl="pallas2", prec="hilo", ramp=True),
-            dict(k=42, block=256, impl="pallas", prec="bf16"),
-            dict(k=50, block=256, impl="pallas", prec="hilo"),  # 2 tiles
-        ])
-        return
-    if arg == "round3":
-        # post-default-flip sweep: can the near-tie guard (alpha) buy the
-        # K=50 round count without K=50's split-order AUC loss?  Guard
-        # rounds split only leaves with gain >= alpha * round-max, so
-        # high alpha approaches strict best-first at more rounds/tree
-        sweep(X, y, [
-            dict(k=25, block=8192, impl="pallas2", prec="hilo",
-                 ramp=True),  # current default, re-baseline
-            dict(k=50, block=8192, impl="pallas2", prec="hilo", ramp=True,
-                 alpha=0.2),
-            dict(k=50, block=8192, impl="pallas2", prec="hilo", ramp=True,
-                 alpha=0.5),
-            dict(k=84, block=8192, impl="pallas2", prec="hilo", ramp=True,
-                 alpha=0.5),
-        ])
-        return
-    if arg == "round4":
-        # partition-lowering A/B at the committed defaults: "vselect"
-        # replaces the K unrolled select passes with ONE [K, n] fused
-        # block (fewer program points; candidate for the ~170 ms/tree
-        # non-contraction time, PERF_NOTES round-4).  Bit-parity with
-        # "select" is CPU-proven (tests/test_grower.py TestVselectPartition)
-        sweep(X, y, [
-            dict(k=25, block=8192, impl="pallas2", prec="hilo",
-                 ramp=True, part="select"),   # default, re-baseline
-            dict(k=25, block=8192, impl="pallas2", prec="hilo",
-                 ramp=True, part="vselect"),
-            dict(k=50, block=8192, impl="pallas2", prec="hilo",
-                 ramp=True, part="vselect", alpha=0.5),
-        ])
-        return
-    if arg == "decide":
-        # the post-outage decision sweep: partition A/B at default K, then
-        # K scaling, then the pallas backend at a VMEM-sized block
-        sweep(X, y, [
-            dict(part="gather", k=15, block=16384, impl="xla"),
-            dict(part="select", k=15, block=16384, impl="xla"),
-            dict(part="select", k=25, block=16384, impl="xla"),
-            dict(part="select", k=50, block=16384, impl="xla"),
-            dict(part="select", k=25, block=65536, impl="xla"),
-            # pallas: [F*B, block] bf16 one-hot + [F*B, K*S] f32
-            # accumulator must fit ~16MB VMEM -> block <= 512 at K=25
-            dict(part="select", k=25, block=256, impl="pallas"),
-            dict(part="select", k=25, block=512, impl="pallas"),
-            dict(part="select", k=12, block=512, impl="pallas"),
-        ])
-        return
     sweep(X, y, [dict(impl=i, k=k, block=b)
-                 for i in ("xla", "pallas") for k in (16, 25)
-                 for b in (16384, 65536)], iters=5)
+                 for i, b in (("xla", 16384), ("pallas2", 8192))
+                 for k in (16, 25)], iters=5)
 
 
 if __name__ == "__main__":
