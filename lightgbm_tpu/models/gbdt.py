@@ -24,6 +24,7 @@ from ..config import Config
 from ..io.bin_mapper import BinMapper, MissingType
 from ..io.dataset import TrainingData
 from ..utils import faultline, membudget
+from ..ops.lookup import lookup
 from ..ops.predict import (PackedForest, feature_meta_dev, device_tables,
                            forest_class_scores, forest_leaf_values,
                            pack_trees, row_bucket)
@@ -1185,10 +1186,10 @@ class GBDT:
                 g = sync_sums(np.concatenate([outs, has]))
                 tree.leaf_value[:L] = g[:L] / np.maximum(g[L:], 1.0)
         tree.apply_shrinkage(self.shrinkage_rate)
-        # train scores: leaf-partition gather (ScoreUpdater::AddScore train path)
-        leaf_vals = jnp.asarray(tree.leaf_value[:tree.num_leaves]
-                                .astype(np.float32))
-        self.train_scores.add(class_id, leaf_vals[leaf_ids])
+        # train scores: leaf-partition lookup (ScoreUpdater::AddScore train
+        # path); the whole leaf vector, so every tree has one table shape
+        leaf_vals = jnp.asarray(tree.leaf_value.astype(np.float32))
+        self.train_scores.add(class_id, lookup(leaf_vals, leaf_ids))
         # valid scores: binned traversal (device kernel, host fallback)
         pc: Dict = {}
         for vs, vd in zip(self.valid_scores, self.valid_sets):

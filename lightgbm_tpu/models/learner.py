@@ -24,6 +24,7 @@ from ..ops.grower import (GrowerParams, canonical_params, mode_flags_np,
                           pad_rows, pool_dtype, resolve_split_batch)
 from ..ops.histogram import (hashed_uniform, key_words, perfeature_chunks,
                              perfeature_columns_per_dot)
+from ..ops.lookup import lookup
 from ..parallel.mesh import put_global, put_local
 from ..parallel.strategies import (bins_sharding, make_strategy_grower,
                                    pool_partition_spec,
@@ -1242,19 +1243,23 @@ class TPUTreeLearner:
         def _post(scores, records, leaf_ids, leaf_output, class_id):
             with jax.named_scope("score_update"):
                 any_split = records[0, 14] > 0.5  # REC_DID_SPLIT
-                # scale the [L] leaf vector FIRST, then gather: the
-                # per-row path is gather + ONE correctly-rounded add.
+                # scale the [L] leaf vector FIRST, then look up: the
+                # per-row path is lookup + ONE correctly-rounded add.
                 # The per-row `leaf_output[ids] * lr + scores` form left
                 # a mul+add chain that XLA/LLVM may (or may not)
                 # contract into an FMA depending on the surrounding
                 # program — serial and shard_map programs contracted
                 # differently, drifting scores one ulp apart at the
                 # SAME trees and breaking the cross-topology bitwise
-                # contract (ROADMAP item 7's second root cause)
+                # contract (ROADMAP item 7's second root cause).  The
+                # one-hot lookup (a TPU's) keeps that to the bit; under
+                # the gather XLA:CPU fuses this multiply back into the
+                # per-row loop and contracts it with the add, barrier or
+                # not, alike in every topology (tests/test_score_lookup.py)
                 scaled = jnp.where(any_split,
                                    leaf_output * learning_rate, 0.0)
                 new_scores = scores.at[class_id, :].add(
-                    scaled[leaf_ids[:n]])
+                    lookup(scaled, leaf_ids[:n]))
             return new_scores, leaf_ids[:n]
 
         external_pool = self._external_pool
