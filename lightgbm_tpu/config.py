@@ -525,11 +525,12 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     #             record (all_gather + shared deterministic tie-break) —
     #             the reference's Network::ReduceScatter +
     #             SyncUpGlobalBestSplit (data_parallel_tree_learner.cpp:
-    #             149-163).  ~2× less ICI receive volume, ~P× less
-    #             per-shard histogram-pool HBM, and the search runs once
-    #             instead of P times; int8/int16 decisions stay
-    #             bit-identical to psum at every shard count.  In voting
-    #             mode the voted [k, B, 3] aggregation scatters instead.
+    #             149-163).  A shard holds 1/P of the histogram pool
+    #             and the search runs once instead of P times (timed on
+    #             four chips in PERF.md section 5; psum was not);
+    #             int8/int16 decisions stay bit-identical to psum at
+    #             every shard count.  In voting mode the voted [k, B, 3]
+    #             aggregation scatters instead.
     #   auto    - scatter whenever the data axis spans >1 device
     "tpu_hist_agg": ("str", "auto", ()),
     # f64 histogram accumulation everywhere (requires x64): serial and

@@ -156,7 +156,9 @@ class TrainingData:
         self.used_feature_idx: List[int] = []     # used col -> original col
         self.mappers: List[BinMapper] = []        # one per ORIGINAL column
         self._bins: Optional[np.ndarray] = None   # [n, num_used] uint8/uint16
-        self._ingest_bins = None   # device-resident [n, num_used] (ops/binning)
+        # device-resident [n, num_used]: one array, or ops/binning.RowParts
+        # where ingest dealt the rows to a row-sharded learner's chips
+        self._ingest_bins = None
         self.metadata: Optional[Metadata] = None
         self.feature_names: List[str] = []
         self.config: Optional[Config] = None
@@ -189,10 +191,18 @@ class TrainingData:
         return self._bins is not None or self._ingest_bins is not None
 
     def device_ingest_bins(self):
-        """The device-resident narrow-dtype bin matrix, or None when the
-        host copy is authoritative (host ingest, or a consumer already
-        materialized + possibly mutated through the property)."""
+        """The device-resident narrow-dtype bin matrix (an array, or a
+        RowParts over several chips), or None when the host copy is
+        authoritative (host ingest, or a consumer already materialized +
+        possibly mutated through the property)."""
         return self._ingest_bins if self._bins is None else None
+
+    def ingest_matrix(self):
+        """The device-ingested matrix as ONE device array; row parts are
+        gathered onto their first chip (a whole-table consumer's cost)."""
+        from ..ops.binning import RowParts
+
+        return RowParts.of(self._ingest_bins).gathered()
 
     @property
     def num_features(self) -> int:
@@ -226,7 +236,7 @@ class TrainingData:
         import jax.numpy as jnp
         if self._device_bins is None:
             if self._ingest_bins is not None:
-                self._device_bins = self._ingest_bins.astype(jnp.int32)
+                self._device_bins = self.ingest_matrix().astype(jnp.int32)
             else:
                 self._device_bins = jnp.asarray(self.bins.astype(np.int32))
         return self._device_bins
@@ -240,8 +250,9 @@ class TrainingData:
         way, so the result is bit-identical to `(bins == 0).mean(0)`."""
         dev = self.device_ingest_bins()
         if dev is not None:
-            import jax.numpy as jnp
-            cnt = np.asarray(jnp.sum(dev == 0, axis=0, dtype=jnp.int32))
+            from ..ops.binning import RowParts
+
+            cnt = RowParts.of(dev).column_counts(lambda p: p == 0)
             return cnt.astype(np.float64) / max(self.num_data, 1)
         return (self.bins == 0).mean(axis=0)
 
@@ -253,10 +264,10 @@ class TrainingData:
         zb = np.asarray(zero_bins)
         dev = self.device_ingest_bins()
         if dev is not None:
-            import jax.numpy as jnp
-            return np.asarray(jnp.sum(
-                dev != jnp.asarray(zb.astype(np.int32))[None, :],
-                axis=0, dtype=jnp.int32)).astype(np.int64)
+            from ..ops.binning import RowParts
+
+            zb32 = zb.astype(np.int32)[None, :]
+            return RowParts.of(dev).column_counts(lambda p: p != zb32)
         bins = self.bins
         n = bins.shape[0]
         step = max((1 << 28) // max(bins.shape[1], 1), 1024)
@@ -274,10 +285,11 @@ class TrainingData:
             from .bundling import _stride_sample
 
             return _stride_sample(self.bins, quota)
+        from ..ops.binning import RowParts
+
         n = self.num_data
         if n > quota:
-            step = n // quota
-            return np.asarray(dev[::step][:quota])
+            return RowParts.of(dev).take(np.arange(0, n, n // quota)[:quota])
         return np.asarray(dev)
 
     # ------------------------------------------------------------------
@@ -331,7 +343,8 @@ class TrainingData:
                 dtype = np.uint8 if self.max_num_bin <= 256 else np.uint16
                 binner = self._make_device_binner(config, dtype, n)
                 if binner is not None:
-                    self._ingest_bins = binner.bin_matrix(X)
+                    self._ingest_bins = binner.bin_matrix(
+                        X, self._row_shard_devices(config))
                     self._bins = None
                 else:
                     bins = np.empty((n, self.num_features), dtype=dtype)
@@ -350,6 +363,25 @@ class TrainingData:
             self.metadata = Metadata(n, label, weight, group_sizes, init_score)
             self._set_constraints(config)
             return self
+
+    @staticmethod
+    def _row_shard_devices(config: Config):
+        """The chips a device ingest deals the rows to, or None for one
+        matrix on the default device: under tree_learner=data|voting with
+        num_machines chips in one process the learner shards rows over
+        exactly these (parallel/topology.make_topology takes jax.devices()
+        in order), so each shard is binned where it will train.  A learner
+        built otherwise still lays out correctly, at a chip-to-chip copy."""
+        import jax
+
+        from ..parallel.strategies import resolve_tree_learner
+
+        shards = int(config.num_machines)
+        if (resolve_tree_learner(config.tree_learner) in ("data", "voting")
+                and 1 < shards <= len(jax.devices())
+                and jax.process_count() == 1 and not str(config.machines)):
+            return jax.devices()[:shards]
+        return None
 
     def _make_device_binner(self, config: Config, dtype, n_rows: int):
         """A ready DeviceBinner when config routes ingest to the device
@@ -571,7 +603,8 @@ class TrainingData:
                 # bin_stream re-chunks across reader blocks, so only the
                 # file's final launch pads
                 self._ingest_bins = binner.bin_stream(
-                    Xc for Xc, _ in reader.chunks())
+                    (Xc for Xc, _ in reader.chunks()),
+                    self._row_shard_devices(config), n)
                 self._bins = None
             else:
                 bins = np.empty((n, self.num_features), dtype=dtype)

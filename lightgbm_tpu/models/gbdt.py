@@ -372,21 +372,21 @@ class GBDT:
             if (self.objective is not None and not self.objective.needs_renew
                     and not self.objective.host_only
                     # CEGB threads cross-tree used/paid state through
-                    # learner.train (the sync path); the fused step's meta is
-                    # closure-captured and cannot carry it
+                    # learner.train (the sync path); the async step hands
+                    # the grower the learner's meta as it stood at layout
                     and not self.learner.params.has_cegb
                     # multi-host meshes need learner.train's global array
                     # placement (put_global); the fused step mixes local
                     # score state into the global-mesh program
                     and not self.learner._multiproc
                     # the streamed layout has no device-resident bins_t for
-                    # the fused step to close over: its train() drives the
+                    # the async step to pass: its train() drives the
                     # per-block host loop (ops/stream.py) — sync path only
                     and not self.learner.stream_layout
                     and all(self.objective.class_need_train(k)
                             for k in range(self.num_tree_per_iteration))):
                 self._train_step = self.learner.make_train_step(
-                    self.objective.get_gradients, self.shrinkage_rate,
+                    self.objective, self.shrinkage_rate,
                     self._bag_cfg, self._goss_cfg)
 
     def _bagging_config(self) -> Optional[Dict]:
@@ -1585,7 +1585,7 @@ class GBDT:
         if data._device_bins is not None:  # already resident: reuse
             return data._device_bins
         if data._ingest_bins is not None:  # device ingest: widen in place
-            return data._ingest_bins.astype(jnp.int32)
+            return data.ingest_matrix().astype(jnp.int32)
         return jnp.asarray(data.bins.astype(np.int32))
 
     def _tree_delta_device(self, data: TrainingData, tree: Tree,

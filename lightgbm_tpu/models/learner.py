@@ -15,6 +15,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
 from ..config import Config
@@ -25,12 +26,14 @@ from ..ops.grower import (GrowerParams, canonical_params, mode_flags_np,
 from ..ops.histogram import (hashed_uniform, key_words, perfeature_chunks,
                              perfeature_columns_per_dot)
 from ..ops.lookup import lookup
-from ..parallel.mesh import put_global, put_local
+from ..parallel.mesh import (exchange_bytes_per_tree, put_global, put_local,
+                             tree_hist_slots)
 from ..parallel.strategies import (bins_sharding, make_strategy_grower,
                                    pool_partition_spec,
                                    resolve_tree_learner, rows_sharding)
+from ..parallel.topology import ROW_AXES
 from ..utils import timer
-from ..utils.compile_ledger import ledger_jit
+from ..utils.compile_ledger import closed_over_bytes, ledger_jit
 from ..utils.log import Log
 from .tree import Tree
 
@@ -493,6 +496,19 @@ class TPUTreeLearner:
                         a = math.lcm(a, 32)
                     self.g_pad = -(-self.g_pad // a) * a
 
+            from ..parallel import topology as _topo
+
+            if strategy == "serial":
+                self.topology = None
+            else:
+                self.topology = _topo.make_topology(
+                    num_data_shards=self.d_shards,
+                    num_feature_shards=self.f_shards,
+                    num_hosts=self.hosts,
+                    partitioned_rows=self._partitioned)
+            self.mesh = self.topology.mesh if self.topology else None
+            _topo.activate(self.topology)
+
             # transposed [G, n] bin matrix: rows ride the 128-lane minor axis
             # for the histogram contraction (see ops/histogram.py).  Stored
             # uint8 when bins fit (the reference's narrow dense bins,
@@ -614,18 +630,17 @@ class TPUTreeLearner:
                 live = self.num_columns
                 # partitioned: only this process's rows, at its local width
                 width = self._local_width if self._partitioned else self.n_pad
-                if (dev_src is not None and strategy == "serial"
-                        and not self.stream_layout):
+                if (dev_src is not None and not self.stream_layout
+                        and (strategy == "serial"
+                             or jax.process_count() == 1)):
                     # device-side layout: transpose + pad the device-
-                    # resident ingest matrix in HBM — the host [n, F]
-                    # matrix never exists on this path
-                    bins_t = jnp.zeros(
-                        (self.g_pad, width),
-                        dtype=jnp.uint8 if B <= 256 else jnp.int32)
-                    bins_t = bins_t.at[:self.num_columns, :n].set(
-                        dev_src.T.astype(bins_t.dtype))
+                    # resident ingest matrix in HBM, on its shards under
+                    # a parallel strategy — the host [n, F] matrix never
+                    # exists on this path
+                    bins_t = self._layout_device_bins(
+                        dev_src, n, jnp.uint8 if B <= 256 else jnp.int32)
                 else:
-                    if cols_src is None:  # parallel placement ships host
+                    if cols_src is None:  # a mesh over processes ships host
                         cols_src = train_data.bins
                     bins_t = np.zeros((self.g_pad, width), dtype=bin_dtype)
                     bins_t[:self.num_columns, :n] = cols_src.T
@@ -722,21 +737,9 @@ class TPUTreeLearner:
                                     dtype=v.dtype)])
                 meta_host[k] = v
 
-            from ..parallel import topology as _topo
-
             if strategy == "serial":
-                self.topology = None
-                self.mesh = None
-                _topo.activate(None)
                 self._place_serial_bins(bins_t, n)
             else:
-                self.topology = _topo.make_topology(
-                    num_data_shards=self.d_shards,
-                    num_feature_shards=self.f_shards,
-                    num_hosts=self.hosts,
-                    partitioned_rows=self._partitioned)
-                _topo.activate(self.topology)
-                self.mesh = self.topology.mesh
                 if self._partitioned:
                     # each process contributes only ITS rows to the global
                     # arrays (reference pre_partition: rows never leave
@@ -751,8 +754,14 @@ class TPUTreeLearner:
                         ones, rows_sharding(self.mesh, strategy),
                         (self.n_pad,))
                 else:
-                    self.bins_t = put_global(
-                        bins_t, bins_sharding(self.mesh, strategy))
+                    sharding = bins_sharding(self.mesh, strategy)
+                    if isinstance(bins_t, np.ndarray):
+                        with self._place_shards_span(bins_t, "host"):
+                            self.bins_t = put_global(bins_t, sharding)
+                    else:
+                        # on its shards already (_layout_device_bins): a
+                        # no-op unless the 4-bit packing reshaped it
+                        self.bins_t = jax.device_put(bins_t, sharding)
                     ones = np.ones(self.n_pad, np.float32)
                     ones[n:] = 0.0
                     self._ones_host = ones
@@ -776,9 +785,10 @@ class TPUTreeLearner:
                              and bool(config.tpu_quant_refit_leaves)),
                 cegb_tradeoff=float(config.cegb_tradeoff),
                 cegb_penalty_split=float(config.cegb_penalty_split))
-            if self._multiproc:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
+            if self.mesh is not None:
+                # replicated over the mesh once, here: an array left on
+                # one device would be copied to the others at every call
+                # of the sharded programs
                 self._rep_sharding = NamedSharding(self.mesh, P())
                 self._rows_shard = rows_sharding(self.mesh, strategy)
                 self.meta = {k: put_global(v, self._rep_sharding)
@@ -792,13 +802,8 @@ class TPUTreeLearner:
                 # placement so no replicated->sharded reshard crosses the
                 # program boundary (the CPU gloo backend aborts on those)
                 sp_rows, sp_bins, perm = self._sparse_arrays
-                if self._multiproc:
-                    from jax.sharding import NamedSharding
-                    from jax.sharding import PartitionSpec as P_
-
-                    from ..parallel.topology import ROW_AXES
-
-                    shard3 = NamedSharding(self.mesh, P_(ROW_AXES))
+                if self.mesh is not None:
+                    shard3 = NamedSharding(self.mesh, P(ROW_AXES))
                     if self._partitioned:
                         # this process built only ITS shards' tables
                         gshape = (self.d_shards,) + sp_rows.shape[1:]
@@ -831,8 +836,8 @@ class TPUTreeLearner:
                 for d, l in enumerate(by_shard):
                     sf[d, :len(l)] = l
                 self.meta["scatter_feat"] = (
-                    put_global(sf, self._rep_sharding) if self._multiproc
-                    else jnp.asarray(sf))
+                    put_global(sf, self._rep_sharding)
+                    if self.mesh is not None else jnp.asarray(sf))
 
         self.params = GrowerParams(
             num_leaves=max(int(config.num_leaves), 2),
@@ -893,7 +898,8 @@ class TPUTreeLearner:
         if has_cegb:
             zeros_f = np.zeros(self.f_pad, np.float32)
             self._cegb_used = (put_global(zeros_f, self._rep_sharding)
-                               if self._multiproc else jnp.asarray(zeros_f))
+                               if self.mesh is not None
+                               else jnp.asarray(zeros_f))
             self.meta["cegb_used"] = self._cegb_used
             if has_cegb_lazy:
                 # bool storage: the reference's bitset is n*F/8 bytes;
@@ -916,8 +922,6 @@ class TPUTreeLearner:
             pdt = jnp.dtype(pool_dtype(precision))
             sharding = None
             if self.mesh is not None:
-                from jax.sharding import NamedSharding
-
                 sharding = NamedSharding(self.mesh, pool_partition_spec(
                     strategy, self.hist_agg == "scatter"))
             self._pool_spec = (shape, pdt, sharding)
@@ -933,6 +937,7 @@ class TPUTreeLearner:
             external_pool=self._external_pool,
             live_columns=self.live_columns)
         self._feature_rng = np.random.default_rng(int(config.feature_fraction_seed))
+        self._note_exchange()
 
     def reset_pool(self) -> None:
         """(Re)create the donated histogram-pool buffer as zeros.
@@ -963,6 +968,112 @@ class TPUTreeLearner:
         host-resident as fixed-size row blocks (ops/stream.py)."""
         self.bins_t = jnp.asarray(bins_t)
         self._ones_mask = jnp.ones(self.n_pad, jnp.float32).at[n:].set(0.0)
+
+    def _place_shards_span(self, bins_t, source: str):
+        """The span around the bin matrix's way onto its shards."""
+        return obs.span("place_shards", source=source,
+                        bytes=int(bins_t.size) * bins_t.dtype.itemsize,
+                        devices=int(self.mesh.size))
+
+    def _layout_device_bins(self, dev_src, n: int, dtype):
+        """The transposed, padded [g_pad, n_pad] bin matrix from the
+        device-resident [n, F] ingest matrix, built in HBM.  Under a
+        parallel strategy it is built SHARD BY SHARD: each chip is handed
+        the rows and columns of its own block (already there where ingest
+        dealt the rows to the chips, ops/binning.RowParts; chip to chip
+        otherwise) and pads and transposes that block alone, so no chip
+        holds more of the table than it trains on."""
+        from ..ops.binning import RowParts
+
+        cols, shape = self.num_columns, (self.g_pad, self.n_pad)
+        src = RowParts.of(dev_src)
+        if self.mesh is None:
+            return jnp.zeros(shape, dtype).at[:cols, :n].set(
+                src.gathered().T.astype(dtype))
+        sharding = bins_sharding(self.mesh, self.strategy)
+
+        def lay(pieces, block):
+            # a chip's block of the matrix from the pieces that hold its
+            # rows and columns of the table
+            x = jnp.concatenate(pieces, axis=0)
+            return jnp.zeros(block, dtype).at[
+                :x.shape[1], :x.shape[0]].set(x.T.astype(dtype))
+
+        lay = ledger_jit(lay, site="learner.layout", static_argnums=1)
+
+        with self._place_shards_span(jax.ShapeDtypeStruct(shape, dtype),
+                                     "device"):
+            shards = []
+            for dev, (cs, rs) in sharding.addressable_devices_indices_map(
+                    shape).items():
+                (c0, c1, _), (r0, r1, _) = (cs.indices(shape[0]),
+                                            rs.indices(shape[1]))
+                pieces = (src.rows(r0, min(r1, n), dev,
+                                   slice(c0, min(c1, cols)))
+                          if c0 < cols else [])
+                block = (c1 - c0, r1 - r0)
+                shards.append(lay(pieces, block) if pieces else
+                              jnp.zeros(block, dtype, device=dev))
+            return jax.make_array_from_single_device_arrays(
+                shape, sharding, shards)
+
+    def place_rows(self, v) -> jnp.ndarray:
+        """A per-row array ([..., n], or already [..., n_pad]) as the
+        step's programs take it: the row axis padded with zeros to n_pad
+        and sharded like the grower's row vectors."""
+        v = jnp.asarray(v)
+        pad = self.n_pad - v.shape[-1]
+        if pad:
+            v = jnp.pad(v, [(0, 0)] * (v.ndim - 1) + [(0, pad)])
+        if self.mesh is None:
+            return v
+        return jax.device_put(v, self._rows_sharding(v.ndim))
+
+    def _rows_sharding(self, ndim: int = 1) -> NamedSharding:
+        """Sharding of an array whose LAST axis is the padded row axis."""
+        return NamedSharding(self.mesh, P(
+            *(None,) * (ndim - 1),
+            *rows_sharding(self.mesh, self.strategy).spec))
+
+    def _note_exchange(self) -> None:
+        """Gauges of the data axis, from the shapes the programs run."""
+        rps = self.n_pad // self.d_shards
+        obs.REGISTRY.set_gauge(
+            "lgbm_data_shards", self.d_shards,
+            help="row shards of the binned table (1: no data axis)")
+        for k in range(self.d_shards):
+            obs.REGISTRY.set_gauge(
+                "lgbm_shard_rows", rps, shard=str(k),
+                help="rows of the padded row axis a shard's programs sweep")
+            if not self._partitioned:
+                # rows [0, n) are the table's and the padding is the tail,
+                # so the last shards hold it all
+                obs.REGISTRY.set_gauge(
+                    "lgbm_shard_table_rows",
+                    min(max(self.n - k * rps, 0), rps), shard=str(k),
+                    help="of a shard's rows, those of the table")
+        for mode in ("psum", "scatter"):
+            obs.REGISTRY.set_gauge(
+                "lgbm_hist_agg", int(self.d_shards > 1
+                                     and self.hist_agg == mode), mode=mode,
+                help="how histograms cross the data axis (1: the mode in "
+                     "force; both 0 without a data axis)")
+        p = self.params
+        per_op = exchange_bytes_per_tree(
+            tree_hist_slots(p.num_leaves, p.split_batch, p.ramp,
+                            p.ramp_step),
+            self.g_pad // self.f_shards, p.num_bins,
+            jnp.dtype(pool_dtype(p.precision)).itemsize,
+            self.hist_agg == "scatter", p.num_bins if p.has_cat else 1)
+        for op, nbytes in per_op.items():
+            obs.REGISTRY.set_gauge(
+                "lgbm_exchange_bytes_per_tree",
+                nbytes if self.d_shards > 1 and self.strategy != "voting"
+                else 0, op=op,
+                help="bytes a row shard hands to the data axis's "
+                     "collectives per tree (parallel/mesh.py "
+                     "exchange_bytes_per_tree; voting exchanges voted "
+                     "columns only and is not modelled)")
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1121,11 +1232,14 @@ class TPUTreeLearner:
         return jnp.zeros(self.n_pad, v.dtype).at[:v.shape[0]].set(v)
 
     # ------------------------------------------------------------------
-    def make_train_step(self, grad_fn, learning_rate: float,
+    def make_train_step(self, objective, learning_rate: float,
                         bagging: Optional[Dict] = None,
                         goss: Optional[Dict] = None):
-        """Fuse gradients + tree growth + train-score update into ONE device
-        program per iteration.
+        """The device step of one iteration for EVERY tree_learner: three
+        programs, gradients (`learner.pre`) -> the strategy's grower ->
+        score update (`learner.post`), dispatched back to back; on a data
+        axis a fourth between the last two, the all-gather of the leaf ids
+        (`learner.gather`).
 
         A host<->device round trip per tree would leave the device idle,
         so the driver dispatches asynchronously and never syncs on the hot
@@ -1133,32 +1247,41 @@ class TPUTreeLearner:
         feature-fraction masks are sampled on device, and the only per-tree
         artifact is the packed [L-1, 15] record array (fetched lazily).
 
-        grad_fn: (scores [k, n]) -> (grad [k, n], hess [k, n]) pure device fn.
-        Returns step(scores, key, class_id_static) ->
-            (records, new_scores, leaf_ids, leaf_output, new_key).
+        Nothing with a row axis and nothing that differs by data set is
+        closed over: the bin matrix, the row mask, `meta` and the
+        objective's per-row arrays (`objective.row_arrays()`: labels,
+        weights) are ARGUMENTS, padded to n_pad and on the strategy's row
+        sharding, so a program's cache key holds shapes and not a table
+        (`lgbm_step_row_constant_bytes` says so, program by program), and
+        the grower stays the one bucketed program every Booster of a shape
+        shares.  pre's outputs leave on the sharding the grower's inputs
+        have, so nothing is resharded between programs.
+
+        objective: supplies `gradients(scores [k, n_pad], rows) ->
+        (grad, hess)`, a pure device function of its per-row arrays.
+        Returns step(grad_scores, scores, key, bag_key, pool, class_id,
+        refresh_bag, goss_on) -> (records, new_scores, leaf_ids [n_pad],
+        leaf_output, new_key, new_bag_key, pool).
         """
         n, n_pad = self.n, self.n_pad
         frac = 1.0 if bagging is None else bagging.get("fraction", 1.0)
         pos_frac = 1.0 if bagging is None else bagging.get("pos_fraction", 1.0)
         neg_frac = 1.0 if bagging is None else bagging.get("neg_fraction", 1.0)
-        is_pos = None
+        rows = {k: self.place_rows(v)
+                for k, v in objective.row_arrays().items()}
         if bagging is not None and (pos_frac < 1.0 or neg_frac < 1.0):
-            is_pos = jnp.asarray(bagging["is_pos"])
+            rows["is_pos"] = self.place_rows(bagging["is_pos"])
         feature_frac = float(self.config.feature_fraction)
-        ones_mask = self._ones_mask
         F = self.num_features
         f_pad = self.f_pad
-        grow = self.grow
-        meta = self.meta
-        bins_t = self.bins_t
 
         goss_top_k = goss_other_k = 0
         if goss is not None:
             goss_top_k = max(1, int(n * float(goss["top_rate"])))
             goss_other_k = max(1, int(n * float(goss["other_rate"])))
 
-        def _pre(grad_scores, key, bag_key, class_id, refresh_bag,
-                 goss_on):
+        def _pre(grad_scores, rows, valid, key, bag_key, class_id,
+                 refresh_bag, goss_on):
             # grad_scores = scores at ITERATION start: all classes' gradients
             # come from the same snapshot, like the reference's single
             # Boosting() call per iteration (gbdt.cpp:150-158); `scores`
@@ -1169,12 +1292,23 @@ class TPUTreeLearner:
             # multiplying the program count)
             # named_scope: the host-span vocabulary (boost / bagging /
             # score_update) mirrored into xprof device traces
+            rows = dict(rows)
+            is_pos = rows.pop("is_pos", None)
+            live = valid > 0
             with jax.named_scope("boost"):
-                grad, hess = grad_fn(grad_scores)
+                # the scores take the row vectors' padding (and, on a
+                # mesh, their sharding: every shard computes its own
+                # rows' gradients); a padding row's gradient is whatever
+                # zeros give and is zeroed below
+                padded = jnp.pad(grad_scores, ((0, 0), (0, n_pad - n)))
+                if self.mesh is not None:
+                    padded = jax.lax.with_sharding_constraint(
+                        padded, self._rows_sharding(2))
+                grad, hess = objective.gradients(padded, rows)
             g = grad[class_id] if grad.ndim == 2 else grad
             h = hess[class_id] if hess.ndim == 2 else hess
-            g = jnp.zeros(n_pad, jnp.float32).at[:n].set(g[:n])
-            h = jnp.zeros(n_pad, jnp.float32).at[:n].set(h[:n])
+            g = jnp.where(live, g, 0.0).astype(jnp.float32)
+            h = jnp.where(live, h, 0.0).astype(jnp.float32)
 
             key, kf = jax.random.split(key)
             bag_key = jnp.where(jnp.asarray(refresh_bag),
@@ -1190,7 +1324,7 @@ class TPUTreeLearner:
                 # which made bagging masks topology-dependent and broke
                 # the cross-shard bitwise contract (ROADMAP item 7).
                 # Precondition: iota == global row index, which holds
-                # because this fused step only exists single-process
+                # because this step only exists single-process
                 # (_maybe_make_train_step gates on not _multiproc) and
                 # the single-process layout is compact-at-front (rows
                 # [0, n) contiguous, padding only at the tail) — the
@@ -1200,7 +1334,7 @@ class TPUTreeLearner:
                 return hashed_uniform(
                     jax.lax.iota(jnp.uint32, n_pad), sa, sb, salt)
 
-            mask = ones_mask
+            mask = valid
             if goss_on:
                 # GOSS on device (reference goss.hpp:91-139 BaggingHelper):
                 # keep the top_rate rows by sum_k |g*h|, Bernoulli-sample
@@ -1212,7 +1346,7 @@ class TPUTreeLearner:
                     gh_all = jnp.sum(jnp.abs(grad * hess), axis=0)
                 else:
                     gh_all = jnp.abs(grad * hess)
-                gh = jnp.full(n_pad, -1.0, jnp.float32).at[:n].set(gh_all[:n])
+                gh = jnp.where(live, gh_all, -1.0).astype(jnp.float32)
                 thr = jnp.sort(gh)[n_pad - goss_top_k]
                 keep_top = gh >= thr
                 bag_key = jax.random.split(bag_key)[0]
@@ -1258,74 +1392,108 @@ class TPUTreeLearner:
                 # not, alike in every topology (tests/test_score_lookup.py)
                 scaled = jnp.where(any_split,
                                    leaf_output * learning_rate, 0.0)
-                new_scores = scores.at[class_id, :].add(
+                return scores.at[class_id, :].add(
                     lookup(scaled, leaf_ids[:n]))
-            return new_scores, leaf_ids[:n]
 
-        external_pool = self._external_pool
-        donate = self._donate
+        # the grower's own ledgered jit donates the pool, post the scores
+        # buffer.  The scores are [k, n] with no row padding, so on a mesh
+        # they are replicated (n need not divide by the shards) and post
+        # takes the leaf ids gathered (`gather_j` below).  pre and post
+        # are closures of this Booster (the objective's scalars, the
+        # bagging rates), traced once per Booster; what they compile to
+        # depends on shapes alone, so the persistent cache answers a new
+        # data set of the same shape.  Only goss_on stays static (its
+        # sort is structural work).
+        shard_kw_pre, shard_kw_post = {}, {}
+        if self.mesh is not None:
+            row, rep = self._rows_sharding(), self._rep_sharding
+            shard_kw_pre["out_shardings"] = (row, row, row, rep, rep, rep,
+                                             rep)
+            shard_kw_post["out_shardings"] = rep
+        pre_j = ledger_jit(_pre, site="learner.pre",
+                           static_argnames=("goss_on",), **shard_kw_pre)
+        post_j = ledger_jit(_post, site="learner.post",
+                            donate_argnums=((0,) if self._donate else ()),
+                            **shard_kw_post)
+        # row-sharded leaf ids reach post through an all-gather of their
+        # own program: post then runs replicated, on replicated operands,
+        # so every device runs the serial learner's post and rounds as it
+        # does (a post partitioned over sharded ids fuses its multiply
+        # and add otherwise: scores an ulp apart at the same trees).  A
+        # `device_put` to the replicated sharding would do the same
+        # through the host, and wait for the grower first
+        gather_j = None
+        if self.d_shards > 1:
+            gather_j = ledger_jit(lambda ids: ids, site="learner.gather",
+                                  out_shardings=self._rep_sharding)
 
-        def make_step(pre_fn, post_fn):
-            # ONE step body shared by both modes: pre -> grow -> post.
+        def step(grad_scores, scores, key, bag_key, pool, class_id,
+                 refresh_bag, goss_on=False):
             # `pool` is the donated histogram-pool buffer (None when
             # donation is off): grow rewrites it in place and the caller
-            # threads the returned buffer into the next call.
-            def step(grad_scores, scores, key, bag_key, pool, class_id,
-                     refresh_bag, goss_on=False):
-                g, h, mask, fmask, k_node, key, bag_key = pre_fn(
-                    grad_scores, key, bag_key, class_id=class_id,
-                    refresh_bag=refresh_bag, goss_on=goss_on)
-                if external_pool:
-                    out = grow(bins_t, g, h, mask, fmask, meta, k_node,
-                               pool)
-                    pool = out["pool"]
-                else:
-                    out = grow(bins_t, g, h, mask, fmask, meta, k_node)
-                new_scores, lids = post_fn(scores, out["records"],
-                                           out["leaf_ids"],
-                                           out["leaf_output"],
-                                           class_id=class_id)
-                return (out["records"], new_scores, lids,
-                        out["leaf_output"], key, bag_key, pool)
-            return step
+            # threads the returned buffer into the next call
+            if self.mesh is not None:
+                # the caller's first scores and keys sit on one device; the
+                # programs hand them back replicated, after which these
+                # puts move nothing, and every iteration runs the programs
+                # the first one compiled
+                grad_scores, scores, key, bag_key = (
+                    jax.device_put(x, self._rep_sharding)
+                    for x in (grad_scores, scores, key, bag_key))
+            g, h, mask, fmask, k_node, key, bag_key = pre_j(
+                grad_scores, rows, self._ones_mask, key, bag_key,
+                class_id=class_id, refresh_bag=refresh_bag,
+                goss_on=goss_on)
+            if self._external_pool:
+                out = self.grow(self.bins_t, g, h, mask, fmask, self.meta,
+                                k_node, pool)
+                pool = out["pool"]
+            else:
+                out = self.grow(self.bins_t, g, h, mask, fmask, self.meta,
+                                k_node)
+            ids = out["leaf_ids"]
+            if gather_j is not None:
+                ids = gather_j(ids)
+            new_scores = post_j(scores, out["records"], ids,
+                                out["leaf_output"], class_id=class_id)
+            return (out["records"], new_scores, out["leaf_ids"],
+                    out["leaf_output"], key, bag_key, pool)
 
-        if int(self.config.tpu_shape_buckets) > 0 \
-                and self.strategy == "serial":
-            # shape-bucketed pipeline (serial strategy only): keep the
-            # n-shaped grad/score glue in SMALL separate programs
-            # (seconds to compile) so the big bucketed grower program is
-            # the only expensive compile — a new dataset in the same
-            # bucket reuses it from the persistent cache.  All three
-            # dispatches stay async; no host sync is introduced.
-            # Parallel strategies keep the fused program: their sharded
-            # outputs (leaf_ids on the 'data' axis) would reshard across
-            # the program boundary, which the CPU-collectives test
-            # backend aborts on — and multi-chip wants the fusion anyway.
-            # Only goss_on stays static (its sort is structural work);
-            # the grower's own ledgered jit donates the pool here and
-            # post donates the scores buffer.  pre/post are per-objective
-            # closures (label arrays captured as constants), so each
-            # Booster traces and compiles its own pair, and a new
-            # dataset misses the persistent cache: ~20 s for pre at
-            # 27M rows on a v5e (PERF.md, PR 22 finding 3).
-            pre_j = ledger_jit(_pre, site="learner.pre",
-                               static_argnames=("goss_on",))
-            post_j = ledger_jit(_post, site="learner.post",
-                                donate_argnums=((0,) if donate else ()))
-            return make_step(pre_j, post_j)
-        # exact-shape mode (tpu_shape_buckets=0): ONE fused program —
-        # the round-3 hardware-validated hot path, bit-identical.
-        # Donation at the fused boundary: scores (arg 1) and the pool
-        # (arg 4) are rewritten in place by XLA.  The fused jit is the
-        # ledger site here (the grower's own jit is traced inline).
-        dn = []
-        if donate:
-            dn.append(1)
-        if external_pool:
-            dn.append(4)
-        return ledger_jit(make_step(_pre, _post), site="learner.step",
-                          static_argnames=("goss_on",),
-                          donate_argnums=tuple(dn))
+        self._note_row_constants(pre_j, gather_j, post_j, rows,
+                                 objective.num_model_per_iteration(),
+                                 goss is not None)
+        return step
+
+    def _note_row_constants(self, pre_j, gather_j, post_j, rows,
+                            classes: int, goss_on: bool) -> None:
+        """`lgbm_step_row_constant_bytes{site=}`: for each program of the
+        step, the bytes of the arrays it closes over that have a row axis
+        (n, n_pad or a shard's rows long).  Read off the programs' traces
+        at the shapes the step runs, the traces its first call uses."""
+        spec = jax.ShapeDtypeStruct
+        f32 = jnp.float32
+        scores, key = spec((classes, self.n), f32), spec((2,), jnp.uint32)
+        vec = spec((self.n_pad,), f32)
+        pool = () if self._pool is None else (self._pool,)
+        grow_args = (self.bins_t, vec, vec, vec, spec((self.f_pad,), f32),
+                     self.meta, key) + pool
+        out = self.grow.trace(*grow_args).out_info
+        calls = [
+            (pre_j, (scores, rows, self._ones_mask, key, key),
+             dict(class_id=0, refresh_bag=False, goss_on=goss_on)),
+            (self.grow, grow_args, {}),
+            (post_j, (scores, out["records"], out["leaf_ids"],
+                      out["leaf_output"]), dict(class_id=0))]
+        if gather_j is not None:
+            calls.append((gather_j, (out["leaf_ids"],), {}))
+        lengths = {self.n, self.n_pad, self.n_pad // self.d_shards}
+        for fn, args, kwargs in calls:
+            obs.REGISTRY.set_gauge(
+                "lgbm_step_row_constant_bytes",
+                closed_over_bytes(fn, args, kwargs, lengths), site=fn.site,
+                help="bytes of closed-over arrays with a row axis in a "
+                     "program of the training step (0: the table, the "
+                     "masks and the labels are arguments)")
 
     def train(self, grad: jnp.ndarray, hess: jnp.ndarray,
               row_mask: Optional[jnp.ndarray] = None

@@ -13,6 +13,7 @@ sorts, cheap relative to histogram work).
 
 from __future__ import annotations
 
+import copy
 import functools
 from typing import Dict, List, Optional, Tuple
 
@@ -55,6 +56,26 @@ class Objective:
         """score: [k, n] raw scores -> (grad, hess) [k, n]."""
         raise NotImplementedError
 
+    #: the attributes `get_gradients` reads that hold one entry per row
+    #: (device arrays, the row axis last).  The training step takes them
+    #: as ARGUMENTS, padded and sharded like every other row vector, so
+    #: no program of the step holds a data set's labels as constants
+    row_attrs: Tuple[str, ...] = ("label", "weights")
+
+    def row_arrays(self) -> Dict[str, jnp.ndarray]:
+        """name -> per-row device array, for the `row_attrs` that are set."""
+        return {a: getattr(self, a) for a in self.row_attrs
+                if getattr(self, a, None) is not None}
+
+    def gradients(self, score: jnp.ndarray, rows: Dict[str, jnp.ndarray]
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """`get_gradients` as a function of its per-row arrays: `rows`
+        (the keys of `row_arrays`, any row count that matches `score`)
+        stand in for the attributes of the same names."""
+        bound = copy.copy(self)
+        vars(bound).update(rows)
+        return bound.get_gradients(score)
+
     #: whether renew_tree_output does anything (lets the driver skip
     #: device->host transfers of scores/leaf ids on the hot path)
     needs_renew = False
@@ -88,6 +109,7 @@ class BinaryLogloss(Objective):
     to build its per-class losses (reference multiclass_objective.hpp:186).
     """
     name = "binary"
+    row_attrs = ("_sign", "_lw", "weights")
 
     def __init__(self, config: Config, is_pos_fn=None):
         super().__init__(config)
@@ -157,6 +179,7 @@ class BinaryLogloss(Objective):
 class RegressionL2(Objective):
     """reference src/objective/regression_objective.hpp:78-158."""
     name = "regression"
+    row_attrs = ("trans_label", "weights")
 
     def __init__(self, config: Config):
         super().__init__(config)

@@ -107,6 +107,7 @@ class _RenewMixin:
 class RegressionL1(_RenewMixin, RegressionL2):
     """reference regression_objective.hpp:189-270."""
     name = "regression_l1"
+    row_attrs = ("label", "weights")
 
     def is_constant_hessian(self) -> bool:
         return self.metadata.weight is None
@@ -131,6 +132,7 @@ class RegressionL1(_RenewMixin, RegressionL2):
 class Huber(RegressionL2):
     """reference regression_objective.hpp:275-333."""
     name = "huber"
+    row_attrs = ("label", "weights")
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -155,6 +157,7 @@ class Huber(RegressionL2):
 class Fair(RegressionL2):
     """reference regression_objective.hpp:337-378."""
     name = "fair"
+    row_attrs = ("label", "weights")
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -180,6 +183,7 @@ class Poisson(RegressionL2):
     """reference regression_objective.hpp:384-462.  Internal score f is the
     log-rate: grad = exp(f) - y, hess = exp(f + poisson_max_delta_step)."""
     name = "poisson"
+    row_attrs = ("label", "weights")
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -218,6 +222,7 @@ class Poisson(RegressionL2):
 class Quantile(_RenewMixin, RegressionL2):
     """reference regression_objective.hpp:464-556."""
     name = "quantile"
+    row_attrs = ("label", "weights")
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -251,6 +256,7 @@ class MAPE(_RenewMixin, RegressionL2):
     """reference regression_objective.hpp:562-654.  Uses label weights
     1/max(1,|y|) for both gradients and the percentile refits."""
     name = "mape"
+    row_attrs = ("label", "_label_weight_dev", "weights")
 
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
@@ -335,6 +341,7 @@ class Tweedie(Poisson):
 class MulticlassSoftmax(Objective):
     """reference src/objective/multiclass_objective.hpp:24-175."""
     name = "multiclass"
+    row_attrs = ("_onehot", "weights")
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -422,6 +429,22 @@ class MulticlassOVA(Objective):
         gs, hs = [], []
         for k, bl in enumerate(self.binary_losses):
             g, h = bl.get_gradients(score[k:k + 1])
+            gs.append(g)
+            hs.append(h)
+        return jnp.stack(gs), jnp.stack(hs)
+
+    def row_arrays(self):
+        # one group per class's binary loss, keyed "<class>.<attribute>"
+        return {f"{k}.{a}": v for k, bl in enumerate(self.binary_losses)
+                for a, v in bl.row_arrays().items()}
+
+    def gradients(self, score, rows):
+        gs, hs = [], []
+        for k, bl in enumerate(self.binary_losses):
+            prefix = f"{k}."
+            mine = {a[len(prefix):]: v for a, v in rows.items()
+                    if a.startswith(prefix)}
+            g, h = bl.gradients(score[k:k + 1], mine)
             gs.append(g)
             hs.append(h)
         return jnp.stack(gs), jnp.stack(hs)

@@ -28,7 +28,9 @@ i+1's key planes (cheap vectorized bit twiddling) while the device bins
 chunk i — transfer and compute overlap through jax's async dispatch —
 and the full `[n, F]` matrix is assembled device-side, never
 materialized on the host unless a host consumer asks (see
-`TrainingData.bins`).
+`TrainingData.bins`).  For a learner that shards rows over several chips
+of one process the chunks go to the chips in consecutive row ranges and
+stay there (`RowParts`): no chip ever holds the whole table.
 """
 
 from __future__ import annotations
@@ -99,6 +101,66 @@ def _bin_chunk_kernel(vhi, vlo, cv, t: Dict[str, jnp.ndarray],
     return out.astype(out_dtype)
 
 
+class RowParts:
+    """An `[n, F]` bin matrix kept as consecutive row ranges, each a device
+    array on the chip that binned it.  What a row-sharded learner lays its
+    shards out from (`TPUTreeLearner._layout_device_bins`), chip by chip;
+    a plain device matrix is the one-part case (`RowParts.of`)."""
+
+    def __init__(self, parts: Sequence[jnp.ndarray]):
+        self.parts = list(parts)
+        self.starts = np.concatenate(
+            [[0], np.cumsum([p.shape[0] for p in self.parts])]).astype(int)
+        self.shape = (int(self.starts[-1]), self.parts[0].shape[1])
+        self.dtype = self.parts[0].dtype
+
+    @classmethod
+    def of(cls, matrix) -> "RowParts":
+        return matrix if isinstance(matrix, cls) else cls([matrix])
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.concatenate([np.asarray(p) for p in self.parts])
+        return out if dtype is None else out.astype(dtype, copy=False)
+
+    def rows(self, lo: int, hi: int, device,
+             cols: slice = slice(None)) -> List[jnp.ndarray]:
+        """Rows [lo, hi) of the columns `cols` as pieces in row order, each
+        on `device`: a part that lies on it already is not copied, the
+        others' rows are cut where they lie and go chip to chip."""
+        whole = cols == slice(None) or cols.indices(self.shape[1]) == (
+            0, self.shape[1], 1)
+        out = []
+        for p, s in zip(self.parts, self.starts):
+            a, b = max(lo - s, 0), min(hi - s, p.shape[0])
+            if a < b:
+                out.append(jax.device_put(
+                    p if whole and (a, b) == (0, p.shape[0])
+                    else p[a:b, cols], device))
+        return out
+
+    def gathered(self) -> jnp.ndarray:
+        """The whole matrix as one array, on the first part's device."""
+        if len(self.parts) == 1:
+            return self.parts[0]
+        device = next(iter(self.parts[0].devices()))
+        return jnp.concatenate(self.rows(0, self.shape[0], device), axis=0)
+
+    def column_counts(self, hit) -> np.ndarray:
+        """Per column, the rows for which `hit(part)` holds: each part is
+        reduced where it lies and only the [F] counts leave the device."""
+        return sum(np.asarray(jnp.sum(hit(p), axis=0, dtype=jnp.int32))
+                   .astype(np.int64) for p in self.parts)
+
+    def take(self, idx: np.ndarray) -> np.ndarray:
+        """Host copy of the rows at the ascending indices `idx`."""
+        idx = np.asarray(idx)
+        cut = np.searchsorted(idx, self.starts)
+        return np.concatenate([
+            np.asarray(p[idx[a:b] - s]) for p, s, a, b in
+            zip(self.parts, self.starts, cut[:-1], cut[1:]) if a < b]
+            or [np.zeros((0, self.shape[1]), self.dtype)])
+
+
 class DeviceBinner:
     """Streams raw row chunks through the device bin kernel.
 
@@ -116,7 +178,8 @@ class DeviceBinner:
         self.chunk_rows = max(int(chunk_rows), 256)
         self._cat_widths = tables["cat_width"].copy() if has_cat else None
         self._is_cat = tables["is_cat"].copy() if has_cat else None
-        self._dev_tables = {k: jnp.asarray(v) for k, v in tables.items()}
+        self._tables = tables
+        self._dev_tables = {}  # device (None: the default one) -> tables
         self._launches = 0  # kernel launches so far: the spans' chunk tag
 
     # ------------------------------------------------------------------
@@ -221,8 +284,9 @@ class DeviceBinner:
             cv = np.where(isnan, np.int32(_NAN_CAT), t)
         return vhi, vlo, cv
 
-    def bin_chunk(self, block: np.ndarray) -> jnp.ndarray:
-        """Bin one [rows, F] raw block (guarded ingest-upload site).
+    def bin_chunk(self, block: np.ndarray, device=None) -> jnp.ndarray:
+        """Bin one [rows, F] raw block (guarded ingest-upload site) on
+        `device`, the default one where None.
 
         A classified device OOM halves `chunk_rows` and re-bins the
         block in smaller launches — bins are bit-identical at ANY chunk
@@ -239,7 +303,7 @@ class DeviceBinner:
             try:
                 with membudget.oom_guard("ingest_chunk",
                                          rows=int(sub.shape[0])):
-                    parts.append(self._bin_chunk_once(sub))
+                    parts.append(self._bin_chunk_once(sub, device))
                 lo += sub.shape[0]
             except membudget.DeviceOutOfMemory:
                 if not self._shrink_chunk():
@@ -277,7 +341,7 @@ class DeviceBinner:
         self.chunk_rows = new
         return True
 
-    def _bin_chunk_once(self, block: np.ndarray) -> jnp.ndarray:
+    def _bin_chunk_once(self, block: np.ndarray, device=None) -> jnp.ndarray:
         """One [rows, F] kernel launch, padded to the chunk shape so
         every launch reuses ONE compiled program, slicing the pad off
         on device."""
@@ -289,15 +353,17 @@ class DeviceBinner:
         chunk, self._launches = self._launches, self._launches + 1
         with obs.span("ingest/stage", chunk=chunk, rows=rows):
             vhi, vlo, cv = self._prep_chunk(block)
-        dummy = np.zeros((0,), np.int32)
+        if cv is None:
+            cv = np.zeros((0,), np.int32)
         with obs.span("ingest/dispatch", chunk=chunk):
+            if device not in self._dev_tables:
+                self._dev_tables[device] = jax.device_put(self._tables, device)
             out = _bin_chunk_kernel(
-                jnp.asarray(vhi), jnp.asarray(vlo),
-                jnp.asarray(cv) if cv is not None else jnp.asarray(dummy),
-                self._dev_tables, self.has_cat, str(self.out_dtype))
+                *jax.device_put((vhi, vlo, cv), device),
+                self._dev_tables[device], self.has_cat, str(self.out_dtype))
             return out[:rows] if pad else out
 
-    def bin_matrix(self, X: np.ndarray) -> jnp.ndarray:
+    def bin_matrix(self, X: np.ndarray, devices=None):
         """Stream X's used columns through the kernel chunk by chunk.
 
         Dispatch is async: while the device bins chunk i, the host is
@@ -305,19 +371,36 @@ class DeviceBinner:
         with compute (the "Out-of-Core GPU Gradient Boosting" chunked
         ingest pattern).
         """
-        return self.bin_stream([X])
+        return self.bin_stream([X], devices, X.shape[0])
 
-    def bin_stream(self, blocks) -> jnp.ndarray:
+    def bin_stream(self, blocks, devices=None, total_rows: int = 0):
         """Bin an iterable of raw row blocks, re-chunking across block
         boundaries so only the FINAL kernel launch pads — a file
         reader's chunk size rarely aligns with `chunk_rows`, and padding
         every reader chunk's tail would waste a steady fraction of the
-        kernel work on long streams."""
-        parts = []
+        kernel work on long streams.
+
+        With `devices` the `total_rows` rows are dealt to them in
+        consecutive ranges of whole chunks, each chunk binned on the
+        device that keeps it, and the result is a `RowParts`."""
+        per = self.chunk_rows * max(
+            1, -(-total_rows // (len(devices or [0]) * self.chunk_rows)))
+        parts: list = [[] for _ in devices or [0]]
+        done = 0  # rows handed to a device so far
+
+        def emit(rows_block):
+            nonlocal done
+            k = min(done // per, len(parts) - 1)
+            parts[k].append(self.bin_chunk(
+                rows_block, devices[k] if devices else None))
+            done += rows_block.shape[0]
+
         pend: list = []
         pend_rows = 0
         for block in blocks:
-            b = np.asarray(block, dtype=np.float64)[:, self.used_cols]
+            b = np.asarray(block, dtype=np.float64)
+            if self.used_cols != list(range(b.shape[1])):
+                b = b[:, self.used_cols]  # a copy: only where columns drop
             pend.append(b)
             pend_rows += b.shape[0]
             while pend_rows >= self.chunk_rows:
@@ -327,12 +410,13 @@ class DeviceBinner:
                 # and re-reading it for the remainder slice would keep
                 # rows the call already binned (silent duplication)
                 c = self.chunk_rows
-                parts.append(self.bin_chunk(buf[:c]))
+                emit(buf[:c])
                 pend = [buf[c:]]
                 pend_rows = pend[0].shape[0]
-        if pend_rows > 0 or not parts:
+        if pend_rows > 0 or not done:
             if not pend:
                 return jnp.zeros((0, len(self.used_cols)), self.out_dtype)
-            buf = pend[0] if len(pend) == 1 else np.concatenate(pend)
-            parts.append(self.bin_chunk(buf))
-        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+            emit(pend[0] if len(pend) == 1 else np.concatenate(pend))
+        whole = [p[0] if len(p) == 1 else jnp.concatenate(p, axis=0)
+                 for p in parts if p]
+        return RowParts(whole) if devices else whole[0]

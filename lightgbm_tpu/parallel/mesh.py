@@ -19,7 +19,7 @@ psum-vs-scatter decision is priced with.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -224,6 +224,45 @@ def reduce_scatter_recv_bytes(nbytes: int, shards: int) -> int:
     if shards <= 1:
         return 0
     return (shards - 1) * nbytes // shards
+
+
+def tree_hist_slots(num_leaves: int, split_batch: int, ramp: bool,
+                    ramp_step: int) -> List[int]:
+    """Leaf slots of each histogram call of one tree, the root's first,
+    when every round splits all it can: the grower's own schedule
+    (ops/grower.py: the root, the frontier ramp's pre-rounds at 1, s,
+    s^2, ... slots, then the round loop at `split_batch`).  255 leaves at
+    25 slots and s = 4 give 15 calls of 1, 1, 4, 16 and 11 x 25 slots."""
+    k = max(1, min(int(split_batch), num_leaves - 1))
+    widths, kr = [], 1
+    while ramp and k > 1 and kr < k:
+        widths.append(kr)
+        kr *= int(ramp_step)
+    slots, leaves = [1], 1
+    while leaves < num_leaves:
+        width = widths.pop(0) if widths else k
+        slots.append(width)
+        leaves += min(width, leaves, num_leaves - leaves)
+    return slots
+
+
+def exchange_bytes_per_tree(slots: Sequence[int], columns: int, bins: int,
+                            hist_itemsize: int, scatter: bool,
+                            cat_bins: int = 1) -> Dict[str, int]:
+    """Bytes one row shard hands to each collective of the data axis while
+    it grows one tree (operand bytes, before the ring's (P-1)/P): every
+    call's [slots, columns, bins, 3] histograms into the reduce-scatter
+    (scatter) or the all-reduce (psum), and under scatter the best-split
+    sync of both children of every slot and of the root: three all-
+    gathered words (gain, feature, threshold bin) and the winner's record
+    (nine words and the categorical mask) through a masked all-reduce.
+    The leaf totals' scalar all-reduces are left out."""
+    hist = sum(slots) * columns * bins * 3 * hist_itemsize
+    searched = 1 + 2 * sum(slots[1:])
+    if not scatter:
+        return {"reduce_scatter": 0, "all_gather": 0, "all_reduce": hist}
+    return {"reduce_scatter": hist, "all_gather": searched * 3 * 4,
+            "all_reduce": searched * (9 + cat_bins) * 4}
 
 
 # --------------------------------------------------------------------------

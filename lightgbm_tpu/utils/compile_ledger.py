@@ -422,6 +422,19 @@ class LedgeredJit:
         return getattr(self._fn, name)
 
 
+def closed_over_bytes(jitted, args: tuple, kwargs: dict,
+                      lengths) -> int:
+    """Bytes of the arrays a jitted function closes over (the constants
+    of its program, a table among them if it is captured and not passed)
+    that have an axis of one of `lengths`.  Traces `jitted` at `args`
+    (arrays or `jax.ShapeDtypeStruct`s; the trace is the one its first
+    call at these shapes uses) and compiles nothing."""
+    lengths = set(lengths)
+    consts = jitted.trace(*args, **kwargs).jaxpr.consts
+    return sum(int(c.size) * c.dtype.itemsize for c in consts
+               if lengths & set(getattr(c, "shape", ())))
+
+
 def ledger_jit(fn=None, *, site: Optional[str] = None, **jit_kwargs):
     """Drop-in `jax.jit` replacement that records programs in LEDGER.
 

@@ -8,9 +8,10 @@ The contract under test:
 * re-running an identical training in-process compiles nothing new (the
   grower/strategy memoization reuses the jitted executables) EXCEPT the
   Booster's own `learner.pre` / `learner.post` pair, closures over the
-  label arrays that every Booster traces and compiles for itself (on
-  the ledger since ISSUE 24, which found them to be the one program a
-  new dataset cannot load from the persistent cache);
+  objective's scalars that every Booster traces for itself (on the
+  ledger since ISSUE 24; since ISSUE 32 the labels are their arguments,
+  so a new dataset of the shape loads them from the persistent cache:
+  tests/test_sharded_step.py);
 * while enabled, the ledger charges every program JAX produces to the
   site whose call was in flight, and says whether the persistent cache
   answered it;

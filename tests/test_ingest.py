@@ -99,6 +99,62 @@ def _build_mappers(X, cfg=None, categorical=(3,)):
     return td
 
 
+class TestRowsDealtToDevices:
+    """`bin_matrix(X, devices)`: consecutive row ranges of whole chunks,
+    each binned and kept on its device, the bins those of one matrix."""
+
+    @pytest.fixture(scope="class")
+    def dealt(self):
+        import jax
+
+        X = _mixed_matrix(seed=7)
+        td = _build_mappers(X)
+        b = DeviceBinner.build(td.mappers, td.used_feature_idx, np.uint8,
+                               chunk_rows=256)
+        devices = jax.devices()[:4]
+        return (np.asarray(b.bin_matrix(X)), b.bin_matrix(X, devices),
+                devices)
+
+    def test_every_device_keeps_its_consecutive_whole_chunks(self, dealt):
+        whole, parts, devices = dealt
+        n = whole.shape[0]
+        per = -(-n // (4 * 256)) * 256
+        assert [p.shape[0] for p in parts.parts] == [
+            min(per, n - k * per) for k in range(4)]
+        assert [next(iter(p.devices())) for p in parts.parts] == devices
+        assert parts.shape == whole.shape and parts.dtype == whole.dtype
+
+    def test_the_bins_are_those_of_one_matrix(self, dealt):
+        whole, parts, _ = dealt
+        assert np.array_equal(np.asarray(parts), whole)
+        assert np.array_equal(np.asarray(parts.gathered()), whole)
+
+    @pytest.mark.parametrize("lo, hi", [(0, 300), (250, 2600), (1024, 1024),
+                                        (2000, 10 ** 9)])
+    def test_a_row_range_arrives_on_the_device_asked_for(self, dealt, lo, hi):
+        whole, parts, devices = dealt
+        pieces = parts.rows(lo, min(hi, whole.shape[0]), devices[2])
+        assert all(next(iter(p.devices())) == devices[2] for p in pieces)
+        got = (np.concatenate([np.asarray(p) for p in pieces]) if pieces
+               else whole[:0])
+        assert np.array_equal(got, whole[lo:hi])
+
+    def test_column_counts_and_the_strided_sample(self, dealt):
+        whole, parts, _ = dealt
+        assert np.array_equal(parts.column_counts(lambda p: p == 0),
+                              (whole == 0).sum(axis=0))
+        idx = np.arange(0, whole.shape[0], 37)
+        assert np.array_equal(parts.take(idx), whole[idx])
+
+    def test_a_plain_matrix_is_the_one_part_case(self, dealt):
+        from lightgbm_tpu.ops.binning import RowParts
+        import jax.numpy as jnp
+
+        whole = jnp.asarray(dealt[0])
+        one = RowParts.of(whole)
+        assert one.gathered() is whole and RowParts.of(one) is one
+
+
 class TestDeviceKernelParity:
     @pytest.mark.parametrize("max_bin", [2, 3, 16, 255, 300])
     def test_mixed_corners(self, max_bin):

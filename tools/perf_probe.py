@@ -433,7 +433,8 @@ def run_retrace(n=20000, f=10, leaves=31, bins=63, iters=3):
 
     # the retrace-elimination contract: an identical second training run
     # reuses every cached executable but the Booster's own learner.pre /
-    # learner.post (closures over the labels, one pair per Booster) —
+    # learner.post (closures of the Booster, one pair each; the labels
+    # are their arguments) —
     # any OTHER program compiled here is a regression (a jit site keyed
     # on a fresh closure or static value)
     ds2 = lgb.Dataset(X, label=y, params=p)
