@@ -181,6 +181,9 @@ def leg_train(ctx):
     check(lp.hist_impl == ("xla" if DRY else "pallas2"),
           f"tpu_hist_impl=auto resolved to {lp.hist_impl!r} at hilo")
     check(lp.precision == "hilo", f"precision {lp.precision!r}")
+    check(lp.partition_impl == ("select" if DRY else "kernel"),
+          f"tpu_partition_impl=auto resolved to {lp.partition_impl!r} on a "
+          "dense numerical table")
     auc = train_auc(bst, ctx["y"])
     check(auc >= AUC_FLOOR, f"train AUC {auc:.4f} under floor {AUC_FLOOR}")
     leaf_err = first_tree_recount(bst, ctx["X"], ctx["y"],
@@ -192,7 +195,8 @@ def leg_train(ctx):
     check(ooms == 0 and ladder == 0,
           f"oom events {ooms}, ladder steps {ladder}")
     ctx.update(bst=bst, compile_wall_s=warm_s)
-    say(f"train: ok impl={lp.hist_impl} precision={lp.precision} "
+    say(f"train: ok impl={lp.hist_impl} partition={lp.partition_impl} "
+        f"precision={lp.precision} "
         f"block_rows={lp.block_rows} trees={WARM_ITERS + TIMED_ITERS}x"
         f"{LEAVES} leaves auc={auc:.4f} "
         f"tree0_max_leaf_value_err_vs_host_recount={leaf_err:.2e} "

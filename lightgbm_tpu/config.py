@@ -540,13 +540,17 @@ _P: Dict[str, Tuple[str, Any, Tuple[str, ...]]] = {
     # only batch leaves whose gain >= alpha * the round's best gain (near
     # ties); keeps batched split order close to strict best-first
     "tpu_split_batch_alpha": ("float", 0.0, ()),
-    # row-partition lowering: select | vselect (ops/grower.py
-    # GrowerParams.partition_impl; honored by every tree learner).  select
-    # unrolls one scalar-broadcast pass per split; vselect fuses the K
-    # passes into one [K, n] block — fewer program points, but its
-    # CATEGORICAL path gathers per-row from a tiny table (the pattern
-    # select avoids)
-    "tpu_partition_impl": ("str", "select", ()),
+    # row-partition lowering: auto | select | kernel (ops/grower.py
+    # GrowerParams.partition_impl; honored by every tree learner).  auto is
+    # a fixed rule (learner._resolve_partition_impl): kernel on a TPU when
+    # the table is dense numerical and unpacked (no categorical feature,
+    # EFB bundle, sparse column or 4-bit packing), select everywhere else,
+    # CPU included: XLA's select costs 0.9 ms per split and 27M rows on a
+    # v5e, 296 times a 255-leaf tree, the kernel 2.1-2.5 ms per round, 14
+    # times (PERF.md section 5).  kernel = one Pallas pass over the leaf
+    # ids per round (ops/partition.py); select = one XLA pass per split,
+    # the form that takes every storage.  Same trees bit for bit
+    "tpu_partition_impl": ("str", "auto", ()),
     # frontier ramp: unrolled K'=1,2,4,... pre-rounds before the full-K
     # loop (bit-identical trees, removes early rounds' dead-slot MXU
     # work; see GrowerParams.ramp).  On v5e Higgs-1M it is worth ~10%

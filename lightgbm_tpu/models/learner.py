@@ -26,6 +26,7 @@ from ..ops.grower import (GrowerParams, canonical_params, mode_flags_np,
 from ..ops.histogram import (hashed_uniform, key_words, perfeature_chunks,
                              perfeature_columns_per_dot)
 from ..ops.lookup import lookup
+from ..ops.partition import partition_kernel_fits
 from ..parallel.mesh import (exchange_bytes_per_tree, put_global, put_local,
                              tree_hist_slots)
 from ..parallel.strategies import (bins_sharding, make_strategy_grower,
@@ -205,8 +206,9 @@ class TPUTreeLearner:
         removed = {("tpu_hist_impl", "pallas"): "pallas2",
                    ("tpu_hist_impl", "fused"): "pallas2",
                    ("tpu_partition_impl", "gather"): "select",
-                   ("tpu_partition_impl", "kernel"): "select"}
-        for key, allowed in (("tpu_partition_impl", ("select", "vselect")),
+                   ("tpu_partition_impl", "vselect"): "auto"}
+        for key, allowed in (("tpu_partition_impl",
+                              ("auto", "select", "kernel")),
                              ("tpu_hist_impl", ("auto", "xla", "pallas2")),
                              ("tpu_hist_precision", ("hilo", "bf16", "f32",
                                                      "f64", "int8", "int16")),
@@ -868,7 +870,16 @@ class TPUTreeLearner:
             cegb_penalty_split=float(config.cegb_penalty_split),
             forced=forced,
             hist_impl=hist_impl,
-            partition_impl=str(config.tpu_partition_impl),
+            partition_impl=self._resolve_partition_impl(
+                config,
+                dense_unpacked=not (meta_np["is_categorical"].any()
+                                    or plan is not None
+                                    or self._sparse_arrays is not None
+                                    or self.packed_bins
+                                    # the streamed grower has its own
+                                    or self.stream_layout),
+                shard_rows=self.n_pad // self.d_shards,
+                columns=self.g_pad, itemsize=1 if B <= 256 else 4),
             has_bundles=plan is not None,
             has_sparse=self._sparse_arrays is not None,
             packed_bins=self.packed_bins,
@@ -938,6 +949,7 @@ class TPUTreeLearner:
             live_columns=self.live_columns)
         self._feature_rng = np.random.default_rng(int(config.feature_fraction_seed))
         self._note_exchange()
+        self._note_partition()
 
     def reset_pool(self) -> None:
         """(Re)create the donated histogram-pool buffer as zeros.
@@ -1075,7 +1087,46 @@ class TPUTreeLearner:
                      "exchange_bytes_per_tree; voting exchanges voted "
                      "columns only and is not modelled)")
 
+    def _note_partition(self) -> None:
+        """`lgbm_partition_passes_per_tree{impl=}`: sweeps of the leaf ids
+        the grow program's row partition makes while it grows one tree,
+        from the round widths it was built with: one per split slot under
+        `select`, one per round under `kernel`; 0 on the lowering not in
+        force."""
+        p = self.params
+        rounds = tree_hist_slots(p.num_leaves, p.split_batch, p.ramp,
+                                 p.ramp_step)[1:]   # the root splits nothing
+        for impl in ("select", "kernel"):
+            passes = sum(rounds) if impl == "select" else len(rounds)
+            obs.REGISTRY.set_gauge(
+                "lgbm_partition_passes_per_tree",
+                passes if impl == p.partition_impl else 0, impl=impl,
+                help="sweeps of the leaf ids the row partition makes per "
+                     "tree when every round splits all it can (0: the "
+                     "lowering is not in force)")
+
     # ------------------------------------------------------------------
+    @staticmethod
+    def _resolve_partition_impl(config: Config, dense_unpacked: bool,
+                                shard_rows: int, columns: int,
+                                itemsize: int) -> str:
+        """Resolve tpu_partition_impl, honoring "auto": a rule over what
+        the code can observe, like `_resolve_hist_impl`'s.  The Pallas
+        pass ("kernel", ops/partition.py: one sweep of the leaf ids a
+        round) on a TPU when the table is dense numerical and unpacked (no
+        categorical feature, EFB bundle, sparse column or 4-bit packing)
+        and its shapes give the kernel whole registers and a narrow row
+        (`partition_kernel_fits`), at any tree_learner: inside shard_map
+        each shard partitions its own rows.  "select" (one XLA pass per
+        split) everywhere else, CPU included."""
+        impl = str(config.tpu_partition_impl)
+        if impl != "auto":
+            return impl
+        on_tpu = jax.devices()[0].platform == "tpu"
+        return ("kernel" if on_tpu and dense_unpacked
+                and partition_kernel_fits(shard_rows, columns, itemsize)
+                else "select")
+
     @staticmethod
     def _resolve_hist_agg(config: Config, strategy: str,
                           d_shards: int) -> str:

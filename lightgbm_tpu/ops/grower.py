@@ -102,8 +102,10 @@ from ..utils.compile_ledger import ledger_jit
 from .histogram import (build_histogram_batched_t, build_histogram_sparse,
                         build_histogram_t, key_words, pack_stats,
                         quant_limit, quantize_values, unpack2d)
+from .partition import partition_rows
 from .split import (K_MIN_SCORE, SplitResult, argbest, finalize_split,
-                    leaf_output, leaf_split_gain, numeric_go_left,
+                    go_right_scalars, leaf_output, leaf_split_gain,
+                    numeric_go_left,
                     per_feature_best_split,
                     per_feature_best_split_categorical,
                     MISSING_NAN, MISSING_ZERO)
@@ -175,11 +177,12 @@ class GrowerParams(NamedTuple):
     # batched-histogram backend: "xla" (scan + dot_general) or "pallas2"
     # (the perfeature VMEM kernel — ops/histogram.py _hist_pallas)
     hist_impl: str = "xla"
-    # row-partition lowering: "select" unrolls K scalar-broadcast passes
-    # (one dynamic row slice + elementwise compare per split — no per-row
-    # table gathers, which XLA serializes on TPU); "vselect" fuses those
-    # K passes into one [K, n] block (fewer program points; NOTE its
-    # categorical path per-row-gathers from the [K, CB] mask table)
+    # row-partition lowering: "kernel" is ONE Pallas pass over the leaf
+    # ids per round (ops/partition.py; dense numerical unpacked bins
+    # only); "select" unrolls K scalar-broadcast passes (one dynamic row
+    # slice + elementwise compare per split — no per-row table gathers,
+    # which XLA serializes on TPU) and takes every storage.  The learner
+    # resolves tpu_partition_impl=auto to one of the two
     partition_impl: str = "select"
     # EFB (reference FindGroups/FastFeatureBundling, dataset.cpp:91-263):
     # bins_t holds G <= F bundle columns; meta carries bundle_idx /
@@ -382,6 +385,17 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             "sparse train-time storage (tpu_sparse_threshold) requires "
             "tree_learner=serial/data/voting and no EFB bundling / 4-bit "
             "packing")
+    if params.partition_impl not in ("select", "kernel"):
+        raise ValueError(f"partition_impl={params.partition_impl!r}; "
+                         "expected select or kernel (the learner resolves "
+                         "'auto' upstream)")
+    if params.partition_impl == "kernel" and (
+            params.has_cat or params.has_bundles or params.has_sparse
+            or params.packed_bins):
+        raise ValueError(
+            "partition_impl=kernel takes dense numerical unpacked bins "
+            "only (no categorical feature, EFB bundle, sparse column or "
+            "4-bit packing); use select")
     precision = params.precision
     # quantized-gradient mode (tpu_hist_precision=int16|int8): stats ride
     # the MXU as narrow ints, histograms/pool/psum/subtraction stay in
@@ -1203,8 +1217,20 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             # the bins are replicated, so sel_feat's GLOBAL ids index
             # bins_t/meta directly in both lowerings — no column
             # broadcast ----
-            if params.partition_impl == "select":
-                # K unrolled scalar-broadcast passes: each split reads ONE
+            if params.partition_impl == "kernel":
+                # ONE pass over the ids: every slot's split applied to a
+                # block of rows in registers (ops/partition.py)
+                leaf_ids = partition_rows(
+                    bins_t, leaf_ids, jnp.where(do_k, sel, -1), new_ids,
+                    sel_feat, sel_thr,
+                    go_right_scalars(meta["missing_type"][sel_feat],
+                                     meta["num_bin"][sel_feat],
+                                     meta["default_bin"][sel_feat],
+                                     sel_thr, sel_dleft))
+            else:
+                # "select": K unrolled scalar-broadcast passes (the form
+                # that takes every storage: bundles, sparse columns,
+                # packed rows, categorical masks): each split reads ONE
                 # bin row (dynamic slice) and updates its own rows with
                 # elementwise compares.  No per-row table gathers — XLA's
                 # TPU gather for tiny tables serializes per element, and at
@@ -1262,59 +1288,6 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                     new_leaf = jnp.where(in_k & (~go_left_k),
                                          new_ids[k], new_leaf)
                 leaf_ids = new_leaf
-            else:
-                # "vselect", the vectorized single-block form: ONE [K, n]
-                # row gather + one fused elementwise block instead of K
-                # unrolled passes — K fewer program points for launch
-                # overhead at ~3 [K, n] intermediates of HBM traffic;
-                # same math as "select" bit-for-bit.
-                feat_rows = (meta["bundle_idx"][sel_feat]
-                             if params.has_bundles else
-                             meta["dense_col"][sel_feat]
-                             if params.has_sparse else sel_feat)
-                cols = bins_t[feat_rows]                     # [K, n_cols]
-                if params.packed_bins:
-                    cols = unpack2d(
-                        cols.reshape(Kr, nb, bcols)).reshape(Kr, -1)
-                if params.has_sparse:
-                    # vectorized on-the-fly materialization of the K
-                    # chosen columns' sparse variants (see the "select"
-                    # branch for the semantics)
-                    slots = meta["sparse_slot"][sel_feat]    # [K]
-                    si = sp_idx_t[slots]                     # [K, M]
-                    sb = sp_bin_t[slots]
-                    scols = jnp.broadcast_to(
-                        meta["default_bin"][sel_feat][:, None].astype(
-                            cols.dtype), (Kr, n_pad)).at[
-                        jnp.arange(Kr, dtype=jnp.int32)[:, None], si].set(
-                        sb.astype(cols.dtype), mode="drop")
-                    cols = jnp.where(
-                        (meta["is_sparse"][sel_feat] > 0)[:, None],
-                        scols, cols)
-                if params.has_bundles:
-                    cols = fix_bundle_col(
-                        cols, meta["bin_offset"][sel_feat][:, None],
-                        meta["num_bin"][sel_feat][:, None],
-                        (meta["needs_fix"][sel_feat] > 0)[:, None])
-                go_left = numeric_go_left(
-                    cols, meta["missing_type"][sel_feat][:, None],
-                    meta["num_bin"][sel_feat][:, None],
-                    meta["default_bin"][sel_feat][:, None],
-                    sel_thr[:, None], sel_dleft[:, None])    # [K, n]
-                if params.has_cat:
-                    # per-row gather from the tiny [K, CB] mask table —
-                    # the pattern "select" exists to avoid on TPU; see
-                    # the config.py tpu_partition_impl caveat
-                    cm = jnp.take_along_axis(cmask_sel, cols, axis=1)
-                    go_left = jnp.where(sel_iscat[:, None], cm > 0.5,
-                                        go_left)
-                move = ((leaf_ids[None, :] == sel[:, None])
-                        & do_k[:, None] & (~go_left))        # [K, n]
-                # each row sits in at most one frontier leaf, so a max
-                # over slots recovers its (unique) new id; -1 = stay
-                moved_to = jnp.max(
-                    jnp.where(move, new_ids[:, None], -1), axis=0)
-                leaf_ids = jnp.where(moved_to >= 0, moved_to, leaf_ids)
 
             # ---- monotone constraint propagation -----------------------
             # (reference serial_tree_learner.cpp:840-851)

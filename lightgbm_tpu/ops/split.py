@@ -41,12 +41,27 @@ MISSING_NAN = 2
 def numeric_go_left(col, mt, nbf, db, thr, dleft):
     """Numerical split decision incl. missing-value routing (reference
     dense_bin.hpp Split semantics); elementwise, the single source of
-    truth for every partition lowering — the grower's select and vselect
-    passes route rows through this one function."""
+    truth for every partition lowering — the grower's select passes route
+    rows through this one function, and the partition kernel through its
+    scalar restatement, `go_right_scalars`."""
     is_miss = jnp.where(
         mt == MISSING_NAN, col == nbf - 1,
         jnp.where(mt == MISSING_ZERO, col == db, False))
     return jnp.where(is_miss, dleft, col <= thr)
+
+
+def go_right_scalars(mt, nbf, db, thr, dleft):
+    """`numeric_go_left` folded for a pass that has only scalars per split:
+    a row goes RIGHT iff `(col > thr) ^ (col == flip_bin)`.  flip_bin is the
+    split's missing bin (the last bin under NaN routing, the default bin
+    under zero routing) where the threshold alone would send it the wrong
+    way, else -1, which no bin equals.  Elementwise over the splits; the
+    partition kernel (ops/partition.py) compares a row's bin against these
+    two numbers."""
+    miss = jnp.where(mt == MISSING_NAN, nbf - 1,
+                     jnp.where(mt == MISSING_ZERO, db, -1))
+    wrong = (miss >= 0) & ((miss > thr) == dleft)
+    return jnp.where(wrong, miss, -1).astype(jnp.int32)
 
 
 def argbest(gain: jnp.ndarray, feature: jnp.ndarray,

@@ -178,28 +178,39 @@ class TestEndToEnd:
 
 
 class TestPartitionImpls:
-    """select- and vselect-lowered partitions must grow identical trees."""
+    """The kernel-lowered partition (interpret mode here) must grow the
+    trees the select lowering grows, and the tables it has no form for
+    (categorical, EFB-bundled) must refuse it by name and train under the
+    default as under select."""
 
     def _train_dump(self, X, y, extra, impl):
         import lightgbm_tpu as lgb
         params = {"objective": "regression", "num_leaves": 31,
                   "min_data_in_leaf": 5, "max_bin": 64,
                   "tpu_partition_impl": impl, **extra}
-        ds = lgb.Dataset(X, label=y, params={"max_bin": 64})
+        ds = lgb.Dataset(X, label=y, params={"max_bin": 64, **extra},
+                         categorical_feature=extra.get("categorical_feature",
+                                                       "auto"))
         bst = lgb.train(params, ds, num_boost_round=8, verbose_eval=False)
         # trees only: the parameters section embeds tpu_partition_impl
         # itself and must differ between the two runs
         return bst.model_to_string().split("parameters", 1)[0]
+
+    def _refused_and_default_is_select(self, X, y, extra):
+        with pytest.raises(ValueError, match="dense numerical unpacked"):
+            self._train_dump(X, y, extra, "kernel")
+        assert (self._train_dump(X, y, extra, "auto")
+                == self._train_dump(X, y, extra, "select"))
 
     def test_numerical_identical(self):
         rng = np.random.default_rng(7)
         X = rng.normal(size=(3000, 6))
         y = X[:, 0] ** 2 + np.sin(3 * X[:, 1]) + 0.1 * rng.normal(size=3000)
         a = self._train_dump(X, y, {}, "select")
-        b = self._train_dump(X, y, {}, "vselect")
+        b = self._train_dump(X, y, {}, "kernel")
         assert a == b
 
-    def test_categorical_and_missing_identical(self):
+    def test_categorical_and_missing_refused(self):
         rng = np.random.default_rng(8)
         n = 3000
         Xc = rng.integers(0, 8, size=n).astype(np.float64)
@@ -208,26 +219,32 @@ class TestPartitionImpls:
         X = np.column_stack([Xc, Xn])
         y = (Xc % 3 == 1).astype(float) * 2 + np.nan_to_num(Xn) + \
             0.1 * rng.normal(size=n)
-        extra = {"categorical_feature": [0]}
-        a = self._train_dump(X, y, extra, "select")
-        b = self._train_dump(X, y, extra, "vselect")
+        self._refused_and_default_is_select(
+            X, y, {"categorical_feature": [0]})
+
+    def test_missing_identical(self):
+        rng = np.random.default_rng(8)
+        n = 3000
+        X = rng.normal(size=(n, 2))
+        X[rng.random((n, 2)) < 0.2] = np.nan
+        y = np.nan_to_num(X[:, 0]) + np.isnan(X[:, 1]) + \
+            0.1 * rng.normal(size=n)
+        a = self._train_dump(X, y, {}, "select")
+        b = self._train_dump(X, y, {}, "kernel")
         assert a == b
 
-    def test_bundled_identical(self):
+    def test_bundled_refused(self):
         rng = np.random.default_rng(9)
         n = 4000
-        # sparse one-hot-ish columns so EFB actually bundles
-        X = np.zeros((n, 6))
-        grp = rng.integers(0, 3, size=n)
-        for g in range(3):
-            X[grp == g, g] = rng.uniform(1, 2, size=(grp == g).sum())
-        X[:, 3:] = rng.normal(size=(n, 3))
-        y = X[:, 0] + 2 * X[:, 1] - X[:, 2] + X[:, 3] + \
+        # sparse one-hot-ish columns of few values so EFB actually bundles
+        X = np.zeros((n, 9))
+        grp = rng.integers(0, 6, size=n)
+        for g in range(6):
+            X[grp == g, g] = rng.integers(1, 4, size=(grp == g).sum())
+        X[:, 6:] = rng.normal(size=(n, 3))
+        y = X[:, 0] + 2 * X[:, 1] - X[:, 2] + X[:, 6] + \
             0.1 * rng.normal(size=n)
-        extra = {"enable_bundle": True}
-        a = self._train_dump(X, y, extra, "select")
-        b = self._train_dump(X, y, extra, "vselect")
-        assert a == b
+        self._refused_and_default_is_select(X, y, {"enable_bundle": True})
 
 
 class TestBatchedHistogramImpls:
@@ -628,10 +645,11 @@ class TestPackedBins:
             tpu_block_rows=128)._driver.learner.packed_bins
 
 
-class TestVselectPartition:
-    """tpu_partition_impl=vselect (one vectorized [K, n] pass) must
-    reproduce the unrolled "select" lowering bit-for-bit across plain,
-    categorical, EFB-bundled, and packed-bin configurations."""
+class TestKernelPartition:
+    """tpu_partition_impl=kernel (one Pallas pass a round) must reproduce
+    the unrolled "select" lowering bit-for-bit on plain tables, and be
+    refused by name on categorical, EFB-bundled and packed-bin ones,
+    which train under the default as under select."""
 
     def _model(self, seed, cat=False, **extra):
         import lightgbm_tpu as lgb
@@ -656,28 +674,43 @@ class TestVselectPartition:
         {"max_bin": 15, "tpu_hist_impl": "pallas2",
          "tpu_block_rows": 512},  # packed bins active
     ])
-    def test_vselect_matches_select(self, cfg):
+    def test_kernel_matches_select_or_is_refused(self, cfg):
         cfg = dict(cfg)
         cat = cfg.pop("cat", False)
         a = self._model(9, cat=cat, tpu_partition_impl="select", **cfg)
-        b = self._model(9, cat=cat, tpu_partition_impl="vselect", **cfg)
+        if cat or cfg:
+            with pytest.raises(ValueError, match="dense numerical unpacked"):
+                self._model(9, cat=cat, tpu_partition_impl="kernel", **cfg)
+            b = self._model(9, cat=cat, **cfg)          # the default
+        else:
+            b = self._model(9, cat=cat, tpu_partition_impl="kernel", **cfg)
         assert a == b
 
-    def test_vselect_matches_select_with_bundles(self):
+    def test_kernel_is_refused_with_bundles(self):
         import lightgbm_tpu as lgb
 
         rng = np.random.default_rng(11)
-        X = np.where(rng.random((3000, 10)) < 0.85, 0.0,
-                     rng.normal(size=(3000, 10)))
-        y = (X.sum(axis=1) > 0).astype(np.float64)
-        out = []
-        for impl in ("select", "vselect"):
+        # mutually exclusive columns of few values, so that EFB bundles
+        X = rng.normal(size=(3000, 10))
+        grp = rng.integers(0, 6, size=3000)
+        for g in range(6):
+            X[:, g] = np.where(grp == g, rng.integers(1, 4, size=3000), 0.0)
+        y = (X[:, :6].sum(axis=1) + X[:, 6] > 2).astype(np.float64)
+        out = {}
+        for impl in ("select", "auto", "kernel"):
             p = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
                  "max_bin": 31, "tpu_partition_impl": impl}
             ds = lgb.Dataset(X, label=y, params=p)
-            out.append(lgb.train(p, ds, num_boost_round=5)
-                       .model_to_string().split("\nparameters:")[0])
-        assert out[0] == out[1]
+            if impl == "kernel":
+                with pytest.raises(ValueError,
+                                   match="dense numerical unpacked"):
+                    lgb.train(p, ds, num_boost_round=5)
+                continue
+            bst = lgb.train(p, ds, num_boost_round=5,
+                            keep_training_booster=True)
+            assert bst._driver.learner.bundle_plan is not None
+            out[impl] = bst.model_to_string().split("\nparameters:")[0]
+        assert out["select"] == out["auto"]
 
 
 class TestAutoHistResolution:
@@ -727,7 +760,7 @@ class TestAutoHistResolution:
     @pytest.mark.parametrize("key,value,instead", [
         ("tpu_hist_impl", "fused", "pallas2"),
         ("tpu_hist_impl", "pallas", "pallas2"),
-        ("tpu_partition_impl", "kernel", "select"),
+        ("tpu_partition_impl", "vselect", "auto"),
         ("tpu_partition_impl", "gather", "select")])
     def test_removed_impl_is_refused_by_name(self, key, value, instead):
         # the values whose implementations were deleted: the error names

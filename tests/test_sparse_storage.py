@@ -51,19 +51,23 @@ def _model(params, X, y, rounds=5):
 
 
 class TestSparseStorageParity:
-    def test_f64_bitmatch_select_and_vselect(self, _x64_reset):
+    def test_f64_bitmatch_select_and_default(self, _x64_reset):
         X, y = _sparse_problem()
         models = {}
         for tag, extra in (
-                ("dense", {}),
-                ("sparse", {"tpu_sparse_threshold": 0.2}),
-                ("vsel", {"tpu_sparse_threshold": 0.2,
-                          "tpu_partition_impl": "vselect"})):
+                ("dense", {"tpu_partition_impl": "select"}),
+                ("sparse", {"tpu_sparse_threshold": 0.2,
+                            "tpu_partition_impl": "select"}),
+                ("auto", {"tpu_sparse_threshold": 0.2})):
             p = {**BASE, **extra, "deterministic": True}
             m = _model(p, X, y).model_to_string()
             models[tag] = m.split("\nparameters:")[0]
         assert models["sparse"] == models["dense"]
-        assert models["vsel"] == models["dense"]
+        assert models["auto"] == models["dense"]
+        # the kernel partition has no form for sparse columns
+        with pytest.raises(ValueError, match="dense numerical unpacked"):
+            _model({**BASE, "tpu_sparse_threshold": 0.2,
+                    "tpu_partition_impl": "kernel"}, X, y)
 
     def test_default_precision_decisions_agree(self):
         X, y = _sparse_problem()
