@@ -370,7 +370,7 @@ class GBDT:
         self._train_step = None
         with obs.span("train_step/build"):
             if (self.objective is not None and not self.objective.needs_renew
-                    and not self.objective.host_only
+                    and self.objective.steps_on_device(self.learner)
                     # CEGB threads cross-tree used/paid state through
                     # learner.train (the sync path); the async step hands
                     # the grower the learner's meta as it stood at layout
@@ -388,6 +388,13 @@ class GBDT:
                 self._train_step = self.learner.make_train_step(
                     self.objective, self.shrinkage_rate,
                     self._bag_cfg, self._goss_cfg)
+        if self.objective is not None:
+            obs.REGISTRY.set_gauge(
+                "lgbm_train_step_fused", self._train_step is not None,
+                objective=self.objective.name,
+                help="1 where the booster built the fused asynchronous "
+                     "device step (gradients, grower and score update "
+                     "dispatched back to back), 0 on the synchronous path")
 
     def _bagging_config(self) -> Optional[Dict]:
         cfg = self.config

@@ -1302,7 +1302,9 @@ class TPUTreeLearner:
         closed over: the bin matrix, the row mask, `meta` and the
         objective's per-row arrays (`objective.row_arrays()`: labels,
         weights) are ARGUMENTS, padded to n_pad and on the strategy's row
-        sharding, so a program's cache key holds shapes and not a table
+        sharding, and so is what else of the objective differs by data set
+        (`objective.layout_arrays()`: a ranking objective's queries, under
+        `rows["layout"]`), so a program's cache key holds shapes and not a table
         (`lgbm_step_row_constant_bytes` says so, program by program), and
         the grower stays the one bucketed program every Booster of a shape
         shares.  pre's outputs leave on the sharding the grower's inputs
@@ -1320,6 +1322,13 @@ class TPUTreeLearner:
         neg_frac = 1.0 if bagging is None else bagging.get("neg_fraction", 1.0)
         rows = {k: self.place_rows(v)
                 for k, v in objective.row_arrays().items()}
+        layout = objective.layout_arrays()
+        if layout:
+            # what differs by data set and has no row axis (a ranking
+            # objective's queries): whole on every device, an argument too
+            rows["layout"] = jax.tree.map(
+                jnp.asarray if self.mesh is None else
+                lambda v: jax.device_put(v, self._rep_sharding), layout)
         if bagging is not None and (pos_frac < 1.0 or neg_frac < 1.0):
             rows["is_pos"] = self.place_rows(bagging["is_pos"])
         feature_frac = float(self.config.feature_fraction)
@@ -1538,10 +1547,17 @@ class TPUTreeLearner:
         if gather_j is not None:
             calls.append((gather_j, (out["leaf_ids"],), {}))
         lengths = {self.n, self.n_pad, self.n_pad // self.d_shards}
+        # the gradient program may hold no array shaped like the
+        # objective's layout either (a data set's queries)
+        of_layout = {d for leaf in jax.tree.leaves(rows.get("layout", {}))
+                     for d in leaf.shape}
         for fn, args, kwargs in calls:
             obs.REGISTRY.set_gauge(
                 "lgbm_step_row_constant_bytes",
-                closed_over_bytes(fn, args, kwargs, lengths), site=fn.site,
+                closed_over_bytes(fn, args, kwargs,
+                                  lengths | (of_layout if fn is pre_j
+                                             else set())),
+                site=fn.site,
                 help="bytes of closed-over arrays with a row axis in a "
                      "program of the training step (0: the table, the "
                      "masks and the labels are arguments)")

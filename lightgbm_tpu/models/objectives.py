@@ -80,10 +80,18 @@ class Objective:
     #: device->host transfers of scores/leaf ids on the hot path)
     needs_renew = False
 
-    #: objectives whose gradients cannot be traced into the fused device
-    #: step (host RNG, data-dependent per-query work); the driver uses the
-    #: synchronous path for these
-    host_only = False
+    def layout_arrays(self) -> Dict:
+        """What `gradients` reads besides the per-row arrays and differs by
+        data set without being row-shaped (a ranking objective's query
+        layout): a pytree of host arrays, which the training step takes as
+        arguments whole, under `rows["layout"]`."""
+        return {}
+
+    def steps_on_device(self, learner) -> bool:
+        """Whether `gradients` can be traced into `learner`'s fused device
+        step; where not (host RNG, per-query work over rows the learner
+        shards) the driver takes the synchronous path."""
+        return True
 
     def renew_tree_output(self, tree, score: np.ndarray,
                           leaf_ids: np.ndarray, row_mask: np.ndarray) -> None:

@@ -5,9 +5,11 @@ Formulas mirror the reference implementations exactly (per-class citations
 below); the *structure* is TPU-first: gradients are jnp elementwise programs
 that trace into the fused train step where possible.  The L1/quantile/MAPE
 family re-fits leaf outputs on host (`renew_tree_output` — per-leaf
-percentile sorts are tiny next to histogram work), and the ranking
-objectives run per-query pairwise work on host numpy (`host_only`), exactly
-as the reference keeps them on CPU threads.
+percentile sorts are tiny next to histogram work).  LambdaRank's pairwise
+gradients are a device function of the scores on one device
+(`ops/lambdarank.py`); on a row-sharded learner, and for rank_xendcg's host
+RNG, the per-query work stays on host numpy (`steps_on_device`), as the
+reference keeps it on CPU threads.
 """
 
 from __future__ import annotations
@@ -18,8 +20,10 @@ import numpy as np
 
 import jax.numpy as jnp
 
+from .. import obs
 from ..config import Config
 from ..io.dataset import Metadata
+from ..ops import lambdarank
 from .objectives import (BinaryLogloss, Objective, RegressionL2,
                          _apply_weight, register)
 
@@ -572,7 +576,9 @@ def default_label_gain() -> List[float]:
 
 
 class _RankBase(Objective):
-    host_only = True  # per-query sorts + host RNG stay off the jit path
+    def steps_on_device(self, learner) -> bool:
+        # per-query work on host numpy (rank_xendcg draws from a host RNG)
+        return False
 
     def init(self, metadata: Metadata, num_data: int) -> None:
         super().init(metadata, num_data)
@@ -589,10 +595,14 @@ class _RankBase(Objective):
 class LambdarankNDCG(_RankBase):
     """reference src/objective/rank_objective.hpp:23-254.
 
-    Pairwise NDCG lambdas computed per query on host, vectorized over the
-    [cnt, cnt] pair matrix per query.  Exact sigmoid replaces the
+    Pairwise NDCG lambdas.  On one device they are a function of the
+    scores and of the query layout inside the training step
+    (`ops/lambdarank.py`, float32); `get_gradients` is the same equations
+    query by query on the host in float64, the oracle of the device path
+    and the path of a row-sharded learner.  Exact sigmoid replaces the
     reference's 1M-entry lookup table (rank_objective.hpp:196-209)."""
     name = "lambdarank"
+    row_attrs = ("weights",)
 
     def __init__(self, config: Config):
         super().__init__(config)
@@ -604,6 +614,11 @@ class LambdarankNDCG(_RankBase):
         gains = list(config.label_gain) or default_label_gain()
         self.label_gain = np.asarray(gains, np.float64)
 
+    def steps_on_device(self, learner) -> bool:
+        # rows sharded over a mesh would need queries whole on one shard
+        # (query-aligned row sharding): there the host path stays
+        return learner.mesh is None
+
     def init(self, metadata: Metadata, num_data: int) -> None:
         super().init(metadata, num_data)
         lbl = self.label_np
@@ -613,12 +628,38 @@ class LambdarankNDCG(_RankBase):
             raise ValueError("label must be non-negative for ranking task")
         if int(lbl.max()) >= len(self.label_gain):
             raise ValueError("label exceeds label_gain size")
-        # cache 1/maxDCG@k per query (reference rank_objective.hpp:60-70)
-        self.inverse_max_dcgs = np.zeros(self.num_queries)
-        for q in range(self.num_queries):
-            a, b = self.query_boundaries[q], self.query_boundaries[q + 1]
-            mdcg = self._max_dcg_at_k(self.optimize_pos_at, lbl[a:b])
-            self.inverse_max_dcgs[q] = 1.0 / mdcg if mdcg > 0 else 0.0
+        # the queries by padded length, and 1/maxDCG@k per query with them
+        # (reference rank_objective.hpp:60-70)
+        with obs.span("rank/query_layout", queries=self.num_queries,
+                      rows=num_data) as sp:
+            self.layout, self.inverse_max_dcgs, st = \
+                lambdarank.query_layout(self.query_boundaries, lbl,
+                                        self.label_gain,
+                                        self.optimize_pos_at)
+            if sp is not None:
+                sp.tags.update(buckets=st["buckets"], max_len=st["max_len"])
+        g = obs.REGISTRY.set_gauge
+        g("lgbm_rank_queries", st["queries"],
+          help="queries of the ranking objective's training set")
+        g("lgbm_rank_query_len", st["max_len"], stat="max",
+          help="rows of a query")
+        g("lgbm_rank_query_len", st["mean_len"], stat="mean")
+        g("lgbm_rank_buckets", st["buckets"],
+          help="padded lengths the queries are grouped by")
+        g("lgbm_rank_pairs", st["pairs_valid"], kind="valid",
+          help="ordered pairs of one query's rows: with different labels "
+               "(valid), and that the padded layout computes (slots)")
+        g("lgbm_rank_pairs", st["pairs_slots"], kind="slots")
+
+    def layout_arrays(self):
+        return self.layout
+
+    def gradients(self, score, rows):
+        lam, hes = lambdarank.gradients(
+            score.reshape(-1), rows["layout"], sigmoid=self.sigmoid,
+            norm=self.norm)
+        lam, hes = _apply_weight(lam, hes, rows.get("weights"))
+        return lam[None, :], hes[None, :]
 
     def _max_dcg_at_k(self, k: int, label: np.ndarray) -> float:
         k = min(k, len(label))
@@ -684,8 +725,8 @@ class LambdarankNDCG(_RankBase):
 @register
 class RankXENDCG(_RankBase):
     """reference src/objective/rank_xendcg_objective.hpp:19-138
-    (XE_NDCG, arxiv.org/abs/1911.09798).  Stochastic (per-doc gamma draws),
-    hence host_only."""
+    (XE_NDCG, arxiv.org/abs/1911.09798).  Stochastic (per-doc gamma draws
+    from a host RNG), hence on the host."""
     name = "rank_xendcg"
 
     def __init__(self, config: Config):
