@@ -22,7 +22,7 @@ import time
 import numpy as np
 
 from benchmarks.lib import forest, reference, sut, table, timing
-from benchmarks.lib.harness import Outcome
+from benchmarks.lib.harness import Outcome, compare, within
 from benchmarks.lib.spans import WINDOW_SPAN
 
 
@@ -99,10 +99,12 @@ def run(cell) -> Outcome:
     tol = float(traffic["score_rtol"]) * max(1.0, largest)
     walker_compiled = (None if None in (walker_before, walker_after)
                        else walker_after > walker_before)
+    compared = {"max_abs_score_error": compare(err, "<=", tol)}
     checks = {
         "forest_has_the_trees_asked_for":
             len(trees) == int(traffic["forest_trees"]) == big.num_trees(),
-        "device_scores_within_tol_of_float64_walk": err <= tol,
+        "device_scores_within_tol_of_float64_walk":
+            within(compared["max_abs_score_error"]),
         "no_oom_event_or_ladder_step": sut.no_oom_so_far(),
         "no_compilation_in_window": window_compiles == 0,
     }
@@ -126,4 +128,4 @@ def run(cell) -> Outcome:
         attempted=calls, failed=failed, checks=checks,
         end_to_end={"predict_rows_per_s": rows_returned / elapsed,
                     "setup_s": setup_s},
-        facts=facts, notes=notes)
+        facts=facts, notes=notes, compared=compared)

@@ -16,7 +16,7 @@ import traceback
 import numpy as np
 
 from benchmarks.lib import device, reference, sut, table, timing
-from benchmarks.lib.harness import Outcome
+from benchmarks.lib.harness import Outcome, compare, within
 from benchmarks.lib.spans import WINDOW_SPAN
 
 
@@ -86,6 +86,13 @@ def run(cell) -> Outcome:
     k = int(correct["holdout_auc_trees"])
     auc = reference.auc(reference.walk(trees[:k], tab.hold["X"]),
                         tab.hold["y"])
+    compared = {
+        "first_tree_leaf_count_off_by":
+            compare(count_off, "<=", int(correct["leaf_count_slack"])),
+        "first_tree_leaf_value_error":
+            compare(worst_err, "<=", float(correct["leaf_value_tol"])),
+        "holdout_auc": compare(auc, ">=", float(correct["holdout_auc_floor"])),
+    }
     checks = {
         "a_tree_per_iteration":
             len(trees) == warmup + iterations and not stalled,
@@ -94,11 +101,10 @@ def run(cell) -> Outcome:
         "leaf_values_finite":
             all(np.isfinite(t["leaf_value"]).all() for t in trees),
         "first_tree_leaf_counts_match_host_recount":
-            count_off <= int(correct["leaf_count_slack"]),
+            within(compared["first_tree_leaf_count_off_by"]),
         "first_tree_leaf_values_within_tol":
-            worst_err <= float(correct["leaf_value_tol"]),
-        "holdout_auc_at_or_above_floor":
-            auc >= float(correct["holdout_auc_floor"]),
+            within(compared["first_tree_leaf_value_error"]),
+        "holdout_auc_at_or_above_floor": within(compared["holdout_auc"]),
         "no_oom_event_or_ladder_step": sut.no_oom_so_far(),
         "no_compilation_in_window": window_compiles == 0,
     }
@@ -120,12 +126,13 @@ def run(cell) -> Outcome:
                  **observed,
                  trees=len(trees), setup_s=setup_s, window_s=elapsed,
                  iterations=iterations)
-    facts.update(iterations=iterations, window_start=window_start,
-                 rows=int(tab.data["rows"]),
+    rows = int(tab.data["rows"])
+    facts.update(table.histogram_facts(cell, trees, warmup, rows),
+                 iterations=iterations, window_start=window_start, rows=rows,
                  features=int(tab.data["features"]),
                  bins=int(params["max_bin"]))
     return Outcome(
         attempted=iterations, failed=failed, checks=checks,
         end_to_end={"train_iters_per_s": iterations / elapsed,
                     "setup_s": setup_s},
-        facts=facts, notes=notes)
+        facts=facts, notes=notes, compared=compared)

@@ -37,7 +37,7 @@ import numpy as np
 
 from benchmarks.lib import (device, program_gauges, rank_reference, reference,
                             sut, table, timing)
-from benchmarks.lib.harness import Outcome
+from benchmarks.lib.harness import Outcome, compare
 from benchmarks.lib.spans import WINDOW_SPAN
 
 GRADIENT_SITE = "learner.pre"
@@ -214,6 +214,17 @@ def run(cell) -> Outcome:
     else:
         ref_checks, ref_notes = {"two_trees_to_check": False}, {}
     checks.update(ref_checks)
+    # the numbers `rank_checks` compared, beside their limits
+    compared = {name: compare(ref_notes[name], holds, correct[limit])
+                for name, holds, limit in (
+                    ("first_tree_worst_leaf_count_off_by", "<=",
+                     "leaf_count_slack"),
+                    ("tree_0_worst_leaf_value_error", "<=",
+                     "tree_0_leaf_value_tol"),
+                    ("tree_1_worst_leaf_value_error", "<=",
+                     "tree_1_leaf_value_tol"),
+                    ("holdout_ndcg", ">=", "holdout_ndcg_floor"))
+                if name in ref_notes}
     observed = {"hist_impl": sut.hist_impl(bst),
                 "device_ingest": sut.ingest_on_device(tab.dataset)}
     for fact, want in {**conf.get("expect", {}),
@@ -230,12 +241,13 @@ def run(cell) -> Outcome:
                  trees=len(trees), setup_s=setup_s, window_s=elapsed,
                  iterations=iterations,
                  checks_s=time.perf_counter() - t_checks)
-    facts.update(iterations=iterations, window_start=window_start,
-                 rows=int(tab.data["rows"]),
+    rows = int(tab.data["rows"])
+    facts.update(table.histogram_facts(cell, trees, warmup, rows),
+                 iterations=iterations, window_start=window_start, rows=rows,
                  features=int(tab.data["features"]),
                  bins=int(params["max_bin"]))
     return Outcome(
         attempted=iterations, failed=failed, checks=checks,
         end_to_end={"train_iters_per_s": iterations / elapsed,
                     "setup_s": setup_s},
-        facts=facts, notes=notes)
+        facts=facts, notes=notes, compared=compared)

@@ -19,7 +19,9 @@ recount.  Around it:
     program's gauges ("not observable" where one is gone):
     `step_holds_no_row_constant`, `data_shards_equal_chips`,
     `shard_rows_equal_within_one_block` and `hist_agg_as_resolved`, and
-    the shards' rows and the exchange's reckoned bytes among the notes.
+    the shards' rows and the exchange's reckoned bytes among the notes;
+    `data_shards` among the facts, by which the readers of the histogram
+    work divide a tree's rows, and the rows a chip histograms on a line.
 """
 
 import importlib.util
@@ -101,5 +103,10 @@ def run(cell):
     checks, notes = sharded_checks(cell, program_gauges.snapshot())
     outcome.checks.update(checks)
     outcome.notes.update(notes)
-    outcome.facts["data_shards"] = notes["data_shards"]
+    shards = outcome.facts["data_shards"] = notes["data_shards"]
+    if shards:
+        # every chip histograms its own rows of each tree
+        cell.say("histogrammed rows per chip", data_shards=shards,
+                 by_tree=[r / shards for r in
+                          outcome.facts["hist_rows_by_tree"]])
     return outcome

@@ -1,59 +1,62 @@
-"""The histogram kernel's share of its roofline, in percent.
+"""The histogram kernels' share of their roofline, in percent: the least
+time a chip could take for the histogram work of the window's trees
+(`lib/opcount.window_histogram_work`: the rows those trees histogram, from
+the public model text, a shard's share of them on a row-sharded job,
+against the published peaks) over the time the trace gives the layer's
+kernels per chip (`hist_build_ms_per_iter`'s events).
 
-The least time the chip could take for the kernel's calls in the window
-(`lib/opcount.hist_contraction` against the published peaks) over the time
-the trace says they took.  Slots, statistic planes and the planes' type are
-read from each call's own instruction text,
+The work is the trees', not the kernel's: nothing is read from a call's
+operands, so a kernel with another signature, a kernel that contracts only
+the rows a histogram needs, or a pass beside it is read on the same scale.
+The chip has no published vector-unit peak; the three additions a row and
+column stand against the bf16 matrix peak, and the work is memory-bound in
+every cell, so the share reads low by nature: today's kernel does each
+addition as 2 x bins x planes multiply-adds over every row of the table at
+every call.  What it is for is a number that rises with any honest gain in
+the layer and cannot pass 100 % while every histogrammed row is read once.
 
-    %hist_build.16 = f32[8192,125] custom-call(u8[124,32,8192] bins,
-        bf16[124,5,8192] stats, s32[124,1,8192] leaf ids, s32[25,1] slots)
-
-while rows, features and bins are the configuration's, not the padded ones.
-The contraction feeds the MXU bf16 operands, so the compute peak is the
-bf16 one.  Which bound holds goes on an earlier line.
-"""
+Which bound holds goes on an earlier line, and with it, where every call's
+output still reads as `[features x bins, slots x planes]`, the share of the
+MXU's peak that the dense contraction the calls were built as
+(`lib/opcount.hist_contraction`) comes to: how full the MXU is inside the
+formulation, a note with no claim on it.  None where the job states no
+trees or no kernel ran; a device without published peaks is an error."""
 
 import re
 
 from benchmarks.lib import opcount, peaks
 
-_SHAPE = re.compile(r"\b([a-z]+\d+)\[([\d,]+)\]")
-_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s8": 1, "u8": 1, "s32": 4}
+_OUT_COLUMNS = re.compile(r"^%\S+ = [a-z]+\d+\[\d+,(\d+)\]\S* custom-call\(")
 
 
-def call_shape(text: str):
-    """(slots, planes, bytes of a statistic) of one kernel call, or None
-    where the instruction is not the call this reader knows."""
-    shapes = [(t, [int(d) for d in dims.split(",")]) for t, dims
-              in _SHAPE.findall(text.split("custom_call_target")[0])]
-    if len(shapes) != 5 or "custom-call(" not in text:
-        return None
-    (_, out), _, (stat_type, stats), _, (_, slots) = shapes
-    if (len(out) != 2 or len(stats) != 3 or stat_type not in _BYTES
-            or out[1] != slots[0] * stats[1]):
-        return None
-    return slots[0], stats[1], _BYTES[stat_type]
+def dense_mxu_share(facts, events, chip_seconds: float, peak_ops: float):
+    """Percent of `peak_ops` that the calls' dense contractions come to,
+    None where a call's output is not the `[., slots x planes]` one."""
+    rows = facts["rows"] / (facts.get("data_shards") or 1)
+    ops = 0
+    for ev in events:
+        for name in ev.names:
+            m = _OUT_COLUMNS.match(name)
+            if m is None:
+                return None
+            ops += opcount.hist_contraction(rows, facts["features"],
+                                            facts["bins"], int(m.group(1)),
+                                            planes=1)[0]
+    return 100.0 * ops / len(events) / peak_ops / chip_seconds
 
 
 def read(run):
     hist = run.cell.load("layer_metrics", "hist_build_ms_per_iter")
-    facts = run.facts
-    ops = byts = seconds = 0.0
-    for ev in hist.events(run):
-        for name, dur in zip(ev.names, ev.dur):
-            shape = call_shape(name)
-            if shape is None:
-                return None
-            slots, planes, stat_bytes = shape
-            o, b = opcount.hist_contraction(
-                facts["rows"], facts["features"], facts["bins"], slots,
-                planes, stat_bytes=stat_bytes)
-            ops, byts, seconds = ops + o, byts + b, seconds + float(dur)
-    if not seconds:
+    events = hist.events(run)
+    chip_seconds = sum(ev.total() for ev in events) / max(len(events), 1)
+    work = opcount.window_histogram_work(run.facts)
+    if work is None or not chip_seconds:
         return None
     peak = peaks.peaks_for(run.cell.devices[0].device_kind)
-    share, bound = opcount.roofline(ops, byts, seconds, peak["bf16_flops"],
+    share, bound = opcount.roofline(*work, chip_seconds, peak["bf16_flops"],
                                     peak["hbm_bytes_per_s"])
-    run.cell.say("hist_kernel_roofline", bound=bound, kernel_s=seconds,
-                 operations=ops, bytes=byts)
+    run.cell.say("hist_kernel_roofline", bound=bound,
+                 kernel_s_per_chip=chip_seconds, operations=work[0],
+                 bytes=work[1], dense_contraction_mxu_share=dense_mxu_share(
+                     run.facts, events, chip_seconds, peak["bf16_flops"]))
     return share

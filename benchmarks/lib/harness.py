@@ -21,7 +21,7 @@ import shutil
 import sys
 import time
 import traceback
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT_DIR_NAME = "bench_out"  # in the checkout, listed in .gitignore
@@ -115,6 +115,9 @@ class Outcome:
     end_to_end: dict              # metric name -> value
     facts: dict                   # what the per-layer readers need
     notes: dict                   # what else the run found, for its earlier lines
+    # each number `correct` compared, beside its limit:
+    # name -> {"value": .., "limit": .., "holds": "<=" or ">="}
+    compared: dict = field(default_factory=dict)
 
 
 @dataclass
@@ -128,6 +131,20 @@ class Run:
     def metric(self, name: str):
         """What another per-layer reader says of this run."""
         return self.cell.load("layer_metrics", name).read(self)
+
+
+def compare(value, holds: str, limit) -> dict:
+    """One entry of `Outcome.compared`: `value` has to be `<=` or `>=`
+    its `limit`."""
+    if holds not in ("<=", ">="):
+        raise ValueError(f"a number holds its limit by <= or >=, not {holds!r}")
+    return {"value": value, "holds": holds, "limit": limit}
+
+
+def within(c: dict) -> bool:
+    """Whether a compared number is on its limit's right side."""
+    return (c["value"] <= c["limit"] if c["holds"] == "<="
+            else c["value"] >= c["limit"])
 
 
 def correct(checks: dict) -> bool:
@@ -242,6 +259,11 @@ def main(argv, t0: float) -> int:
                 metrics[m["name"]] = {
                     "value": float(outcome.end_to_end[m["name"]]),
                     "unit": m["unit"]}
-    result.update(metrics=metrics, device=dev)
+    result.update(metrics=metrics, device=dev, compared=outcome.compared)
     print(json.dumps(result), flush=True)
+    # the numbers compared are also the last lines of standard error
+    for name, c in outcome.compared.items():
+        print(f"compared {name}: {c['value']!r} {c['holds']} limit "
+              f"{c['limit']!r}", file=sys.stderr)
+    sys.stderr.flush()
     return 0

@@ -1,8 +1,30 @@
-"""Operations and bytes of the two device kernels, from their shapes.
+"""Operations and bytes of the device kernels' work, from shapes and from
+the trees that were grown.
 
 These are the yardstick of the roofline shares, so they count what the
-algorithm as formulated has to do, not what one implementation happens to
-do on top of it (padding rows, recomputation).
+algorithm has to do, not what one implementation happens to do on top of
+it (padding rows, recomputation, a one-hot's zeros).
+
+Histogram work of a tree (`tree_histogram_work`, what `hist_kernel_roofline`
+and `train_step_mfu` stand on): a tree of `splits` splits needs the root's
+histogram over every row and, for each split, the histogram of the smaller
+child (the larger one is the parent's less the smaller's: LightGBM's
+`serial_tree_learner.cpp:428-437`).  Over the `hist_rows` rows those
+histograms hold between them (`lib/reference.histogrammed_rows`, from the
+public model text), `features` columns, `bins` bins and `histograms`
+histograms built,
+
+    operations = 3 * hist_rows * features
+    bytes      = hist_rows * (features * bin_bytes + 8)
+                 + histograms * features * bins * 12
+
+(one addition each to a bin's gradient, hessian and count; a histogrammed
+row's bins and its float32 gradient and hessian read once; each histogram
+written once as three float32 planes).  It is the same work whatever
+implements it, so a kernel that stops contracting rows no histogram needs,
+a pass beside it, or another kernel altogether is read on the same scale,
+and no implementation passes 100 % while it reads every histogrammed row
+once.
 
 Histogram contraction (`ops/histogram.py`, the `pallas2` kernel): for every
 row block the kernel multiplies a one-hot [bins, rows] matrix per feature
@@ -18,8 +40,10 @@ hi/lo split of gradient and hessian plus the count, 3 otherwise) is
 (the binned columns, the statistic planes and the int32 leaf id of every
 row read once; the float32 accumulator written once).  The scatter-add
 the contraction stands for needs only 3 * rows * features additions; the
-one-hot formulation trades those for dense MXU work, and the share reported
-is that of the formulation the kernel runs.
+one-hot formulation trades those for dense MXU work, over all `rows` rows
+at every call.  Since PR 35 this count is a note beside the roofline (how
+full the MXU is inside the formulation), not the yardstick: a kernel that
+skips rows does less than it.
 
 Forest walk (`ops/predict.py`): every row descends every tree, one node per
 level; a level reads the node's five table entries and the row's bin of the
@@ -37,6 +61,33 @@ def hist_contraction(rows: int, features: int, bins: int, slots: int,
     byts = (rows * (features * bin_bytes + planes * stat_bytes + 4)
             + features * bins * slots * planes * 4)
     return ops, byts
+
+
+def tree_histogram_work(hist_rows: float, features: int, bins: int,
+                        histograms: int, bin_bytes: int = 1):
+    ops = 3 * hist_rows * features
+    byts = (hist_rows * (features * bin_bytes + 8)
+            + histograms * features * bins * 12)
+    return ops, byts
+
+
+def window_histogram_work(facts: dict):
+    """(operations, bytes) one chip has to do for the histograms of the
+    window's trees, from what a training job states of them: the rows each
+    tree histograms, dealt over the job's row shards (every chip builds
+    every histogram whole, over its own rows); None where the job states
+    no trees or none fell in the window."""
+    rows_by_tree = facts.get("hist_rows_by_tree")
+    if not rows_by_tree:
+        return None
+    first = int(facts["first_window_tree"])
+    last = first + int(facts["iterations"])
+    hist_rows = sum(rows_by_tree[first:last])
+    if not hist_rows:
+        return None
+    return tree_histogram_work(
+        hist_rows / (facts.get("data_shards") or 1), facts["features"],
+        facts["bins"], sum(facts["histograms_by_tree"][first:last]))
 
 
 def forest_walk(rows: int, trees: int, depth: int, features: int):

@@ -5,6 +5,8 @@
   walked row by row in float64 by the published decision rule
   (LightGBM `tree.h` NumericalDecision: missing routing first, then
   `value <= threshold` goes left).
+* `histogrammed_rows`: the rows a tree's histograms hold between them, from
+  its stated leaf counts: the work of `lib/opcount.tree_histogram_work`.
 * `recount_first_tree`: tree 0 against the rows it was grown from — every
   leaf's row count, and its value from the labels alone.
 * `auc`: rank AUC with ties at their mean rank.
@@ -59,6 +61,55 @@ def parse_model(text: str) -> list:
     if any(t["num_cat"] for t in trees):
         raise ModelTextError("the reference walker has no categorical rule")
     return trees
+
+
+def child_counts(tree: dict) -> np.ndarray:
+    """[2, splits]: the rows under the left and under the right child of
+    every split, a leaf's from `leaf_count`, a split's the sum of its two.
+    The model text numbers a split's children after it (LightGBM numbers
+    splits in the order it makes them), which one pass from the last split
+    to the root leans on."""
+    splits = tree["num_leaves"] - 1
+    kids = np.stack([tree["left_child"][:splits],
+                     tree["right_child"][:splits]])
+    if (kids >= 0).any() and (kids <= np.arange(splits))[kids >= 0].any():
+        raise ModelTextError("a split's child is numbered before the split")
+    under = np.zeros((2, splits), np.int64)
+    for node in range(splits - 1, -1, -1):
+        for side in (0, 1):
+            child = kids[side, node]
+            under[side, node] = (tree["leaf_count"][~child] if child < 0
+                                 else under[:, child].sum())
+    return under
+
+
+def histogrammed_rows(tree: dict):
+    """(rows, histograms): what growing `tree` has to histogram, whatever
+    grew it.  The root's histogram holds every row; of a split's two
+    children only the smaller one's is built from rows, the other is the
+    parent's less that (LightGBM `serial_tree_learner.cpp:428-437`).  So
+
+        rows = count(root) + sum over splits of min(count(left), count(right))
+
+    with a histogram per split and one for the root.  A level's smaller
+    children hold at most half the table between them, so `rows` is at
+    most count(root) * (1 + depth / 2)."""
+    if tree["num_leaves"] <= 1:
+        counted = tree.get("leaf_count", ())
+        return (int(counted[0]) if len(counted) else 0), 1
+    under = child_counts(tree)
+    return (int(under[:, 0].sum() + under.min(axis=0).sum()),
+            int(tree["num_leaves"]))
+
+
+def window_histogram_facts(trees: list, first_window_tree: int) -> dict:
+    """What a training job puts into its `facts` for the readers of the
+    histogram work: each tree's `histogrammed_rows`, and which tree the
+    window began with."""
+    rows, histograms = zip(*map(histogrammed_rows, trees)) if trees else ((), ())
+    return {"hist_rows_by_tree": list(rows),
+            "histograms_by_tree": list(histograms),
+            "first_window_tree": int(first_window_tree)}
 
 
 def leaf_index(tree: dict, X: np.ndarray) -> np.ndarray:

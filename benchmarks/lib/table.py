@@ -1,10 +1,11 @@
-"""The start of every job that trains: the parameters as they are run, the
-table and the hold-out from the seed, and the table binned by
-`Dataset.construct`."""
+"""What every job that trains shares: at its start the parameters as they
+are run, the table and the hold-out from the seed, and the table binned by
+`Dataset.construct`; after its window the facts its per-layer readers get
+of the set-up and of the trees."""
 
 from dataclasses import dataclass
 
-from . import device, sut
+from . import device, reference, sut
 
 
 @dataclass
@@ -50,3 +51,19 @@ def setup_facts(cell, table: Table, setup_compiles, window_compiles: int):
     facts = {"ingest_rows_per_s": rows / table.ingest_s,
              "setup_compiles": setup_compiles}
     return notes, facts
+
+
+def histogram_facts(cell, trees: list, first_window_tree: int,
+                    rows: int) -> dict:
+    """The facts of the histogram work (`reference.window_histogram_facts`,
+    from the trees as the model text states them), and a line that says
+    each tree's histogrammed rows over the table's rows beside the most a
+    tree of its depth can histogram, 1 + depth / 2: a reading over that
+    is a fault of the count, not of the program."""
+    facts = reference.window_histogram_facts(trees, first_window_tree)
+    cell.say("histogrammed rows", table_rows=rows, **facts,
+             over_table_rows_by_tree=[r / rows for r in
+                                      facts["hist_rows_by_tree"]],
+             most_a_tree_can_by_tree=[1 + reference.depth(t) / 2
+                                      for t in trees])
+    return facts

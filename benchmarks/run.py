@@ -7,7 +7,9 @@ or fewer chips than the cell asks for, exits non-zero and prints no result.
 `--rehearse-cpu` (never the default) runs the same code at toy size on the
 CPU and says `"platform": "cpu"` in its result: a rehearsal, no measurement.
 The last line of standard output is the result; the lines before it are
-notes (`{"note": ...}`).  See benchmarks/lib/harness.py.
+notes (`{"note": ...}`).  Each number `correct` compared stands beside its
+limit under the result's last key, `compared`, and on the last lines of
+standard error.  See benchmarks/lib/harness.py.
 """
 
 import time

@@ -21,7 +21,8 @@ KEPT = harness.load_json(os.path.join(harness.BENCH_DIR, "kept_for_later.json"))
 KEPT_CELLS = [w["name"] for w in KEPT["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
 LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 
 
 def run_cell(root, *args, env=None):
@@ -144,7 +145,7 @@ def run(cell):
             jnp.asarray(rows["X"])).block_until_ready()
     return Outcome(attempted=1, failed=0, checks={"ran": True},
                    end_to_end={"echo_per_s": 7.0, "setup_s": 1.0},
-                   facts={"answer": 42}, notes={})
+                   facts={"answer": 42}, notes={})   # a job may compare nothing
 '''
 NEW_DATAGEN = '''
 import numpy as np
@@ -226,6 +227,7 @@ def test_a_later_prs_cell_runs_with_no_edit(later_pr, trace):
     assert rc == 0, err
     result = json.loads(lines[-1])
     assert result["correct"] and result["attempted"] == 1
+    assert result["compared"] == {} and "compared " not in err
     if trace:
         # only the readers that apply to the cell were asked
         assert result["metrics"]["answer"] == {"value": 42.0, "unit": "1"}
@@ -300,6 +302,27 @@ def test_rehearsal_prints_the_contracts_result_line(cell, trace, kept_root):
     assert all("note" in json.loads(n) for n in notes if n.startswith("{"))
     assert set(result) == RESULT_KEYS | ({"breakdown"} if trace else set())
     assert result["correct"] is True and result["failed"] == 0
+    # each number compared beside its limit: last in the result's line, and
+    # the last lines of standard error
+    compared = result["compared"]
+    assert list(result)[-1] == "compared" and len(compared) >= 1
+    assert all(harness.within(c) for c in compared.values())
+    assert err.strip().splitlines()[-len(compared):] == [
+        f"compared {k}: {c['value']!r} {c['holds']} limit {c['limit']!r}"
+        for k, c in compared.items()]
+    said = {n["note"]: n for n in map(json.loads, notes) if "note" in n}
+    if "train" in cell.split(".")[-1]:
+        # the rows each tree histograms: at least the table once, at most
+        # what a tree of its depth can, and a quarter of it a chip on four
+        rows = said["histogrammed rows"]
+        assert rows["first_window_tree"] >= 1
+        assert len(rows["hist_rows_by_tree"]) == result["attempted"] + \
+            rows["first_window_tree"]
+        assert all(1.0 <= got <= most for got, most in zip(
+            rows["over_table_rows_by_tree"], rows["most_a_tree_can_by_tree"]))
+        if chips > 1:
+            assert said["histogrammed rows per chip"]["by_tree"] == [
+                r / chips for r in rows["hist_rows_by_tree"]]
     assert result["attempted"] >= 1
     dev = result["device"]
     assert (dev["platform"], dev["count"]) == ("cpu", chips)  # a rehearsal says so

@@ -76,7 +76,14 @@ def test_an_empty_trace_reads_none(own):
                                       {"iterations": 2})) is None
 
 
-def test_the_metric_is_declared_beside_the_score_updates():
+# the cells whose traced run on the chip reports the partition's kernel
+# (my chip runs, PR 35)
+REPORTED_IN = ["higgs-27m-255.train", "higgs-27m-63.train",
+               "criteo-13m-67.train", "criteo-27m-67.train-data4",
+               "mslr-7m-63.train-rank"]
+
+
+def test_the_metric_is_declared_for_the_cells_that_report_it():
     with open(os.path.join(os.path.dirname(harness.BENCH_DIR),
                            "BENCHMARK.json")) as f:
         spec = json.load(f)
@@ -84,6 +91,5 @@ def test_the_metric_is_declared_beside_the_score_updates():
     assert by_name[NAME] == {
         "name": NAME, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "grower",
-        "moves": "train_iters_per_s",
-        "workloads": by_name["score_update_ms_per_iter"]["workloads"]}
+        "moves": "train_iters_per_s", "workloads": REPORTED_IN}
     assert by_name[NAME]["layer"] == by_name["grow_other_ms_per_iter"]["layer"]

@@ -80,29 +80,40 @@ def test_a_trace_with_no_score_update_reads_none(own):
                                       {"iterations": 2})) is None
 
 
+# the cells whose traced run on the chip reports a score update program
+# (my chip runs, PR 35)
+REPORTED_IN = ["higgs-27m-255.train", "higgs-27m-63.train",
+               "criteo-13m-67.train", "criteo-27m-67.train-data4",
+               "mslr-7m-63.train-rank"]
+
+
 def declared(name):
     with open(os.path.join(os.path.dirname(harness.BENCH_DIR),
                            "BENCHMARK.json")) as f:
         spec = json.load(f)
-    train = [w["name"] for w in spec["workloads"] if w["traffic"] == "train"]
-    return next(m for m in spec["per_layer"] if m["name"] == name), train
+    return next(m for m in spec["per_layer"] if m["name"] == name)
 
 
-def test_the_metric_is_declared_for_the_train_cells():
-    entry, train = declared(NAME)
-    assert entry == {
+def test_the_metric_is_declared_for_the_cells_that_report_it():
+    assert declared(NAME) == {
         "name": NAME, "unit": "ms", "better": "lower",
         "source": "device_trace", "layer": "boosting_driver",
-        "moves": "train_iters_per_s", "workloads": train}
+        "moves": "train_iters_per_s", "workloads": REPORTED_IN}
 
 
-def test_the_entry_before_it_is_as_it_was():
-    """What `test_hist_columns_per_dot.py` asserts of `per_layer[-1]`, by
-    name: that entry is no longer the last, so that test fails until a
-    `benchmark` PR makes it a lookup by name (PERF.md §7)."""
-    entry, train = declared("hist_columns_per_dot")
-    assert entry == {
-        "name": "hist_columns_per_dot", "unit": "columns",
-        "better": "higher", "source": "program_counter",
-        "layer": "histogram_kernel", "moves": "train_iters_per_s",
-        "workloads": train}
+def test_the_histogram_kernels_metrics_are_declared_by_name():
+    """The roofline over the trees' work and the step's share of the peak
+    (PR 35), in every cell that trains, the second on the `device` layer;
+    `hist_columns_per_dot` is looked up by name in its own file."""
+    cells = declared("hist_build_ms_per_iter")["workloads"]
+    assert len(cells) == 5 and set(REPORTED_IN) <= set(cells)
+    assert declared("hist_kernel_roofline") == {
+        "name": "hist_kernel_roofline", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "histogram_kernel",
+        "moves": "train_iters_per_s", "workloads": cells}
+    assert declared("train_step_mfu") == {
+        "name": "train_step_mfu", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "device",
+        "moves": "train_iters_per_s", "workloads": cells}
+    assert declared("train_step_mfu")["layer"] == \
+        declared("device_idle_share")["layer"]

@@ -31,9 +31,12 @@ NEW = {
 }
 JOINED = ("device_idle_share", "driver_host_ms_per_iter",
           "hist_build_ms_per_iter", "grow_other_ms_per_iter",
-          "hist_kernel_roofline", "hist_feature_chunks", "hist_bin_occupancy")
-NOT_JOINED = ("score_update_ms_per_iter", "partition_ms_per_iter",
-              "hist_columns_per_dot", "compile_miss_s")
+          "hist_kernel_roofline", "hist_feature_chunks", "hist_bin_occupancy",
+          # PR 35: the step's share of the peak, and the three that had been
+          # pinned to traffic `train` though the cell's traced run reports them
+          "train_step_mfu", "score_update_ms_per_iter",
+          "partition_ms_per_iter", "hist_columns_per_dot")
+NOT_JOINED = ("collective_ms_per_iter", "collective_exposed_ms_per_iter")
 
 
 # ---- what is declared ----------------------------------------------------------------

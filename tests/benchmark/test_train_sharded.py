@@ -2,7 +2,8 @@
 job refuses a program whose source cannot name the gauge of its step's
 row constants, the sharded checks read the gauges and say "not observable"
 where one is gone, the recount that decides `correct` reads a sound tree
-at 0 and a faulty one far over the cell's slack, and the readers of the collectives and of the shard's roofline on
+at 0 and a faulty one far over the cell's slack, and the readers of the
+collectives and of the histogram work's roofline with a shard's rows on
 traces built by hand (two chips, a `while` around a body, an asynchronous
 pair) and on the recorded one-chip trace, which has no collective."""
 
@@ -20,6 +21,10 @@ from tests.benchmark.test_xplane import (TRAIN_FACTS, TRAIN_WINDOW, US,
 
 JOB = load_module(harness.BENCH_DIR, "jobs", "train_sharded")
 CELL = "criteo-27m-67.train-data4"
+# what the cell's traced run on the chip reports of the three metrics that
+# were pinned to the cells of traffic `train` (my chip run, PR 35)
+ON_THE_CHIP = {"score_update_ms_per_iter", "partition_ms_per_iter",
+               "hist_columns_per_dot"}
 
 
 def snapshot(**over):
@@ -278,30 +283,43 @@ def test_what_runs_under_a_collective_is_not_exposed():
         pytest.approx(5e-3)
 
 
-def test_the_shard_roofline_counts_one_shards_rows(two_chips):
-    facts = {"iterations": 2, "rows": 4 * 65536, "features": 67, "bins": 255,
-             "data_shards": 4.0}
+def test_the_roofline_counts_one_shards_rows_over_a_chips_kernel_time(
+        two_chips):
+    """Two shards on two chips: every chip histograms half of each tree's
+    rows and builds every histogram whole, in its own kernel time (40 and
+    44 us here, so 42 a chip).  `hist_shard_roofline` was this arithmetic
+    beside a reader that counted the whole table for every chip."""
+    facts = {"iterations": 2, "rows": 2 * 65536, "features": 67, "bins": 255,
+             "data_shards": 2.0, "hist_rows_by_tree": [0, 300000, 200000],
+             "histograms_by_tree": [1, 255, 255], "first_window_tree": 1}
     run = fake_run(two_chips, (0.0, 112 * US), facts)
-    got = reader("hist_shard_roofline").read(run)
-    ops, byts = opcount.hist_contraction(65536, 67, 255, 25, 5, stat_bytes=2)
+    got = reader("hist_kernel_roofline").read(run)
+    ops, byts = opcount.tree_histogram_work(500000 / 2, 67, 255, 510)
     peak = peaks.peaks_for("TPU v5 lite")
-    want, bound = opcount.roofline(2 * ops, 2 * byts, (40 + 44) * US,
-                                   peak["bf16_flops"],
+    want, bound = opcount.roofline(ops, byts, 42 * US, peak["bf16_flops"],
                                    peak["hbm_bytes_per_s"])
-    assert got == pytest.approx(want, rel=1e-9)
-    assert run.said[-1][1]["rows_per_shard"] == 65536
-    # the whole table's rows for every chip's call: the shards' count too much
+    assert got == pytest.approx(want, rel=1e-12) and bound == "memory"
+    said = run.said[-1][1]
+    assert said["kernel_s_per_chip"] == pytest.approx(42 * US)
+    assert (said["operations"], said["bytes"]) == (ops, byts)
+    # the dense note: a chip's call contracts its shard's 65,536 rows
+    assert said["dense_contraction_mxu_share"] == pytest.approx(
+        100 * 2 * 65536 * 67 * 255 * 125 / 197e12 / (42 * US))
+    # unsharded facts on the same trace count every row for every chip
     whole = reader("hist_kernel_roofline").read(
-        fake_run(two_chips, (0.0, 112 * US), facts))
-    assert bound == "compute" and whole == pytest.approx(4 * got, rel=1e-3)
+        fake_run(two_chips, (0.0, 112 * US), dict(facts, data_shards=None)))
+    rows_part = 250000 * (67 + 8) / 819e9 / (42 * US) * 100
+    assert whole - got == pytest.approx(rows_part, rel=1e-9)
+    # and the step's share is the same work over the window, per chip
+    assert reader("train_step_mfu").read(run) == pytest.approx(
+        want * 42 / 112, rel=1e-12)
 
 
 def test_on_one_chip_and_on_the_cpu_the_readers_say_nothing():
     one = xplane.load(os.path.join(harness.BENCH_DIR, "fixtures",
                                    "v5e_train_2iters.textproto"))
     run = fake_run(one, TRAIN_WINDOW, dict(TRAIN_FACTS))
-    for name in ("collective_ms_per_iter", "collective_exposed_ms_per_iter",
-                 "hist_shard_roofline"):
+    for name in ("collective_ms_per_iter", "collective_exposed_ms_per_iter"):
         assert reader(name).read(run) is None
     cpu = xplane.Trace(ops=one.ops, modules={}, host={}, on_device=False)
     run = fake_run(cpu, TRAIN_WINDOW, dict(TRAIN_FACTS, data_shards=4.0))
@@ -318,16 +336,17 @@ def test_the_cell_and_its_metrics_are_declared():
         "criteo-27m-67", "train-data4", 4)
     has = {m["name"] for m in spec["end_to_end"] + spec["per_layer"]
            if CELL in m.get("workloads", [])}
-    assert has == {"train_iters_per_s", "device_idle_share",
+    assert has == {"train_iters_per_s", "device_idle_share", "train_step_mfu",
                    "driver_host_ms_per_iter", "hist_build_ms_per_iter",
                    "grow_other_ms_per_iter",   # `jit_grow(` runs sharded too
+                   "hist_kernel_roofline",     # a shard's rows a chip, PR 35
                    "hist_feature_chunks", "hist_bin_occupancy",
                    "collective_ms_per_iter",
-                   "collective_exposed_ms_per_iter", "hist_shard_roofline"}
-    for name in ("collective_ms_per_iter", "collective_exposed_ms_per_iter",
-                 "hist_shard_roofline"):
+                   "collective_exposed_ms_per_iter"} | ON_THE_CHIP
+    for name in ("collective_ms_per_iter", "collective_exposed_ms_per_iter"):
         m = next(m for m in spec["per_layer"] if m["name"] == name)
         assert m["workloads"] == [CELL] and m["moves"] == "train_iters_per_s"
+    assert "hist_shard_roofline" not in {m["name"] for m in spec["per_layer"]}
     conf = harness.load_json(os.path.join(
         harness.BENCH_DIR, "configs", "criteo-27m-67.json"))
     assert conf["data"]["rows"] == 4 * 1_700_000_000 // 128 == 53_125_000

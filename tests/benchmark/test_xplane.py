@@ -159,39 +159,129 @@ def test_recorded_trace_reduces_to_the_figures_counted_by_hand(v5e_train):
         (8534230 - 6030982) * 1e-6 / 2, rel=1e-9)
 
 
-def test_roofline_share_of_the_recorded_kernel(v5e_train):
-    run = fake_run(v5e_train, TRAIN_WINDOW, TRAIN_FACTS)
-    # 30 calls, each one slot x five planes: 2*65536*28*63*5 operations,
-    # far more MXU time than HBM time, over 6.030982 ms of kernel time
-    want = 100 * (30 * 2 * 65536 * 28 * 63 * 5 / 197e12) / 6030982e-9
-    assert reader("hist_kernel_roofline").read(run) == pytest.approx(want)
-    assert want == pytest.approx(2.919, rel=1e-3)
-    assert run.said[0][1]["bound"] == "compute"
+# ---- the histogram work's two shares, on the recorded kernel's time ---------------
+# three trees of 15 leaves; the window's two iterations grew trees 1 and 2,
+# which histogram 150,000 + 140,000 rows in 15 + 15 histograms
+HIST_FACTS = dict(TRAIN_FACTS, hist_rows_by_tree=[170000, 150000, 140000],
+                  histograms_by_tree=[15, 15, 15], first_window_tree=1)
+HIST_BYTES = 290000 * (28 + 8) + 30 * 28 * 63 * 12
+KERNEL_S = 6030982e-9
 
 
-def test_roofline_needs_published_peaks(v5e_train):
-    run = fake_run(v5e_train, TRAIN_WINDOW, TRAIN_FACTS, kind="TPU v9")
+def test_roofline_share_is_the_trees_work_over_the_kernels_time(v5e_train):
+    run = fake_run(v5e_train, TRAIN_WINDOW, dict(HIST_FACTS))
+    # 3 x 290,000 x 28 additions are 0.12 us of the MXU's peak, the bytes
+    # 13.5 us of HBM: memory-bound, over 6.030982 ms of kernel time
+    want = 100 * (HIST_BYTES / 819e9) / KERNEL_S
+    assert reader("hist_kernel_roofline").read(run) == pytest.approx(
+        want, rel=1e-12)
+    assert want == pytest.approx(0.22422, rel=1e-4)
+    what, said = run.said[0]
+    assert what == "hist_kernel_roofline" and said["bound"] == "memory"
+    assert (said["operations"], said["bytes"]) == (3 * 290000 * 28, HIST_BYTES)
+    assert said["kernel_s_per_chip"] == pytest.approx(KERNEL_S, rel=1e-9)
+    # the note beside it: 30 calls of one slot x five planes as the dense
+    # contraction they were built as, 2*65536*28*63*5 operations each
+    assert said["dense_contraction_mxu_share"] == pytest.approx(
+        100 * (30 * 2 * 65536 * 28 * 63 * 5 / 197e12) / KERNEL_S)
+    assert said["dense_contraction_mxu_share"] == pytest.approx(2.919, rel=1e-3)
+
+
+def another_kernel(trace, rename, speed_up):
+    """`trace` with every `%hist_*` call renamed and `speed_up` times
+    shorter: what a later kernel's trace would hold."""
+    ev = trace.ops[0]
+    mine = np.array([n.startswith("%hist_") for n in ev.names])
+    ops = xplane.Events([rename(n) if m else n for n, m in zip(ev.names, mine)],
+                        ev.start, np.where(mine, ev.dur / speed_up, ev.dur))
+    return xplane.Trace({0: ops}, trace.modules, trace.host, True)
+
+
+OTHER_SIGNATURES = {
+    # a prefetched count before the operands, a second output
+    "a prefetched count and a second output": lambda n: n.replace(
+        " custom-call(", " custom-call(s32[8]{0} %live, ").replace(
+            " = f32[", " = (s32[8]{0}, f32[", 1),
+    # a compaction pass of the layer, named as the layer's passes are
+    "a pass named hist_pack": lambda n: n.replace("%hist_build", "%hist_pack"),
+}
+
+
+@pytest.mark.parametrize("how", sorted(OTHER_SIGNATURES))
+def test_another_operand_list_at_half_the_time_reads_twice_the_share(
+        v5e_train, how):
+    """What the re-basing is for: the share does not depend on the call's
+    operands or on rows x slots, so a kernel that does the same trees' work
+    in half the time reads twice the share, not `None` and not over 100."""
+    base = reader("hist_kernel_roofline").read(
+        fake_run(v5e_train, TRAIN_WINDOW, dict(HIST_FACTS)))
+    other = another_kernel(v5e_train, OTHER_SIGNATURES[how], 2.0)
+    assert not any(n.startswith("%hist_") and "[124," in n.split("(")[0]
+                   for n in other.ops[0].names)
+    run = fake_run(other, TRAIN_WINDOW, dict(HIST_FACTS))
+    # twice to the last digits (the window's clip adds start and duration)
+    assert reader("hist_kernel_roofline").read(run) == pytest.approx(
+        2 * base, rel=1e-12)
+    assert reader("hist_build_ms_per_iter").read(run) == pytest.approx(
+        1e3 * KERNEL_S / 2 / 2, rel=1e-9)
+    if "second output" in how:
+        # the dense note has nothing it can read there, and says so
+        assert run.said[0][1]["dense_contraction_mxu_share"] is None
+
+
+def test_two_shards_halve_the_rows_and_keep_the_histograms_whole(v5e_train):
+    run = fake_run(v5e_train, TRAIN_WINDOW, dict(HIST_FACTS, data_shards=2.0))
+    byts = 145000 * (28 + 8) + 30 * 28 * 63 * 12
+    assert reader("hist_kernel_roofline").read(run) == pytest.approx(
+        100 * (byts / 819e9) / KERNEL_S, rel=1e-12)
+    assert run.said[0][1]["operations"] == 3 * 145000 * 28
+
+
+@pytest.mark.parametrize("facts", [
+    dict(TRAIN_FACTS),                                   # a job that states none
+    dict(HIST_FACTS, hist_rows_by_tree=[]),              # no tree parsed
+    dict(HIST_FACTS, first_window_tree=3),               # none in the window
+], ids=["no facts", "no trees", "no tree in the window"])
+def test_without_the_trees_work_the_two_shares_say_nothing(v5e_train, facts):
+    run = fake_run(v5e_train, TRAIN_WINDOW, facts)
+    assert reader("hist_kernel_roofline").read(run) is None
+    assert reader("train_step_mfu").read(run) is None
+    assert run.said == []
+
+
+def test_without_kernel_events_the_roofline_says_nothing(v5e_train):
+    none = another_kernel(v5e_train, lambda n: n.replace("%hist_", "%other_"),
+                          1.0)
+    run = fake_run(none, TRAIN_WINDOW, dict(HIST_FACTS))
+    assert reader("hist_build_ms_per_iter").read(run) is None
+    assert reader("hist_kernel_roofline").read(run) is None
+    # the step's share needs no kernel: it is what bounds a step without one
+    assert reader("train_step_mfu").read(run) is not None
+
+
+@pytest.mark.parametrize("name", ["hist_kernel_roofline", "train_step_mfu"])
+def test_roofline_needs_published_peaks(v5e_train, name):
+    run = fake_run(v5e_train, TRAIN_WINDOW, dict(HIST_FACTS), kind="TPU v9")
     with pytest.raises(KeyError, match="no published peaks"):
-        reader("hist_kernel_roofline").read(run)
+        reader(name).read(run)
 
 
-@pytest.mark.parametrize("text, want", [
-    ("%hist_build.16 = f32[8192,125]{1,0} custom-call(u8[124,32,8192]{2,1,0} "
-     "%a, bf16[124,5,8192]{2,1,0} %b, s32[124,1,8192]{2,1,0} %c, s32[25,1]"
-     "{1,0} %d), custom_call_target=\"tpu_custom_call\", operand_layout_"
-     "constraints={u8[124,32,8192]{2,1,0}}", (25, 5, 2)),
-    ("%hist_build.2 = s32[8192,48]{1,0} custom-call(u8[4,32,8192]{2,1,0} %a, "
-     "s8[4,3,8192]{2,1,0} %b, s32[4,1,8192]{2,1,0} %c, s32[16,1]{1,0} %d), "
-     "custom_call_target=\"tpu_custom_call\"", (16, 3, 1)),
-    # output columns that are not slots x planes: not the call we know
-    ("%hist_build.2 = f32[8192,50]{1,0} custom-call(u8[4,32,8192]{2,1,0} %a, "
-     "bf16[4,5,8192]{2,1,0} %b, s32[4,1,8192]{2,1,0} %c, s32[16,1]{1,0} %d), "
-     "custom_call_target=\"tpu_custom_call\"", None),
-    ("%hist_build.5 = f32[32,256,3]{2,1,0} fusion(f32[3,8192]{1,0} %x), "
-     "kind=kOutput", None),
-])
-def test_kernel_call_shape_is_read_from_the_instruction(text, want):
-    assert reader("hist_kernel_roofline").call_shape(text) == want
+def test_step_mfu_is_the_same_work_over_the_window(v5e_train):
+    """A stated window of 9.747290 ms: the least time for the trees' work
+    over it, under the kernel's share by the kernel's part of the window."""
+    window_s = TRAIN_WINDOW[1] - TRAIN_WINDOW[0]
+    run = fake_run(v5e_train, TRAIN_WINDOW, dict(HIST_FACTS))
+    got = reader("train_step_mfu").read(run)
+    assert got == pytest.approx(100 * (HIST_BYTES / 819e9) / window_s,
+                                rel=1e-12)
+    assert got == pytest.approx(
+        reader("hist_kernel_roofline").read(run) * KERNEL_S / window_s)
+    half = (TRAIN_WINDOW[0], TRAIN_WINDOW[0] + window_s / 2)
+    assert reader("train_step_mfu").read(
+        fake_run(v5e_train, half, dict(HIST_FACTS))) == pytest.approx(2 * got)
+    cpu = xplane.Trace(v5e_train.ops, {}, {}, on_device=False)
+    assert reader("train_step_mfu").read(
+        fake_run(cpu, TRAIN_WINDOW, dict(HIST_FACTS))) is None
 
 
 def test_recorded_predict_call(v5e_predict):

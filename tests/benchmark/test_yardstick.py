@@ -29,6 +29,58 @@ def test_hist_contraction_int8_planes():
     assert byts == 8 * (2 + 3 + 4) + 2 * 4 * 3 * 4
 
 
+@pytest.mark.parametrize("hist_rows, features, bins, histograms, bin_bytes, "
+                         "want_ops, want_bytes", [
+    # 155 histogrammed rows of 28 one-byte bins: three additions a row and
+    # column; a row's 28 bins and its float32 gradient and hessian read
+    # once; 4 histograms of 28 x 255 bins in three float32 planes written
+    (155, 28, 255, 4, 1, 3 * 155 * 28, 155 * 36 + 4 * 28 * 255 * 12),
+    # Higgs at 255 bins as ISSUE 35 reckons it: 3.9 n rows, 255 histograms
+    (3.9 * 27262976, 28, 255, 255, 1, 3 * 3.9 * 27262976 * 28,
+     3.9 * 27262976 * 36 + 255 * 28 * 255 * 12),
+    (1000, 67, 63, 2, 2, 201000, 1000 * (134 + 8) + 2 * 67 * 63 * 12),
+    (0, 28, 255, 1, 1, 0, 28 * 255 * 12),     # a stump no row was counted for
+])
+def test_tree_histogram_work_against_hand_counts(
+        hist_rows, features, bins, histograms, bin_bytes, want_ops,
+        want_bytes):
+    assert opcount.tree_histogram_work(
+        hist_rows, features, bins, histograms, bin_bytes) == (
+            pytest.approx(want_ops, rel=1e-15),
+            pytest.approx(want_bytes, rel=1e-15))
+
+
+def test_the_issues_reckoning_of_higgs_at_255_bins():
+    """3.85e9 bytes, 4.70 ms of HBM, memory-bound: what `PERF.md` predicts
+    the two shares from."""
+    ops, byts = opcount.tree_histogram_work(3.9 * 27262976, 28, 255, 255)
+    p = peaks.peaks_for("TPU v5 lite")
+    assert byts == pytest.approx(3.85e9, rel=2e-3)
+    share, bound = opcount.roofline(ops, byts, 4.3098, p["bf16_flops"],
+                                    p["hbm_bytes_per_s"])
+    assert bound == "memory" and share == pytest.approx(0.109, rel=5e-3)
+
+
+WINDOW_FACTS = {"hist_rows_by_tree": [400, 300, 200, 100],
+                "histograms_by_tree": [4, 3, 2, 1], "first_window_tree": 1,
+                "iterations": 2, "features": 5, "bins": 7}
+
+
+@pytest.mark.parametrize("over, want", [
+    ({}, opcount.tree_histogram_work(500, 5, 7, 5)),       # trees 1 and 2
+    ({"data_shards": 4.0}, opcount.tree_histogram_work(125, 5, 7, 5)),
+    ({"data_shards": None}, opcount.tree_histogram_work(500, 5, 7, 5)),
+    # fewer trees than iterations (an iteration failed): those that are there
+    ({"iterations": 9}, opcount.tree_histogram_work(600, 5, 7, 6)),
+    ({"first_window_tree": 4}, None),
+    ({"hist_rows_by_tree": []}, None),
+    ({"hist_rows_by_tree": None}, None),
+])
+def test_the_windows_work_is_its_own_trees_a_shards_share_of_the_rows(
+        over, want):
+    assert opcount.window_histogram_work({**WINDOW_FACTS, **over}) == want
+
+
 def test_forest_walk_against_hand_counts():
     ops, byts = opcount.forest_walk(rows=10, trees=3, depth=4, features=28)
     assert ops == 10 * 3 * 4 * 4
@@ -183,6 +235,54 @@ def test_zero_as_missing_takes_the_default_side():
 def test_a_stump_of_one_leaf():
     stump = {"num_leaves": 1, "leaf_value": np.array([0.7])}
     assert reference.walk([stump], np.zeros((3, 2))) == pytest.approx([0.7] * 3)
+
+
+def four_leaves():
+    """Split 0 parts 100 rows 40 / 60; split 1 parts the 40 into leaves of
+    10 and 30, split 2 the 60 into leaves of 5 and 55."""
+    return {"num_leaves": 4, "left_child": np.array([1, -1, -2]),
+            "right_child": np.array([2, -3, -4]),
+            "leaf_count": np.array([10, 5, 30, 55])}
+
+
+def test_histogrammed_rows_of_a_hand_built_tree():
+    tree = four_leaves()
+    assert reference.child_counts(tree).tolist() == [[40, 10, 5], [60, 30, 55]]
+    # the root's 100, then the smaller child of each split: 40, 10, 5
+    assert reference.histogrammed_rows(tree) == (155, 4)
+    # under the most a tree of two levels can: 100 * (1 + 2 / 2)
+    assert 155 <= 100 * (1 + reference.depth(tree) / 2)
+    # the hand tree of the walker's tests: 5 rows, then min(2, 3), min(2, 1)
+    assert reference.histogrammed_rows(hand_tree()) == (5 + 2 + 1, 3)
+    # a chain, as min_data_in_leaf=1 grows them: every split takes one row off
+    chain = {"num_leaves": 4, "left_child": np.array([-1, -2, -3]),
+             "right_child": np.array([1, 2, -4]),
+             "leaf_count": np.array([1, 1, 1, 97])}
+    assert reference.histogrammed_rows(chain) == (100 + 1 + 1 + 1, 4)
+
+
+@pytest.mark.parametrize("stump, want", [
+    ({"num_leaves": 1, "leaf_value": np.array([0.7])}, (0, 1)),
+    ({"num_leaves": 1, "leaf_count": np.array([9])}, (9, 1)),
+])
+def test_histogrammed_rows_of_a_stump(stump, want):
+    assert reference.histogrammed_rows(stump) == want
+
+
+def test_a_tree_numbered_child_first_is_refused():
+    tree = four_leaves()
+    tree["left_child"] = np.array([1, -1, -2])
+    tree["right_child"] = np.array([2, -3, 1])      # split 2's child is split 1
+    with pytest.raises(reference.ModelTextError, match="before the split"):
+        reference.histogrammed_rows(tree)
+
+
+def test_the_facts_a_training_job_states_of_its_trees():
+    trees = reference.parse_model(MODEL_TEXT)
+    assert reference.window_histogram_facts(trees, 1) == {
+        "hist_rows_by_tree": [5 + 2 + 1, 5 + 2], "histograms_by_tree": [3, 2],
+        "first_window_tree": 1}
+    assert reference.window_histogram_facts([], 1)["hist_rows_by_tree"] == []
 
 
 def test_first_tree_recount():
