@@ -670,7 +670,7 @@ class GBDT:
                     self.iter_ % bag["freq"] == 0
                     or self._force_bag_refresh)
                 (records, scores, leaf_ids, leaf_out, self._key,
-                 self._bag_key, pool) = self._train_step(
+                 self._bag_key, pool, hist_rows) = self._train_step(
                     base_scores, self.train_scores.scores,
                     self._key, self._bag_key, pool, k, refresh, **extra)
                 self.train_scores.scores = scores
@@ -686,7 +686,7 @@ class GBDT:
                 self._pending.append((
                     records,
                     leaf_out if self.learner.refits_leaves else None,
-                    k, inits[k]))
+                    k, inits[k], hist_rows))
             self.iter_ += 1
         return False
 
@@ -1124,11 +1124,13 @@ class GBDT:
         pending, self._pending = self._pending, []
         # one batched fetch for all pending trees (None leaf-out entries
         # are empty pytrees and fetch as None)
-        fetched = jax.device_get([(p[0], p[1]) for p in pending])
+        fetched = jax.device_get([(p[0], p[1], p[4]) for p in pending])
         meta = self.learner.meta_np
-        for (_, _, class_id, init), (rec, leaf_out) in zip(pending, fetched):
+        for (_, _, class_id, init, _), (rec, leaf_out, hist_rows) in zip(
+                pending, fetched):
             if self._stopped:
                 break  # drop queued post-stall iterations (reference pops them)
+            self.learner.note_hist_rows(hist_rows)
             tree = self.learner.build_tree_from_records(
                 np.asarray(rec),
                 None if leaf_out is None else np.asarray(leaf_out))

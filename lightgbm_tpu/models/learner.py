@@ -21,10 +21,12 @@ from .. import obs
 from ..config import Config
 from ..io.bin_mapper import MissingType
 from ..io.dataset import TrainingData
-from ..ops.grower import (GrowerParams, canonical_params, mode_flags_np,
-                          pad_rows, pool_dtype, resolve_split_batch)
+from ..ops.grower import (HIST_ROWS_CALLS, HIST_ROWS_CONTRACTED,
+                          HIST_ROWS_LIVE, GrowerParams, canonical_params,
+                          mode_flags_np, pad_rows, pool_dtype,
+                          resolve_split_batch, row_blocks)
 from ..ops.histogram import (hashed_uniform, key_words, perfeature_chunks,
-                             perfeature_columns_per_dot)
+                             perfeature_columns_per_dot, perfeature_dot_lanes)
 from ..ops.lookup import lookup
 from ..ops.partition import partition_kernel_fits
 from ..parallel.mesh import (exchange_bytes_per_tree, put_global, put_local,
@@ -950,6 +952,9 @@ class TPUTreeLearner:
         self._feature_rng = np.random.default_rng(int(config.feature_fraction_seed))
         self._note_exchange()
         self._note_partition()
+        # running sums of the trees' `hist_rows` over the shards, and trees
+        self._hist_rows = np.zeros(3, np.int64)
+        self._hist_trees = 0
 
     def reset_pool(self) -> None:
         """(Re)create the donated histogram-pool buffer as zeros.
@@ -1104,6 +1109,31 @@ class TPUTreeLearner:
                 help="sweeps of the leaf ids the row partition makes per "
                      "tree when every round splits all it can (0: the "
                      "lowering is not in force)")
+
+    def note_hist_rows(self, hist_rows) -> None:
+        """`lgbm_hist_rows_per_tree{kind=}` from one more tree's fetched
+        `hist_rows` (a row a shard, `ops/grower.py` HIST_ROWS_*), the mean
+        over the trees this learner has grown, summed over the shards:
+        rows its histogram calls swept (calls x a shard's padded rows),
+        rows they contracted (sub-blocks run x their rows) and rows that
+        were live (their leaf one of a call's slots).  Set where the host
+        reads a tree, from what came with its records."""
+        self._hist_rows += np.asarray(hist_rows, np.int64).sum(axis=0)
+        self._hist_trees += 1
+        shard_rows = self.n_pad // self.d_shards
+        block, _ = row_blocks(shard_rows, self.params.block_rows)
+        per_unit = {"swept": (HIST_ROWS_CALLS, shard_rows),
+                    "contracted": (HIST_ROWS_CONTRACTED,
+                                   perfeature_dot_lanes(block)),
+                    "live": (HIST_ROWS_LIVE, 1)}
+        for kind, (column, rows) in per_unit.items():
+            obs.REGISTRY.set_gauge(
+                "lgbm_hist_rows_per_tree",
+                int(self._hist_rows[column]) * rows / self._hist_trees,
+                kind=kind,
+                help="table rows per tree, all shards: swept by the "
+                     "histogram calls, contracted by them, and live (in a "
+                     "leaf the call histograms)")
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1517,7 +1547,8 @@ class TPUTreeLearner:
             new_scores = post_j(scores, out["records"], ids,
                                 out["leaf_output"], class_id=class_id)
             return (out["records"], new_scores, out["leaf_ids"],
-                    out["leaf_output"], key, bag_key, pool)
+                    out["leaf_output"], key, bag_key, pool,
+                    out["hist_rows"])
 
         self._note_row_constants(pre_j, gather_j, post_j, rows,
                                  objective.num_model_per_iteration(),
@@ -1650,12 +1681,14 @@ class TPUTreeLearner:
 
     def build_tree(self, out: Dict) -> Tree:
         """Replay device split records into a reference-compatible Tree."""
-        fetch = [out["records"]]
+        fetch = [out["records"], out.get("hist_rows")]
         if self.refits_leaves:
             fetch.append(out["leaf_output"])
         got = jax.device_get(fetch)  # one fetch
         rec = np.asarray(got[0])
-        leaf_out = np.asarray(got[1]) if self.refits_leaves else None
+        if got[1] is not None:
+            self.note_hist_rows(got[1])
+        leaf_out = np.asarray(got[2]) if self.refits_leaves else None
         return self.build_tree_from_records(rec, leaf_out)
 
     def build_tree_from_records(self, rec: np.ndarray,

@@ -101,7 +101,8 @@ import numpy as np
 from ..utils.compile_ledger import ledger_jit
 from .histogram import (build_histogram_batched_t, build_histogram_sparse,
                         build_histogram_t, key_words, pack_stats,
-                        quant_limit, quantize_values, unpack2d)
+                        perfeature_dot_lanes, quant_limit, quantize_values,
+                        unpack2d)
 from .partition import partition_rows
 from .split import (K_MIN_SCORE, SplitResult, argbest, finalize_split,
                     go_right_scalars, leaf_output, leaf_split_gain,
@@ -581,9 +582,7 @@ def _build_grower(params, num_features, data_axis, feature_axis,
         # rows come from grad, NOT bins_t: with packed (4-bit) storage the
         # bin matrix holds two rows per byte
         n_pad = grad.shape[0]
-        block = min(params.block_rows, n_pad)
-        nb = max(n_pad // block, 1)
-        block = n_pad // nb
+        block, nb = row_blocks(n_pad, params.block_rows)
         bcols = block // 2 if params.packed_bins else block
 
         if feature_axis:
@@ -1042,16 +1041,20 @@ def _build_grower(params, num_features, data_axis, feature_axis,
                 # compiles anyway: the xla scan at pallas-sized short
                 # blocks would round-trip a materialized one-hot per
                 # block through HBM
-                root_local = build_histogram_batched_t(
+                root_local, root_rows = build_histogram_batched_t(
                     bins_blocks, stats_blocks,
                     jnp.zeros((nb, block), jnp.int32),
                     jnp.zeros(1, jnp.int32), B,
                     precision, impl=params.hist_impl,
                     packed_rows=params.packed_bins,
-                    live_columns=live_columns)[0]
+                    live_columns=live_columns, with_rows=True)
+                root_local = root_local[0]
             else:
                 root_local = build_histogram_t(bins_blocks, stats_blocks,
                                                B, precision)
+                root_rows = jnp.array(
+                    [1, n_pad // perfeature_dot_lanes(block), n_pad],
+                    jnp.uint32)
         if params.has_sparse:
             root_local = merge_sparse_hist(
                 root_local[None], jnp.zeros(n_pad, jnp.int32),
@@ -1152,6 +1155,9 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             # write stays in bounds; trimmed to [L-1] on return
             "records": jnp.zeros((L - 1 + K, RW), jnp.float32),
             "n_splits": jnp.int32(0),
+            # what the tree's histogram calls did with this shard's rows
+            # (HIST_ROWS_*): calls, sub-blocks contracted, live rows
+            "hist_rows": root_rows,
         }
         if bynode:
             state["key"] = key
@@ -1310,13 +1316,14 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             # split_search) appear inside xprof device traces too —
             # trace-time metadata, zero runtime cost
             with jax.named_scope("hist_build"):
-                h_local = build_histogram_batched_t(
+                h_local, call_rows = build_histogram_batched_t(
                     bins_blocks, stats_blocks,
                     leaf_ids.reshape(nb, block),
                     smaller_ids, B, precision,
                     impl=params.hist_impl,
                     packed_rows=params.packed_bins,
-                    live_columns=live_columns)           # [K, F, B, 3]
+                    live_columns=live_columns,
+                    with_rows=True)                      # [K, F, B, 3]
                 h_local = merge_sparse_hist(h_local, leaf_ids,
                                             smaller_ids)
                 if sparse_tot:
@@ -1449,6 +1456,7 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             new_state["records"] = jax.lax.dynamic_update_slice(
                 state["records"], rec, (state["n_splits"], jnp.int32(0)))
             new_state["n_splits"] = state["n_splits"] + num_do
+            new_state["hist_rows"] = state["hist_rows"] + call_rows
             return new_state
 
         def body(state, round_k=None):
@@ -1614,6 +1622,8 @@ def _build_grower(params, num_features, data_axis, feature_axis,
             "leaf_output": state["leaf_output"],
             "leaf_cnt": state["leaf_cnt"],
             "leaf_sum_h": state["leaf_sum_h"],
+            # [1, 3]: a row per shard once shard_map stacks them
+            "hist_rows": state["hist_rows"][None],
         }
         if external_pool:
             # the (donated, in-place) pool rides back to the caller so
@@ -1654,6 +1664,18 @@ REC_LEAF, REC_FEATURE, REC_THRESHOLD, REC_DEFAULT_LEFT, REC_GAIN, \
     REC_INTERNAL_WEIGHT, REC_INTERNAL_COUNT, REC_DID_SPLIT, \
     REC_IS_CAT = range(16)
 REC_WIDTH = 16  # categorical mask starts at REC_WIDTH
+# columns of the grower's `hist_rows` output, uint32 per shard: histogram
+# calls of the tree (each sweeps the shard's padded rows), the sub-blocks of
+# `histogram.perfeature_dot_lanes(block)` rows they contracted, and the rows
+# they found live (leaf one of the call's slots)
+HIST_ROWS_CALLS, HIST_ROWS_CONTRACTED, HIST_ROWS_LIVE = range(3)
+
+
+def row_blocks(n_pad: int, block_rows: int) -> Tuple[int, int]:
+    """(rows of a histogram block, blocks) the grower cuts `n_pad` padded
+    rows of a shard into."""
+    nb = max(n_pad // min(block_rows, n_pad), 1)
+    return n_pad // nb, nb
 
 
 def pad_rows(n: int, block_rows: int) -> int:

@@ -36,6 +36,7 @@ Precision modes (`tpu_hist_precision`):
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -283,8 +284,8 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
                               precision: str = "hilo",
                               impl: str = "xla",
                               packed_rows: bool = False,
-                              live_columns: Optional[int] = None
-                              ) -> jnp.ndarray:
+                              live_columns: Optional[int] = None,
+                              with_rows: bool = False):
     """Transposed-layout batched histogram: rows on the lane axis.
 
     Histograms of K leaves in ONE contraction: the single-leaf formulation
@@ -315,13 +316,19 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
         and returns exact zeros for the padding; "xla" contracts every
         column, so padding comes back as whatever its bins say (all rows in
         bin 0).
-    Returns [K, F, B, 3] f32.
+    with_rows: also return what the call did with the table's rows, [3]
+        uint32 (`_call_rows`): 1, the call, which swept every row; the
+        sub-blocks of `perfeature_dot_lanes(block)` rows it contracted; the
+        rows it found live (their leaf one of the slots).  "xla" contracts
+        every row; "pallas2" says (`_hist_pallas`).
+    Returns [K, F, B, 3] f32, with `with_rows` a pair of that and the rows.
     """
     if impl == "pallas2":
-        return _hist_pallas(
+        hist, rows = _hist_pallas(
             bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             num_bins, precision,
             packed_rows=packed_rows, live_columns=live_columns)
+        return (hist, rows) if with_rows else hist
     if impl != "xla":
         raise ValueError(f"unknown histogram impl {impl!r}")
     if packed_rows:
@@ -352,7 +359,19 @@ def build_histogram_batched_t(bins_t_blocks, stats_blocks, leaf_blocks,
     raw = jnp.transpose(
         raw.reshape(num_features * num_bins, K, S), (1, 2, 0))
     hist = jax.vmap(lambda r: _unpack_hist(r, precision))(raw)
-    return hist.reshape(K, num_features, num_bins, 3)
+    hist = hist.reshape(K, num_features, num_bins, 3)
+    if not with_rows:
+        return hist
+    live = jnp.any(slot_leaf_ids[:, None, None] == leaf_blocks[None], axis=0)
+    return hist, _call_rows(nb * (block // perfeature_dot_lanes(block)), live)
+
+
+def _call_rows(contracted, live) -> jnp.ndarray:
+    """[3] uint32 of one histogram call, which a tree's calls sum to its
+    `ops/grower.py` HIST_ROWS_* columns: 1 (the call), the sub-blocks it
+    contracted and its live rows, each a count or per-block counts."""
+    return jnp.stack([1, jnp.sum(contracted),
+                      jnp.sum(live, dtype=jnp.int32)]).astype(jnp.uint32)
 
 
 def build_histogram_sparse(sidx: jnp.ndarray, sbin: jnp.ndarray,
@@ -508,6 +527,11 @@ def perfeature_columns_per_dot(num_bins: int, block: int, precision: str,
     return max(1, min(fits, _PERFEATURE_GROUP_COLUMNS, columns, live))
 
 
+# the leaf id of a lane that holds no row, in a block the perfeature kernel
+# has packed: no slot's, a dead one's (-1) included
+_NO_SLOT = -2
+
+
 def pallas_interpret() -> bool:
     """Whether `pallas_call` runs its kernels in interpret mode — the ONE
     place that is decided: compiled by Mosaic whenever the platform is
@@ -572,6 +596,24 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     that the learner's gauge calls too; where it answers 1 the kernel is
     the ungrouped one: a [Bp, blk] one-hot and a dot per column over the
     whole block, the first block's dot storing.
+
+    Rows: a row carries anything into the call only where its leaf is one
+    of the K slots (a live row); after the root call a fifth of a tree's
+    rows are.  The grouped kernel over lane sub-blocks therefore copies a
+    block to VMEM scratch, left-packs its live rows there (bins as 32-bit
+    words, the stat planes' bit patterns and the leaf ids, moved together
+    and in order: `left_pack`) and runs its dots only over the sub-blocks
+    that then hold a row.  A block with no live row does no dot; a block
+    that packing would not shorten by a sub-block (every block of the root
+    call) is swept as it came.  A dead row added an exact zero, so the sums
+    are the same; only which rows share a sub-block's f32 partial sum
+    moves.  The other two arms (G = 1, 4-bit rows) sweep every row.  What
+    a block's trip count follows is its own live count, which the kernel
+    also writes out, one int32 a block.
+
+    Returns the [K, F, B, 3] histograms and the call's `_call_rows`: the
+    call, the sub-blocks (of `perfeature_dot_lanes(block)` rows) it
+    contracted, and its live rows.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -602,11 +644,9 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         dot_prec = (jax.lax.Precision.HIGHEST if precision == "f32"
                     else jax.lax.Precision.DEFAULT)
 
-    def expand_slots(stats_ref, leaf_ref, slots_ref, at=slice(None)):
-        """[K*S, lanes] per-slot stats of the block's rows `at`: slot
-        one-hot x packed stat rows."""
-        s = stats_ref[0, :, at]                 # [S, lanes]
-        l = leaf_ref[0, :, at]                  # [1, lanes] i32
+    def expand_slots(s, l, slots_ref):
+        """[K*S, lanes] per-slot stats of rows with packed stat rows `s`
+        [S, lanes] and leaf ids `l` [1, lanes]: slot one-hot x stats."""
         slots = slots_ref[:]                    # [K, 1] i32
         hit = slots == l                                    # [K, lanes]
         if precision in _INT_STAT_DTYPES:
@@ -621,6 +661,12 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                     * s[None, :, :].astype(dot_dtype))
         return sexp.reshape(K * S, s.shape[-1])
 
+    def live_lanes(leaf_ref, slots_ref):
+        """[1, block] int32, 1 where a row's leaf is one of the call's
+        slots: the rows that carry anything into a histogram."""
+        hit = slots_ref[:] == leaf_ref[0]                   # [K, block]
+        return jnp.max(hit.astype(jnp.int32), axis=0, keepdims=True)
+
     def accumulate(i, out_ref, rows, acc):
         @pl.when(i == 0)
         def _():
@@ -630,7 +676,7 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         def _():
             out_ref[rows, :] += acc
 
-    def kernel_perfeature_chunk(fblk, nf, G, lanes):
+    def kernel_perfeature_chunk(fblk, nf, G, lanes, compacts, narrow, meta):
         # position f holds a live column in chunks 0..last(f), which only
         # falls as f rises: a run is the adjacent positions live in the same
         # chunks, under one guard, a group up to G adjacent positions of a run
@@ -643,23 +689,31 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                 for last, fs in positions.items()]
 
         def kernel(bins_ref, stats_ref, leaf_ref, slots_ref, out_ref,
-                   *scratch):
+                   live_ref, *scratch):
             fi = pl.program_id(0)  # feature-chunk axis
             i = pl.program_id(1)   # row-block axis (innermost)
+            live_row = live_lanes(leaf_ref, slots_ref)
+            n_live = jnp.sum(live_row)
+            live_ref[i] = n_live
 
-            def sweep(at, land):
-                """Every live column's dot over the block's rows `at`;
-                `land(rows, acc)` takes a dot's result for those rows of
-                the accumulator."""
-                sexp = expand_slots(stats_ref, leaf_ref, slots_ref, at)
+            def each_run(do):
+                """`do(groups)` for every run live in this chunk."""
+                for last, groups in runs:
+                    if last >= nf - 1:
+                        do(groups)
+                    else:
+                        pl.when(fi <= last)(functools.partial(do, groups))
+
+            def sweep(column, s, l, land):
+                """Every live column's dot over `lanes` rows: `column(f)`
+                their bins in column f, `s` their stat planes, `l` their
+                leaf ids; `land(rows, acc)` takes a dot's result for those
+                rows of the accumulator."""
+                sexp = expand_slots(s, l, slots_ref)
                 iota_b = jax.lax.broadcasted_iota(jnp.int32, (Bp, lanes), 0)
 
                 def onehot_of(f):
-                    if packed_rows:
-                        b_f = unpack2d(bins_ref[0, f])              # [blk]
-                    else:
-                        b_f = bins_ref[0, f, at].astype(jnp.int32)  # [lanes]
-                    return (b_f[None, :] == iota_b).astype(dot_dtype)
+                    return (column(f)[None, :] == iota_b).astype(dot_dtype)
 
                 def contract(f, g):
                     if G == 1:
@@ -678,14 +732,20 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                         precision=dot_prec,
                         preferred_element_type=acc_dtype))
 
-                for last, groups in runs:
-                    def run(groups=groups):
-                        for f, g in groups:
-                            contract(f, g)
-                    if last >= nf - 1:
-                        run()
-                    else:
-                        pl.when(fi <= last)(run)
+                def run(groups):
+                    for f, g in groups:
+                        contract(f, g)
+                each_run(run)
+
+            def sweep_block(land):
+                """The block as it came, every row of it."""
+                if packed_rows:
+                    def column(f):
+                        return unpack2d(bins_ref[0, f])
+                else:
+                    def column(f):
+                        return bins_ref[0, f, :].astype(jnp.int32)
+                sweep(column, stats_ref[0], leaf_ref[0], land)
 
             def zero_from_first_block(cond, rows):
                 @pl.when(cond & (i == 0))
@@ -702,37 +762,148 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                             fi > last, slice(fs[0] * Bp, (fs[-1] + 1) * Bp))
                 if live < fblk:  # positions that are padding in every chunk
                     zero_from_first_block(True, slice(live * Bp, fblk * Bp))
-                sweep(slice(None), lambda rows, acc: accumulate(
+                sweep_block(lambda rows, acc: accumulate(
                     i, out_ref, rows, acc))
                 return
             # every dot adds: dead and padding rows stay as zeroed
             zero_from_first_block(True, slice(0, fblk * Bp))
-            if lanes == block:
+            if not compacts:
                 def add(rows, acc):
                     out_ref[rows, :] += acc
-                sweep(slice(None), add)
+                sweep_block(add)
                 return
+            part, packed = scratch[1], scratch[4]
+            as_stored = packed.bitcast(bins_t_blocks.dtype) if narrow \
+                else packed
+            stats_rows, leaf_row = slice(meta, meta + S), slice(
+                meta + S, meta + S + 1)
+
+            def stage():
+                """The block's rows into `packed`, as they came."""
+                if narrow:
+                    packed[0:meta, :] = bins_ref.bitcast(jnp.int32)[0]
+                else:
+                    packed[0:fblk, :] = bins_ref[0].astype(jnp.int32)
+                s = stats_ref[0]
+                if not jnp.issubdtype(s.dtype, jnp.integer):
+                    s = jax.lax.bitcast_convert_type(
+                        s.astype(jnp.float32), jnp.int32)
+                packed[stats_rows, :] = s.astype(jnp.int32)
+                packed[leaf_row, :] = leaf_ref[0]
+
+            def left_pack():
+                """`packed`'s live rows moved to its first `n_live` lanes,
+                in their order, every tile of it alike.  A live row moves
+                left by the dead rows before it: that count by log-steps
+                of roll-and-add (Mosaic lowers no cumsum), then one
+                conditional shift per bit of it, lowest first, which never
+                lands two rows on a lane; the count travels with its row
+                and a lane a row has left counts as dead.  The counts are
+                kept as `[8, block / 8]`, lane p of the block at
+                `[p // (block / 8), p % (block / 8)]`: an eighth of the
+                registers a `[1, block]` row takes.  The lanes past the
+                live rows get a leaf id no slot has (-1 is a dead
+                slot's)."""
+                sub, bits = block // 8, (block - 1).bit_length()
+                lane = jax.lax.broadcasted_iota(jnp.int32, (8, sub), 1)
+                place = lane + sub * jax.lax.broadcasted_iota(
+                    jnp.int32, (8, sub), 0)
+                comes_to, comes_at = scratch[2], scratch[3]
+
+                def ahead(x, step):
+                    """The counts `step` lanes of the block further on,
+                    around its end."""
+                    rows, lanes_on = divmod(step, sub)
+                    if lanes_on:
+                        x = pltpu.roll(x, sub - lanes_on, 1)
+                    here = pltpu.roll(x, 8 - rows, 0) if rows else x
+                    if not lanes_on:
+                        return here
+                    below = pltpu.roll(x, 7 - rows, 0) if rows < 7 else x
+                    return jnp.where(lane < sub - lanes_on, here, below)
+
+                alive = jnp.concatenate(
+                    [live_row[:, r * sub:(r + 1) * sub] for r in range(8)],
+                    axis=0)
+                move = 1 - alive
+                for bit in range(bits):
+                    move = move + jnp.where(
+                        place >= 1 << bit, ahead(move, block - (1 << bit)), 0)
+                move = move * alive
+                for bit in range(bits):
+                    coming = ahead(move, 1 << bit)
+                    comes = (coming >> bit) & 1
+                    comes_at[bit * 8:bit * 8 + 8, :] = comes
+                    move = jnp.where(
+                        comes != 0, coming,
+                        jnp.where(((move >> bit) & 1) != 0, 0, move))
+
+                def widen(bit, carry):
+                    # whether a row comes to a lane at a step, for every
+                    # sublane of a tile
+                    tile = pl.ds(pl.multiple_of(bit * 8, 8), 8)
+                    comes = comes_at[tile, :]
+                    for r in range(8):
+                        comes_to[tile, r * sub:(r + 1) * sub] = (
+                            jnp.broadcast_to(comes[r:r + 1, :], (8, sub)))
+                    return carry
+
+                jax.lax.fori_loop(0, bits, widen, 0)
+
+                def shifts(j, carry):
+                    tile = pl.ds(pl.multiple_of(j * 8, 8), 8)
+                    x = packed[tile, :]
+                    for bit in range(bits):
+                        x = jnp.where(
+                            comes_to[bit * 8:bit * 8 + 8, :] != 0,
+                            pltpu.roll(x, block - (1 << bit), 1), x)
+                    packed[tile, :] = x
+                    return carry
+
+                jax.lax.fori_loop(0, meta // 8 + 1, shifts, 0)
+                packed[leaf_row, :] = jnp.where(
+                    jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+                    < n_live, packed[leaf_row, :], _NO_SLOT)
+
             # a block's sub-blocks are summed apart, in `part`, and meet the
             # accumulator once, as a whole block's dot does: its sums run
             # into the hundreds of thousands, where every f32 add rounds
-            part = scratch[1]
+            def rows_of(groups):
+                return slice(groups[0][0] * Bp, sum(groups[-1]) * Bp)
 
-            def first(rows, acc):
-                part[rows, :] = acc
+            def clear(groups):
+                rows = rows_of(groups)
+                part[rows, :] = jnp.zeros((rows.stop - rows.start, K * S),
+                                          acc_dtype)
 
-            def middle(rows, acc):
+            def add(rows, acc):
                 part[rows, :] += acc
 
-            def final(rows, acc):
-                out_ref[rows, :] += part[rows, :] + acc
-
-            def body(sb, carry):
-                sweep(pl.ds(pl.multiple_of(sb * lanes, lanes), lanes), middle)
+            def sub_block(sb, carry):
+                at = pl.ds(pl.multiple_of(sb * lanes, lanes), lanes)
+                stats = packed[stats_rows, at]
+                if not jnp.issubdtype(stats_ref.dtype, jnp.integer):
+                    stats = jax.lax.bitcast_convert_type(stats, jnp.float32)
+                sweep(lambda f: as_stored[f, at].astype(jnp.int32),
+                      stats, packed[leaf_row, at], add)
                 return carry
 
-            sweep(pl.ds(0, lanes), first)
-            jax.lax.fori_loop(1, block // lanes - 1, body, 0)
-            sweep(pl.ds(block - lanes, lanes), final)
+            def meet(groups):
+                rows = rows_of(groups)
+                out_ref[rows, :] += part[rows, :]
+
+            @pl.when(n_live > 0)
+            def _():
+                stage()
+                # where packing frees no sub-block the rows stay where they
+                # are: a dead row's leaf is no slot's either way
+                pl.when(n_live <= block - lanes)(left_pack)
+                each_run(clear)
+
+            # only the sub-blocks that hold a live row: none of a block
+            # that has none
+            jax.lax.fori_loop(0, (n_live + lanes - 1) // lanes, sub_block, 0)
+            pl.when(n_live > 0)(functools.partial(each_run, meet))
         return kernel
 
     # Mosaic block-shape rule: the last two dims of every block must be
@@ -748,6 +919,17 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     # 4-bit stride layout spans the block, and one column at a time is
     # the kernel as it was
     lanes = block if G == 1 or packed_rows else perfeature_dot_lanes(block)
+    # the grouped kernel over lane sub-blocks packs a block's live rows
+    # first and contracts only the sub-blocks that then hold one; the other
+    # two arms sweep every row of every block
+    compacts = G > 1 and lanes < block
+    # the packed copy of a block: the bins as 32-bit words (`narrow`: four
+    # uint8 columns a word, as Mosaic stores them) or widened, a column a
+    # row, up to whole sublane tiles (`meta` rows); then one more tile, the
+    # S stat planes as their 32-bit patterns and the leaf ids
+    narrow = bins_t_blocks.dtype.itemsize == 1 and fblk % 32 == 0
+    meta = -(-(fblk // 4 if narrow else fblk) // 8) * 8
+    pack_bits = (block - 1).bit_length()
     dot_bytes = jnp.dtype(dot_dtype).itemsize
     # scoped-VMEM ceiling, from the shapes: the compiler's default
     # (16 MiB on a v5e) is under what the block-scaled temporaries
@@ -759,19 +941,23 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     # one-hot and the [K*S, lanes] slot expansion at 32 bits and
     # narrowed, all live at once; a group adds its stacked one-hot
     # (the scratch and the dot's read of it) and its [G*Bp, K*S]
-    # result, lane sub-blocks their partial accumulator
+    # result, lane sub-blocks their partial accumulator, the pack its
+    # copy of the block, a [8, blk] tile of 32-bit masks per step and
+    # ten more of temporaries
     pipelined = 2 * (fblk * Bp * ks_pad * 4
                      + fblk * bins_block * bins_t_blocks.dtype.itemsize
                      + (32 + 32) * block)
     temporaries = lanes * (Bp * (4 + 4 + dot_bytes) + ks_pad * (4 + 4))
     stacked = (G > 1) * G * Bp * (2 * lanes * dot_bytes + ks_pad * 4)
-    part = (lanes < block) * fblk * Bp * ks_pad * 4
-    vmem_limit = pipelined + temporaries + stacked + part
+    part = compacts * fblk * Bp * ks_pad * 4
+    packing = compacts * (meta + 8 + 8 * (10 + pack_bits)) * block * 4
+    vmem_limit = pipelined + temporaries + stacked + part + packing
     # grid order: the row-block axis is LAST (innermost), so each
     # feature chunk's accumulator block stays resident while the row
-    # sweep accumulates into it
-    raw = pl.pallas_call(
-        kernel_perfeature_chunk(fblk, nf, G, lanes),
+    # sweep accumulates into it.  The second output is a block's live
+    # rows, a scalar a grid step (every feature chunk writes the same)
+    raw, live_rows = pl.pallas_call(
+        kernel_perfeature_chunk(fblk, nf, G, lanes, compacts, narrow, meta),
         grid=(nf, nb),
         in_specs=[
             pl.BlockSpec((1, fblk, bins_block), lambda fi, i: (i, fi, 0)),
@@ -779,12 +965,17 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             pl.BlockSpec((1, 1, block), lambda fi, i: (i, 0, 0)),
             pl.BlockSpec((K, 1), lambda fi, i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((fblk * Bp, K * S), lambda fi, i: (fi, 0)),
-        out_shape=jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
+        out_specs=(
+            pl.BlockSpec((fblk * Bp, K * S), lambda fi, i: (fi, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM)),
+        out_shape=(jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
+                   jax.ShapeDtypeStruct((nb,), jnp.int32)),
         scratch_shapes=(
             [pltpu.VMEM((G * Bp, lanes), dot_dtype)] * (G > 1)
-            + [pltpu.VMEM((fblk * Bp, K * S), acc_dtype)]
-            * (lanes < block)),
+            + [pltpu.VMEM((fblk * Bp, K * S), acc_dtype),
+               pltpu.VMEM((8 * pack_bits, block), jnp.int32),
+               pltpu.VMEM((8 * pack_bits, block // 8), jnp.int32),
+               pltpu.VMEM((meta + 8, block), jnp.int32)] * compacts),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
@@ -793,7 +984,9 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     raw = raw.reshape(K, S, F * B)
     hist = jax.vmap(lambda r: _unpack_hist(r.reshape(S, F * B), precision))(
         raw)
-    return hist.reshape(K, F, B, 3)
+    contracted = (-(-live_rows // lanes) if compacts
+                  else nb * (block // perfeature_dot_lanes(block)))
+    return hist.reshape(K, F, B, 3), _call_rows(contracted, live_rows)
 
 
 def build_histogram_t(bins_t_blocks, stats_blocks, num_bins: int,

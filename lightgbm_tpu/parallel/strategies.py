@@ -173,7 +173,8 @@ def _build_strategy_grower(params, num_features, strategy, mesh,
             num_shards=nshards, jit=False, num_columns=num_columns,
             debug_hist=debug_hist, external_pool=external_pool,
             live_columns=live_columns)
-        out_specs = {**base_out, "leaf_ids": P(ROW_AXES)}
+        out_specs = {**base_out, "leaf_ids": P(ROW_AXES),
+                     "hist_rows": P(ROW_AXES)}
         if external_pool:
             out_specs["pool"] = pool_spec
         if debug_hist:
@@ -213,7 +214,7 @@ def _build_strategy_grower(params, num_features, strategy, mesh,
         # slice but partitions rows from the full local matrix, so no
         # per-split column broadcast is needed — the only collective left
         # is the all_gather of per-shard best gains
-        out_specs = {**base_out, "leaf_ids": P()}
+        out_specs = {**base_out, "leaf_ids": P(), "hist_rows": P(FEATURE)}
         if external_pool:
             out_specs["pool"] = pool_spec
         if debug_hist:
@@ -245,7 +246,8 @@ def _build_strategy_grower(params, num_features, strategy, mesh,
         # the partition reads the full matrix, like the 1-D feature
         # mode); histograms psum over the row axes, bests all_gather
         # over 'feature'
-        out_specs = {**base_out, "leaf_ids": P(ROW_AXES)}
+        out_specs = {**base_out, "leaf_ids": P(ROW_AXES),
+                     "hist_rows": P((FEATURE,) + ROW_AXES)}
         if external_pool:
             out_specs["pool"] = pool_spec
         if debug_hist:
