@@ -17,13 +17,10 @@ the window's last call are held against `lib/reference.py`'s float64 walk
 of the same model text.
 """
 
-import time
-
 import numpy as np
 
 from benchmarks.lib import forest, reference, sut, table, timing
 from benchmarks.lib.harness import Outcome, compare, within
-from benchmarks.lib.spans import WINDOW_SPAN
 
 
 def run(cell) -> Outcome:
@@ -74,15 +71,13 @@ def run(cell) -> Outcome:
             rows_returned += len(batch)
             last = batch, out
 
-    window_start = time.perf_counter()
+    def traced_calls():
+        for _ in range(int(traffic["trace_calls"])):
+            one_call()
+
+    window_start, walls, elapsed = timing.window(cell, one_call, traced_calls)
     if cell.trace:
-        with spans.traced_window(cell.out_dir):
-            for _ in range(int(traffic["trace_calls"])):
-                one_call()
         walls = spans.walls("bench/predict_call", window_start)
-        elapsed = spans.walls(WINDOW_SPAN)[0]
-    else:
-        walls, elapsed = timing.run_window(one_call, cell.seconds)
     window_compiles = cell.compiles.snapshot().programs - setup_compiles.programs
     cell.say("calls", calls=calls, batch_rows=batch_rows,
              call_s=timing.summary(walls))

@@ -3,21 +3,18 @@ points, at the configuration's parameters and nothing else.
 
 Set-up (all of it `setup_s`): the table and the hold-out from the seed,
 `Dataset.construct`, `Booster(...)`, `warmup_iters` iterations (the first
-compiles or loads every program).  Window: `Booster.update()` in groups of
+compiles or loads every program).
+Window (`lib/timing.iteration_window`): `Booster.update()` in groups of
 `group_iters`, each group ended by a wait for the device, until the host
 clock passes `--seconds`; the metric is iterations over elapsed time.  A
 traced run measures `trace_iters` iterations under the profiler instead.
 Correctness is decided after the window, by `lib/reference.py`.
 """
 
-import time
-import traceback
-
 import numpy as np
 
 from benchmarks.lib import device, reference, sut, table, timing
 from benchmarks.lib.harness import Outcome, compare, within
-from benchmarks.lib.spans import WINDOW_SPAN
 
 
 def run(cell) -> Outcome:
@@ -37,46 +34,10 @@ def run(cell) -> Outcome:
     setup_compiles = cell.compiles.snapshot()
     setup_s = cell.since_start()
 
-    failed = iterations = 0
-
-    def group(n):
-        """`n` iterations and the wait for the device.  An iteration that
-        splits no leaf is a failed one; so is a call or a wait that raises
-        (a device failure may only show at the wait), and it ends the
-        window."""
-        nonlocal failed, iterations
-        try:
-            for _ in range(n):
-                iterations += 1
-                with spans.span("bench/update"):
-                    if bst.update():
-                        failed += 1  # no leaf could be split: nothing trained
-            with spans.span("bench/sync"):
-                device.sync()
-        except Exception as e:
-            traceback.print_exc()
-            cell.say("an iteration raised", error=repr(e)[:300])
-            failed += 1
-            return timing.STOP
-
-    window_start = time.perf_counter()
-    if cell.trace:
-        group_iters = int(traffic["trace_iters"])
-        with spans.traced_window(cell.out_dir):
-            group(group_iters)
-        groups = spans.walls(WINDOW_SPAN)
-        elapsed = groups[0]
-    else:
-        group_iters = int(traffic["group_iters"])
-        groups, elapsed = timing.run_window(lambda: group(group_iters),
-                                            cell.seconds)
+    win = timing.iteration_window(cell, bst.update)
+    iterations, elapsed = win.iterations, win.window_s
     window_compiles = cell.compiles.snapshot().programs - setup_compiles.programs
 
-    rates = [group_iters / g for g in groups]
-    cell.say("groups", group_iters=group_iters,
-             iterations_per_s=timing.summary(rates), by_group=rates,
-             first_iteration_index=warmup,
-             last_iteration_index=warmup + iterations)
     # ---- after the window: is what was trained right? -------------------------
     trees = reference.parse_model(bst.model_to_string())
     leaves = int(params["num_leaves"])
@@ -128,11 +89,12 @@ def run(cell) -> Outcome:
                  iterations=iterations)
     rows = int(tab.data["rows"])
     facts.update(table.histogram_facts(cell, trees, warmup, rows),
-                 iterations=iterations, window_start=window_start, rows=rows,
+                 iterations=iterations, window_start=win.start, rows=rows,
                  features=int(tab.data["features"]),
                  bins=int(params["max_bin"]))
     return Outcome(
-        attempted=iterations, failed=failed, checks=checks,
+        attempted=iterations, failed=win.failed, checks=checks,
         end_to_end={"train_iters_per_s": iterations / elapsed,
                     "setup_s": setup_s},
-        facts=facts, notes=notes, compared=compared)
+        facts=facts, notes=notes, compared=compared,
+        result_facts=win.facts())

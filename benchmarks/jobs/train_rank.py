@@ -4,9 +4,8 @@ the checks a ranking model can be held to.
 
 Set-up (all of it `setup_s`): the table, its queries and the hold-out from
 the seed, `Dataset.construct`, `Booster(...)` (which lays the queries out
-for the device), `warmup_iters` iterations.  Window: `Booster.update()` in
-groups of `group_iters`, each ended by a wait for the device; a traced run
-measures `trace_iters` iterations under the profiler instead.
+for the device), `warmup_iters` iterations.  Window: job `train`'s
+(`lib/timing.iteration_window`).
 
 Correctness, after the window, by `lib/reference.py` (the trees as the
 model text states them) and `lib/rank_reference.py` (LambdaRank's lambdas
@@ -31,14 +30,12 @@ and NDCG in float64 numpy, nothing of the program):
 """
 
 import time
-import traceback
 
 import numpy as np
 
 from benchmarks.lib import (device, program_gauges, rank_reference, reference,
                             sut, table, timing)
 from benchmarks.lib.harness import Outcome, compare
-from benchmarks.lib.spans import WINDOW_SPAN
 
 GRADIENT_SITE = "learner.pre"
 PAIR_GAUGE = "lgbm_rank_pairs"
@@ -146,44 +143,9 @@ def run(cell) -> Outcome:
     setup_compiles = cell.compiles.snapshot()
     setup_s = cell.since_start()
 
-    failed = iterations = 0
-
-    def group(n):
-        """`n` iterations and the wait for the device; an iteration that
-        splits no leaf is a failed one, so is a call or a wait that raises,
-        and it ends the window (as in job `train`)."""
-        nonlocal failed, iterations
-        try:
-            for _ in range(n):
-                iterations += 1
-                with spans.span("bench/update"):
-                    if bst.update():
-                        failed += 1
-            with spans.span("bench/sync"):
-                device.sync()
-        except Exception as e:
-            traceback.print_exc()
-            cell.say("an iteration raised", error=repr(e)[:300])
-            failed += 1
-            return timing.STOP
-
-    window_start = time.perf_counter()
-    if cell.trace:
-        group_iters = int(traffic["trace_iters"])
-        with spans.traced_window(cell.out_dir):
-            group(group_iters)
-        groups = spans.walls(WINDOW_SPAN)
-        elapsed = groups[0]
-    else:
-        group_iters = int(traffic["group_iters"])
-        groups, elapsed = timing.run_window(lambda: group(group_iters),
-                                            cell.seconds)
+    win = timing.iteration_window(cell, bst.update)
+    iterations, elapsed = win.iterations, win.window_s
     window_compiles = cell.compiles.snapshot().programs - setup_compiles.programs
-    rates = [group_iters / g for g in groups]
-    cell.say("groups", group_iters=group_iters,
-             iterations_per_s=timing.summary(rates), by_group=rates,
-             first_iteration_index=warmup,
-             last_iteration_index=warmup + iterations)
     # ---- after the window: is what was trained right? -------------------------
     t_checks = time.perf_counter()
     trees = reference.parse_model(bst.model_to_string())
@@ -243,11 +205,12 @@ def run(cell) -> Outcome:
                  checks_s=time.perf_counter() - t_checks)
     rows = int(tab.data["rows"])
     facts.update(table.histogram_facts(cell, trees, warmup, rows),
-                 iterations=iterations, window_start=window_start, rows=rows,
+                 iterations=iterations, window_start=win.start, rows=rows,
                  features=int(tab.data["features"]),
                  bins=int(params["max_bin"]))
     return Outcome(
-        attempted=iterations, failed=failed, checks=checks,
+        attempted=iterations, failed=win.failed, checks=checks,
         end_to_end={"train_iters_per_s": iterations / elapsed,
                     "setup_s": setup_s},
-        facts=facts, notes=notes, compared=compared)
+        facts=facts, notes=notes, compared=compared,
+        result_facts=win.facts())

@@ -15,34 +15,17 @@ addition as 2 x bins x planes multiply-adds over every row of the table at
 every call.  What it is for is a number that rises with any honest gain in
 the layer and cannot pass 100 % while every histogrammed row is read once.
 
-Which bound holds goes on an earlier line, and with it, where every call's
-output still reads as `[features x bins, slots x planes]`, the share of the
-MXU's peak that the dense contraction the calls were built as
-(`lib/opcount.hist_contraction`) comes to: how full the MXU is inside the
-formulation, a note with no claim on it.  None where the job states no
-trees or no kernel ran; a device without published peaks is an error."""
+Which bound holds goes on an earlier line.  None where the job states no
+trees or no kernel ran; a device without published peaks is an error.
 
-import re
+The line's earlier note of the MXU share of the dense contraction a call
+was built as went in PR 38: since PR 36 a call contracts the sub-blocks that
+hold a live row, not every row it sweeps, so a count over all its rows
+(`rows x features x bins x slots`) is no longer what the MXU did, and over
+the kernel's shorter time it would read above 100 %.  What a call still
+contracts is `hist_rows_contracted_share`."""
 
 from benchmarks.lib import opcount, peaks
-
-_OUT_COLUMNS = re.compile(r"^%\S+ = [a-z]+\d+\[\d+,(\d+)\]\S* custom-call\(")
-
-
-def dense_mxu_share(facts, events, chip_seconds: float, peak_ops: float):
-    """Percent of `peak_ops` that the calls' dense contractions come to,
-    None where a call's output is not the `[., slots x planes]` one."""
-    rows = facts["rows"] / (facts.get("data_shards") or 1)
-    ops = 0
-    for ev in events:
-        for name in ev.names:
-            m = _OUT_COLUMNS.match(name)
-            if m is None:
-                return None
-            ops += opcount.hist_contraction(rows, facts["features"],
-                                            facts["bins"], int(m.group(1)),
-                                            planes=1)[0]
-    return 100.0 * ops / len(events) / peak_ops / chip_seconds
 
 
 def read(run):
@@ -57,6 +40,5 @@ def read(run):
                                     peak["hbm_bytes_per_s"])
     run.cell.say("hist_kernel_roofline", bound=bound,
                  kernel_s_per_chip=chip_seconds, operations=work[0],
-                 bytes=work[1], dense_contraction_mxu_share=dense_mxu_share(
-                     run.facts, events, chip_seconds, peak["bf16_flops"]))
+                 bytes=work[1])
     return share

@@ -118,6 +118,10 @@ class Outcome:
     # each number `correct` compared, beside its limit:
     # name -> {"value": .., "limit": .., "holds": "<=" or ">="}
     compared: dict = field(default_factory=dict)
+    # what a reader of the result's line needs to see the window, under its
+    # key `facts`: plain numbers (job `train`: iterations, seconds, the
+    # quartiles of an iteration's milliseconds)
+    result_facts: dict = field(default_factory=dict)
 
 
 @dataclass
@@ -259,7 +263,8 @@ def main(argv, t0: float) -> int:
                 metrics[m["name"]] = {
                     "value": float(outcome.end_to_end[m["name"]]),
                     "unit": m["unit"]}
-    result.update(metrics=metrics, device=dev, compared=outcome.compared)
+    result.update(metrics=metrics, device=dev, facts=outcome.result_facts,
+                  compared=outcome.compared)
     print(json.dumps(result), flush=True)
     # the numbers compared are also the last lines of standard error
     for name, c in outcome.compared.items():

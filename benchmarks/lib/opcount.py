@@ -26,25 +26,6 @@ a pass beside it, or another kernel altogether is read on the same scale,
 and no implementation passes 100 % while it reads every histogrammed row
 once.
 
-Histogram contraction (`ops/histogram.py`, the `pallas2` kernel): for every
-row block the kernel multiplies a one-hot [bins, rows] matrix per feature
-into the [slots x stat planes, rows] matrix of per-leaf-slot statistics on
-the MXU.  One call over `rows` rows, `features` feature columns, `bins`
-histogram bins, `slots` leaf slots and `planes` statistic planes (5 for the
-hi/lo split of gradient and hessian plus the count, 3 otherwise) is
-
-    operations = 2 * rows * features * bins * slots * planes
-    bytes      = rows * (features * bin_bytes + planes * stat_bytes + 4)
-                 + features * bins * slots * planes * 4
-
-(the binned columns, the statistic planes and the int32 leaf id of every
-row read once; the float32 accumulator written once).  The scatter-add
-the contraction stands for needs only 3 * rows * features additions; the
-one-hot formulation trades those for dense MXU work, over all `rows` rows
-at every call.  Since PR 35 this count is a note beside the roofline (how
-full the MXU is inside the formulation), not the yardstick: a kernel that
-skips rows does less than it.
-
 Forest walk (`ops/predict.py`): every row descends every tree, one node per
 level; a level reads the node's five table entries and the row's bin of the
 split feature, compares, and picks a child.
@@ -53,14 +34,6 @@ split feature, compares, and picks a child.
     bytes      = rows * trees * depth * 6 * 4  (five node entries + one bin)
                  + rows * features * 4 + rows * 4
 """
-
-
-def hist_contraction(rows: int, features: int, bins: int, slots: int,
-                     planes: int, bin_bytes: int = 1, stat_bytes: int = 2):
-    ops = 2 * rows * features * bins * slots * planes
-    byts = (rows * (features * bin_bytes + planes * stat_bytes + 4)
-            + features * bins * slots * planes * 4)
-    return ops, byts
 
 
 def tree_histogram_work(hist_rows: float, features: int, bins: int,

@@ -5,7 +5,7 @@ of the set-up and of the trees."""
 
 from dataclasses import dataclass
 
-from . import device, reference, sut
+from . import device, program_gauges, reference, sut
 
 
 @dataclass
@@ -59,9 +59,17 @@ def histogram_facts(cell, trees: list, first_window_tree: int,
     from the trees as the model text states them), and a line that says
     each tree's histogrammed rows over the table's rows beside the most a
     tree of its depth can histogram, 1 + depth / 2: a reading over that
-    is a fault of the count, not of the program."""
+    is a fault of the count, not of the program.  The line also has what
+    the program's kernel says it swept, contracted and found live a tree
+    (`program_gauges.hist_rows_per_tree`; None where it says nothing): an
+    untraced run reads no per-layer metric, and the contracted share is
+    what a seed's rate varies with."""
     facts = reference.window_histogram_facts(trees, first_window_tree)
+    kernel_rows = program_gauges.hist_rows_per_tree(program_gauges.snapshot())
     cell.say("histogrammed rows", table_rows=rows, **facts,
+             kernel_rows_per_tree=kernel_rows,
+             kernel_contracted_share=program_gauges.contracted_share(
+                 kernel_rows),
              over_table_rows_by_tree=[r / rows for r in
                                       facts["hist_rows_by_tree"]],
              most_a_tree_can_by_tree=[1 + reference.depth(t) / 2

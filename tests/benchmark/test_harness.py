@@ -22,7 +22,7 @@ KEPT_CELLS = [w["name"] for w in KEPT["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
 LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
-               "compared"}
+               "facts", "compared"}
 
 
 def run_cell(root, *args, env=None):
@@ -312,6 +312,21 @@ def test_rehearsal_prints_the_contracts_result_line(cell, trace, kept_root):
         for k, c in compared.items()]
     said = {n["note"]: n for n in map(json.loads, notes) if "note" in n}
     if "train" in cell.split(".")[-1]:
+        # the window, for a reader of the line: traced, one group of the
+        # traffic's `trace_iters` is the one reading
+        facts = result["facts"]
+        assert set(facts) == {"iterations", "window_s", "iteration_ms",
+                              "slowest_group"}
+        slowest = facts["slowest_group"]
+        assert 0 <= slowest["index"] < facts["iteration_ms"]["n"]
+        per_group = facts["iterations"] // facts["iteration_ms"]["n"]
+        assert 0 < slowest["update_ms"] + slowest["sync_ms"] <= (
+            per_group * facts["iteration_ms"]["max"])
+        assert facts["iterations"] == result["attempted"]
+        assert facts["window_s"] > 0 and facts["iteration_ms"]["n"] == (
+            1 if trace else facts["iterations"] // 2)   # rehearsed in pairs
+        assert facts["iteration_ms"]["q1"] <= facts["iteration_ms"]["q3"]
+        assert {k: said["groups"][k] for k in facts} == facts
         # the rows each tree histograms: at least the table once, at most
         # what a tree of its depth can, and a quarter of it a chip on four
         rows = said["histogrammed rows"]

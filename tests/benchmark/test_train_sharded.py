@@ -302,9 +302,6 @@ def test_the_roofline_counts_one_shards_rows_over_a_chips_kernel_time(
     said = run.said[-1][1]
     assert said["kernel_s_per_chip"] == pytest.approx(42 * US)
     assert (said["operations"], said["bytes"]) == (ops, byts)
-    # the dense note: a chip's call contracts its shard's 65,536 rows
-    assert said["dense_contraction_mxu_share"] == pytest.approx(
-        100 * 2 * 65536 * 67 * 255 * 125 / 197e12 / (42 * US))
     # unsharded facts on the same trace count every row for every chip
     whole = reader("hist_kernel_roofline").read(
         fake_run(two_chips, (0.0, 112 * US), dict(facts, data_shards=None)))
@@ -341,6 +338,7 @@ def test_the_cell_and_its_metrics_are_declared():
                    "grow_other_ms_per_iter",   # `jit_grow(` runs sharded too
                    "hist_kernel_roofline",     # a shard's rows a chip, PR 35
                    "hist_feature_chunks", "hist_bin_occupancy",
+                   "hist_rows_contracted_share",   # summed over the shards
                    "collective_ms_per_iter",
                    "collective_exposed_ms_per_iter"} | ON_THE_CHIP
     for name in ("collective_ms_per_iter", "collective_exposed_ms_per_iter"):

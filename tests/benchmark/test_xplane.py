@@ -180,11 +180,8 @@ def test_roofline_share_is_the_trees_work_over_the_kernels_time(v5e_train):
     assert what == "hist_kernel_roofline" and said["bound"] == "memory"
     assert (said["operations"], said["bytes"]) == (3 * 290000 * 28, HIST_BYTES)
     assert said["kernel_s_per_chip"] == pytest.approx(KERNEL_S, rel=1e-9)
-    # the note beside it: 30 calls of one slot x five planes as the dense
-    # contraction they were built as, 2*65536*28*63*5 operations each
-    assert said["dense_contraction_mxu_share"] == pytest.approx(
-        100 * (30 * 2 * 65536 * 28 * 63 * 5 / 197e12) / KERNEL_S)
-    assert said["dense_contraction_mxu_share"] == pytest.approx(2.919, rel=1e-3)
+    # nothing on the line is read from a call's operands or its output
+    assert set(said) == {"bound", "kernel_s_per_chip", "operations", "bytes"}
 
 
 def another_kernel(trace, rename, speed_up):
@@ -202,6 +199,11 @@ OTHER_SIGNATURES = {
     "a prefetched count and a second output": lambda n: n.replace(
         " custom-call(", " custom-call(s32[8]{0} %live, ").replace(
             " = f32[", " = (s32[8]{0}, f32[", 1),
+    # what the kernel returns since PR 36: the histograms and, second, a
+    # block's live rows (`ops/histogram.py`: `[F x Bp, K x S]`, `[nb]`)
+    "the histograms and the blocks' live rows": lambda n: n.replace(
+        " custom-call(", ", s32[8]{0:T(128)S(6)}) custom-call(", 1).replace(
+            " = f32[", " = (f32[", 1),
     # a compaction pass of the layer, named as the layer's passes are
     "a pass named hist_pack": lambda n: n.replace("%hist_build", "%hist_pack"),
 }
@@ -216,25 +218,45 @@ def test_another_operand_list_at_half_the_time_reads_twice_the_share(
     base = reader("hist_kernel_roofline").read(
         fake_run(v5e_train, TRAIN_WINDOW, dict(HIST_FACTS)))
     other = another_kernel(v5e_train, OTHER_SIGNATURES[how], 2.0)
-    assert not any(n.startswith("%hist_") and "[124," in n.split("(")[0]
-                   for n in other.ops[0].names)
+    assert not any(n in v5e_train.ops[0].names
+                   for n in other.ops[0].names if n.startswith("%hist_"))
     run = fake_run(other, TRAIN_WINDOW, dict(HIST_FACTS))
     # twice to the last digits (the window's clip adds start and duration)
     assert reader("hist_kernel_roofline").read(run) == pytest.approx(
         2 * base, rel=1e-12)
     assert reader("hist_build_ms_per_iter").read(run) == pytest.approx(
         1e3 * KERNEL_S / 2 / 2, rel=1e-9)
-    if "second output" in how:
-        # the dense note has nothing it can read there, and says so
-        assert run.said[0][1]["dense_contraction_mxu_share"] is None
 
 
-def test_two_shards_halve_the_rows_and_keep_the_histograms_whole(v5e_train):
-    run = fake_run(v5e_train, TRAIN_WINDOW, dict(HIST_FACTS, data_shards=2.0))
-    byts = 145000 * (28 + 8) + 30 * 28 * 63 * 12
+@pytest.mark.parametrize("shards", [2, 4])
+def test_shards_divide_the_rows_and_keep_the_histograms_whole(v5e_train,
+                                                              shards):
+    run = fake_run(v5e_train, TRAIN_WINDOW,
+                   dict(HIST_FACTS, data_shards=float(shards)))
+    rows = 290000 // shards
+    byts = rows * (28 + 8) + 30 * 28 * 63 * 12
     assert reader("hist_kernel_roofline").read(run) == pytest.approx(
         100 * (byts / 819e9) / KERNEL_S, rel=1e-12)
-    assert run.said[0][1]["operations"] == 3 * 145000 * 28
+    assert run.said[0][1]["operations"] == 3 * rows * 28
+
+
+def test_a_window_of_one_iteration_reads_that_trees_work_over_its_calls(
+        v5e_train):
+    """The kernels' events are clipped to the window and the work is the
+    window's trees': the second iteration alone reads tree 2's 140,000 rows
+    and 15 histograms over the 15 calls that end in it."""
+    calls = v5e_train.ops[0].select(lambda n: n.startswith("%hist_build"))
+    order = np.argsort(calls.start)
+    cut = float(calls.start[order[15]]) - 1e-9
+    window = (cut, TRAIN_WINDOW[1])
+    run = fake_run(v5e_train, window,
+                   dict(HIST_FACTS, iterations=1, first_window_tree=2))
+    seconds = float(calls.dur[order[15:]].sum())
+    byts = 140000 * (28 + 8) + 15 * 28 * 63 * 12
+    assert reader("hist_kernel_roofline").read(run) == pytest.approx(
+        100 * (byts / 819e9) / seconds, rel=1e-9)
+    assert reader("hist_build_ms_per_iter").read(run) == pytest.approx(
+        1e3 * seconds, rel=1e-9)
 
 
 @pytest.mark.parametrize("facts", [

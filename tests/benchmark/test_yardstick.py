@@ -1,6 +1,8 @@
 """The parts of the yardstick that need no trace: operation counts, peaks,
 timing arithmetic, the generator, the plain reference, the forest tiling."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -9,26 +11,6 @@ from benchmarks.lib.harness import BENCH_DIR, load_module
 
 
 # ---- operations and bytes -------------------------------------------------------
-@pytest.mark.parametrize("features, want_ops, want_bytes", [
-    # 1,000 rows, 255 bins, 25 slots x 5 bf16 planes, one byte per bin:
-    # 2 * 1000 * F * 255 * 125 multiply-adds counted as two operations;
-    # per row F bins + 5 * 2 bytes of statistics + 4 of leaf id, and the
-    # float32 accumulator F * 255 * 125 * 4 written once
-    (28, 1_785_000_000, 1000 * (28 + 10 + 4) + 28 * 255 * 125 * 4),
-    (136, 8_670_000_000, 1000 * (136 + 10 + 4) + 136 * 255 * 125 * 4),
-])
-def test_hist_contraction_against_hand_counts(features, want_ops, want_bytes):
-    assert opcount.hist_contraction(1000, features, 255, slots=25,
-                                    planes=5) == (want_ops, want_bytes)
-
-
-def test_hist_contraction_int8_planes():
-    ops, byts = opcount.hist_contraction(8, 2, 4, slots=1, planes=3,
-                                         stat_bytes=1)
-    assert ops == 2 * 8 * 2 * 4 * 3
-    assert byts == 8 * (2 + 3 + 4) + 2 * 4 * 3 * 4
-
-
 @pytest.mark.parametrize("hist_rows, features, bins, histograms, bin_bytes, "
                          "want_ops, want_bytes", [
     # 155 histogrammed rows of 28 one-byte bins: three additions a row and
@@ -147,6 +129,126 @@ def test_a_step_can_end_its_window():
     assert len(walls) == len(calls) == 3 and elapsed < 1.0
 
 
+class FakeCell:
+    """What `timing.iteration_window` asks of a cell, untraced."""
+    trace, out_dir = False, None
+
+    def __init__(self, seconds, **traffic):
+        from benchmarks.lib.spans import Spans
+
+        self.seconds, self.spans, self.said = seconds, Spans(), []
+        self.traffic = {"warmup_iters": 1, "group_iters": 1,
+                        "trace_iters": 3, **traffic}
+
+    def say(self, what, **fields):
+        self.said.append((what, fields))
+
+
+def raises_at(n, calls):
+    def update():
+        calls.append(1)
+        if len(calls) == n:
+            raise RuntimeError("the device is gone")
+    return update
+
+
+@pytest.mark.parametrize("how, seconds, traffic, update, want", [
+    # the clock ends the window, after a whole group
+    ("clock", 0.05, {}, lambda calls: lambda: calls.append(1), None),
+    ("clock, groups of 2", 0.05, {"group_iters": 2},
+     lambda calls: lambda: calls.append(1), None),
+    # an iteration that splits no leaf trained nothing: failed, not the end
+    ("nothing trained", 0.05, {}, lambda calls: lambda: calls.append(1) or 1,
+     "all failed"),
+    # a step that raises is a failed iteration and ends the window (STOP)
+    ("raises at 3", 60.0, {}, lambda calls: raises_at(3, calls), (3, 1)),
+    ("raises at 3, groups of 2", 60.0, {"group_iters": 2},
+     lambda calls: raises_at(3, calls), (3, 1)),
+])
+def test_iteration_window_on_a_fake_step(how, seconds, traffic, update, want,
+                                         capsys):
+    cell, calls = FakeCell(seconds, **traffic), []
+    t0 = time.perf_counter()
+    win = timing.iteration_window(cell, update(calls))
+    took = time.perf_counter() - t0
+    n = cell.traffic["group_iters"]
+    assert win.iterations == len(calls) and win.group_iters == n
+    assert t0 <= win.start and win.window_s <= took < 5.0
+    assert sum(win.walls) <= win.window_s
+    if isinstance(want, tuple):
+        assert (win.iterations, win.failed) == want
+        assert len(win.walls) == -(-want[0] // n)
+        assert ("an iteration raised",
+                {"error": "RuntimeError('the device is gone')"}) in cell.said
+        assert "the device is gone" in capsys.readouterr().err
+    else:
+        assert win.window_s >= seconds and win.iterations == n * len(win.walls)
+        assert win.failed == (win.iterations if want else 0)
+    # the spans the per-layer readers look for, one a call
+    assert len(cell.spans.walls("bench/update")) == win.iterations
+    assert len(cell.spans.walls("bench/sync")) == len(win.walls) - (
+        1 if isinstance(want, tuple) else 0)
+    # the window as the result's line carries it, and as the note says it
+    facts = win.facts()
+    assert set(facts) == {"iterations", "window_s", "iteration_ms",
+                          "slowest_group"}
+    assert (facts["iterations"], facts["window_s"]) == (win.iterations,
+                                                        win.window_s)
+    if not isinstance(want, tuple):
+        slowest = facts["slowest_group"]
+        assert 1e-3 * (slowest["update_ms"] + slowest["sync_ms"]) <= (
+            win.walls[slowest["index"]])
+    ms = facts["iteration_ms"]
+    assert set(ms) == {"n", "median", "q1", "q3", "min", "max"}
+    assert ms["n"] == len(win.walls)
+    assert ms["min"] <= ms["q1"] <= ms["median"] <= ms["q3"] <= ms["max"]
+    assert ms["max"] == pytest.approx(1e3 * max(win.walls) / n)
+    what, said = cell.said[-1]
+    assert what == "groups" and said["by_group"] == [n / w for w in win.walls]
+    assert (said["first_iteration_index"], said["last_iteration_index"]) == (
+        1, 1 + win.iterations)
+    assert {k: said[k] for k in facts} == facts
+
+
+@pytest.mark.parametrize("where", ["update_ms", "sync_ms"])
+def test_a_stalled_group_is_named_with_where_the_host_spent_it(where,
+                                                               monkeypatch):
+    """The third group of four takes 40 ms more, in `update()` or in the
+    wait for the device: the window's facts say which group and where."""
+    cell, calls, waits = FakeCell(60.0, group_iters=2), [], []
+
+    def update():
+        calls.append(1)
+        if where == "update_ms" and len(calls) == 5:
+            time.sleep(0.04)
+
+    def sync():
+        waits.append(1)
+        if where == "sync_ms" and len(waits) == 3:
+            time.sleep(0.04)
+
+    monkeypatch.setattr(timing.device, "sync", sync)
+    monkeypatch.setattr(timing, "run_window", lambda step, seconds: (
+        [timed(step) for _ in range(4)], 1.0))
+    slowest = timing.iteration_window(cell, update).facts()["slowest_group"]
+    other = "sync_ms" if where == "update_ms" else "update_ms"
+    assert slowest["index"] == 2 and slowest[where] >= 40.0 > slowest[other]
+
+
+def timed(step):
+    t0 = time.perf_counter()
+    step()
+    return time.perf_counter() - t0
+
+
+def test_a_jobs_window_hands_back_its_start_its_readings_and_their_sum():
+    cell, calls = FakeCell(0.03), []
+    start, walls, elapsed = timing.window(cell, lambda: calls.append(1),
+                                          traced=None)
+    assert len(walls) == len(calls) >= 1 and sum(walls) <= elapsed
+    assert start <= time.perf_counter() - elapsed
+
+
 def test_summary_quartiles():
     s = timing.summary([1.0, 2.0, 3.0, 4.0, 5.0])
     assert (s["n"], s["median"], s["q1"], s["q3"]) == (5, 3.0, 2.0, 4.0)
@@ -183,6 +285,28 @@ def test_rows_do_not_depend_on_the_thread_count(higgs_like, monkeypatch):
     one = higgs_like.make(spec, 9, 4500, stream=0)
     assert np.array_equal(many["X"], one["X"])
     assert np.array_equal(many["y"], one["y"])
+
+
+# ---- a table a seed --------------------------------------------------------------
+SEEDED = {
+    "higgs_like": {"features": 28},
+    "criteo_like": {"features": 67},
+    "mslr_like": {"features": 137, "rows": 6810888, "queries": 56757,
+                  "max_query_len": 1251},
+}
+
+
+@pytest.mark.parametrize("generator", sorted(SEEDED))
+def test_a_seed_draws_its_own_table_and_draws_it_again(generator):
+    """`--seed` is the table: `correct` and a claimed gain are read on
+    tables nobody tuned to.  Seeds as large as the driver's."""
+    gen, spec = load_module(BENCH_DIR, "datagen", generator), SEEDED[generator]
+    a = gen.make(spec, 3800000101, 3000, stream=0)
+    again = gen.make(spec, 3800000101, 3000, stream=0)
+    other = gen.make(spec, 2 ** 31 + 7, 3000, stream=0)
+    assert all(np.array_equal(a[k], again[k], equal_nan=True) for k in a)
+    assert not np.array_equal(a["X"], other["X"], equal_nan=True)
+    assert not np.array_equal(a["y"], other["y"])
 
 
 def test_labels_follow_the_rule_of_the_seed(higgs_like):
