@@ -1,42 +1,66 @@
-"""Retrace audit: a ledger of every XLA program this process compiles.
+"""Retrace audit: a ledger of every XLA program this process brings up.
 
-Compile latency is the single biggest wall-clock lever for training
-restarts and serving cold starts (ROADMAP item 3: the one real TPU bench
-spent 155 s compiling vs ~12 s/iter training).  The enemy is not one big
-program but the *zoo*: every jit site that keys a new trace on a static
-argument or a fresh closure silently multiplies the compile bill, and
-nothing counted them — compile_s only showed the total.
+A program is born in three stages: JAX *traces* the function to a jaxpr,
+*lowers* the jaxpr to an MLIR module, and *compiles* the module (or loads
+the executable from the persistent cache).  Every process pays the first
+two for every program, cache or no cache; only the third is what the
+persistent cache saves.  The enemy is not one big program but the *zoo*:
+every jit site that keys a new trace on a static argument or a fresh
+closure silently multiplies the bill.
 
 `ledger_jit` wraps a `jax.jit` site so each DISTINCT compiled program
 (new entry in the jit's own executable cache) is recorded once with:
 
 * the site name (one per wrapped jit call site),
-* the first-call wall time (lowering + XLA compile + first execution —
-  for the big grower programs this is compile-dominated),
+* the first-call wall time (trace + lowering + XLA compile + first
+  execution),
 * a compact signature of the triggering call (static args + input
   shapes/dtypes), so `tools/perf_probe.py retrace` can attribute WHICH
   mode/shape variant added a program.
 
 Overhead discipline: when the ledger is disabled (the default) the
-wrapper costs one attribute check per call and computes nothing; when
-enabled, cache growth is detected via the jit's own `_cache_size()` so
-no per-call signature hashing happens on cache hits.  The wrapper is
-transparent — `lower`, `_cache_size`, etc. delegate to the underlying
-jitted callable, so call sites and tests that poke at jit internals
-keep working.
+wrapper costs one attribute check per call and computes nothing, and no
+`jax.monitoring` listener is registered; when enabled, cache growth is
+detected via the jit's own `_cache_size()` so no per-call signature
+hashing happens on cache hits, and a call the jit cache answers fires
+no stage event at all.  The wrapper is transparent: `_cache_size`,
+`clear_cache`, etc. delegate to the underlying jitted callable; `trace`
+and `lower` are its own methods, so that what they cost lands at the
+site too.
 
-While enabled the ledger also listens to `jax.monitoring`: JAX reports
-one backend-compile duration for every program it has to produce, the
-compiler's work or the persistent cache's answer (the cache's own hit
-event, fired inside that duration on the same thread, tells the two
-apart).  Each is charged to the `ledger_jit` site whose call is in
-flight on that thread, or to `"(none)"` outside any site (eager `jnp`
-ops, a bare `jax.jit`), with the name JAX gives the program, and becomes
-a row of `compiles()`, a count and seconds in
-``lgbm_compile_programs_total`` / ``lgbm_compile_seconds_total``
-``{site,cache}``, and under ``tpu_telemetry=trace`` one ``compile`` span.
-`programs()`'s ``first_call_s`` is a call's wall (trace, compile AND the
-first execution); ``compile_s`` here is the compile alone.
+While enabled the ledger listens to `jax.monitoring`, which reports all
+three stages (`/jax/core/compile/jaxpr_trace_duration`,
+`.../jaxpr_to_mlir_module_duration`, `.../backend_compile_duration`: a
+scalar when a stage starts, its duration when it ends; the compilation
+cache's own hit event, fired inside the third on the same thread, tells
+a load from a compile).  Each stage event is charged to the `ledger_jit`
+site whose call, `trace` or `lower` is in flight on that thread, or to
+`"(none)"` outside any site (eager `jnp` ops, a bare `jax.jit`), with the
+name JAX gives the function, and becomes
+
+* a row of `births()` (`stage`: ``trace`` | ``lower`` | ``compile``;
+  `compiles()` is the third stage's rows under their older keys);
+* seconds and a count in ``lgbm_trace_seconds_total`` /
+  ``lgbm_trace_programs_total`` ``{site}``, ``lgbm_lower_seconds_total``
+  / ``lgbm_lower_programs_total`` ``{site}``, and
+  ``lgbm_compile_seconds_total`` / ``lgbm_compile_programs_total``
+  ``{site,cache}``;
+* under ``tpu_telemetry=trace`` a span: ``program/trace`` and
+  ``program/lower`` ``site= fun_name=``, ``compile`` ``site= fun_name=
+  cache=``.
+
+Stages nest: tracing a function traces every inner `jit` it calls (each
+`jnp` function, each kernel body), a lowering rule may trace, a trace may
+run an eager op to its compile.  JAX says when a stage starts, so every
+event knows what encloses it on its thread.  A row's `seconds` is the
+stage's wall and `self_s` that less the stage events it enclosed, `depth`
+counts what encloses it (0: outermost); the trace and lower counters add
+self seconds (summed over sites and stages they are a wall, not a multiple
+of one) and count outermost events only.  The spans nest as the stages do,
+under whatever `obs.span` was open; a trace directly inside a trace gets no
+span of its own but is counted in the enclosing span's ``inner=`` tag.
+`programs()`'s ``first_call_s`` is a call's wall (all three stages AND the
+first execution).
 
 The module-level `LEDGER` singleton is the process-wide audit surface:
 
@@ -45,6 +69,7 @@ The module-level `LEDGER` singleton is the process-wide audit surface:
     ... train / predict / serve ...
     LEDGER.n_programs()        # the n_programs bench metric
     LEDGER.report()            # per-site breakdown
+    LEDGER.births()            # every stage of every program, by site
     LEDGER.compiles()          # every program produced, by site
 """
 
@@ -59,23 +84,55 @@ from jax import monitoring
 
 from .. import obs
 
-# the two jax.monitoring events the attribution reads (jax 0.9:
-# interpreters/pxla.py _cached_compilation, compiler.py
-# compile_or_get_cached)
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+# the jax.monitoring events the attribution reads (jax 0.9: dispatch.py
+# log_elapsed_time around pjit.py's trace, interpreters/pxla.py's lowering
+# and _cached_compilation; compiler.py compile_or_get_cached)
+_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+           "/jax/core/compile/backend_compile_duration": "compile"}
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
+# the counters of the first two stages (seconds, programs); the third's
+# carry the cache's answer as a second label
+_COUNTERS = {"trace": ("lgbm_trace_seconds_total",
+                       "lgbm_trace_programs_total"),
+             "lower": ("lgbm_lower_seconds_total",
+                       "lgbm_lower_programs_total")}
 NO_SITE = "(none)"
 
-# per thread: the ledger_jit sites whose calls are in flight, and
-# whether the persistent cache answered the program now being produced
+# per thread: the ledger_jit sites whose calls are in flight, the stages
+# in flight, and whether the persistent cache answered the program now
+# being produced
 _tls = threading.local()
 
 
-def _site_stack() -> List[str]:
-    st = getattr(_tls, "sites", None)
+def _thread_list(name: str) -> list:
+    st = getattr(_tls, name, None)
     if st is None:
-        st = _tls.sites = []
+        st = []
+        setattr(_tls, name, st)
     return st
+
+
+def _site_stack() -> List[str]:
+    return _thread_list("sites")
+
+
+class _Stage:
+    """A stage in flight on a thread: the seconds of the stage events it
+    has enclosed so far, how many of them it folded into its span, and
+    that span (None with tracing off, and for a compile, whose span is
+    made when its cache answer is known)."""
+    __slots__ = ("stage", "inner_s", "inner", "span")
+
+    def __init__(self, stage: str, span):
+        self.stage = stage
+        self.inner_s = 0.0
+        self.inner = 0
+        self.span = span
+
+
+def _stage_stack() -> List[_Stage]:
+    return _thread_list("stages")
 
 
 def _describe_leaf(x: Any) -> str:
@@ -151,7 +208,7 @@ class CompileLedger:
         self._enabled = False
         self._capture = False
         self._programs: List[Dict] = []
-        self._compiles: List[Dict] = []
+        self._births: List[Dict] = []
 
     # -- control -------------------------------------------------------
     @property
@@ -164,11 +221,16 @@ class CompileLedger:
         on = bool(on)
         with self._lock:
             if on and not self._enabled:
+                # a stage a switch-off cut short must not enclose what
+                # this thread does next
+                del _stage_stack()[:]
                 monitoring.register_event_listener(self._on_event)
+                monitoring.register_scalar_listener(self._on_start)
                 monitoring.register_event_duration_secs_listener(
                     self._on_duration)
             elif self._enabled and not on:
                 monitoring.unregister_event_listener(self._on_event)
+                monitoring.unregister_scalar_listener(self._on_start)
                 monitoring.unregister_event_duration_listener(
                     self._on_duration)
             self._enabled = on
@@ -188,40 +250,106 @@ class CompileLedger:
     def reset(self) -> None:
         with self._lock:
             self._programs = []
-            self._compiles = []
+            self._births = []
 
-    # -- compile attribution (called by jax.monitoring) -----------------
+    # -- stage attribution (called by jax.monitoring) -------------------
     def _on_event(self, event: str, **_) -> None:
         if event == _CACHE_HIT:
             _tls.cache_hit = True
 
-    def _on_duration(self, event: str, seconds: float, **kw) -> None:
-        if event != _BACKEND_COMPILE:
+    def _on_start(self, event: str, _value, **kw) -> None:
+        """A stage starts on this thread.  With tracing on, a trace or a
+        lowering opens its span here, so that what it encloses is its
+        child; a trace directly inside a trace is only counted."""
+        stage = _STAGES.get(event)
+        if stage is None:
             return
-        cache = "hit" if getattr(_tls, "cache_hit", False) else "miss"
-        _tls.cache_hit = False
+        stages = _stage_stack()
+        sp = None
+        if obs.tracing_on() and stage != "compile":
+            if stage == "trace" and stages and stages[-1].stage == "trace":
+                stages[-1].inner += 1
+            else:
+                sites = _site_stack()
+                sp = obs.span("program/" + stage,
+                              site=sites[-1] if sites else NO_SITE,
+                              fun_name=str(kw.get("fun_name", "")))
+                sp.__enter__()
+        stages.append(_Stage(stage, sp))
+
+    def _on_duration(self, event: str, seconds: float, **kw) -> None:
+        stage = _STAGES.get(event)
+        if stage is None:
+            return
+        stages = _stage_stack()
+        # its own start, or none (a listener switched on in mid-stage)
+        own = (stages.pop() if stages and stages[-1].stage == stage
+               else _Stage(stage, None))
+        depth = len(stages)
+        if stages:
+            stages[-1].inner_s += seconds
+            if own.span is None:
+                # a folded trace hands what it folded to the span above
+                stages[-1].inner += own.inner
+        self_s = max(0.0, seconds - own.inner_s)
         sites = _site_stack()
         site = sites[-1] if sites else NO_SITE
         fun_name = str(kw.get("fun_name", ""))
+        row = {"site": site, "fun_name": fun_name, "stage": stage,
+               "seconds": seconds, "self_s": self_s, "depth": depth}
+        if stage == "compile":
+            cache = row["cache"] = ("hit" if getattr(_tls, "cache_hit", False)
+                                    else "miss")
+            _tls.cache_hit = False
+            obs.REGISTRY.inc("lgbm_compile_seconds_total", seconds,
+                             help="seconds JAX spent producing programs, "
+                                  "by ledger site and persistent-cache "
+                                  "answer",
+                             site=site, cache=cache)
+            obs.REGISTRY.inc("lgbm_compile_programs_total", 1,
+                             help="programs JAX produced (compiled or "
+                                  "loaded)",
+                             site=site, cache=cache)
+            obs.span_ended("compile", seconds, site=site,
+                           fun_name=fun_name, cache=cache)
+        else:
+            seconds_total, programs_total = _COUNTERS[stage]
+            obs.REGISTRY.inc(seconds_total, self_s,
+                             help="seconds JAX spent in this stage of a "
+                                  "program's birth, less the stages it "
+                                  "enclosed, by ledger site",
+                             site=site)
+            if depth == 0:
+                obs.REGISTRY.inc(programs_total, 1,
+                                 help="outermost events of this stage, by "
+                                      "ledger site",
+                                 site=site)
+            if own.span is not None:
+                if own.inner:
+                    own.span.tags["inner"] = own.inner
+                own.span.__exit__(None, None, None)
         with self._lock:
-            self._compiles.append({"site": site, "fun_name": fun_name,
-                                   "compile_s": seconds, "cache": cache})
-        obs.REGISTRY.inc("lgbm_compile_seconds_total", seconds,
-                         help="seconds JAX spent producing programs, "
-                              "by ledger site and persistent-cache answer",
-                         site=site, cache=cache)
-        obs.REGISTRY.inc("lgbm_compile_programs_total", 1,
-                         help="programs JAX produced (compiled or loaded)",
-                         site=site, cache=cache)
-        obs.span_ended("compile", seconds, site=site, fun_name=fun_name,
-                       cache=cache)
+            self._births.append(row)
+
+    def births(self) -> List[Dict]:
+        """Every stage event JAX reported while enabled, in the order
+        they ended (an enclosed event before the one that encloses it):
+        `site`, `fun_name`, `stage` ("trace" | "lower" | "compile"),
+        `seconds` (the stage's wall), `self_s` (that less the events it
+        enclosed), `depth` (the stages in flight around it on its
+        thread; 0: outermost) and, for a compile, `cache` ("hit" |
+        "miss")."""
+        with self._lock:
+            return [dict(b) for b in self._births]
 
     def compiles(self) -> List[Dict]:
         """Every program JAX produced while enabled, in order: `site`,
         `fun_name`, `compile_s` (compile or cache load, no execution),
         `cache` ("hit" | "miss")."""
         with self._lock:
-            return [dict(c) for c in self._compiles]
+            return [{"site": b["site"], "fun_name": b["fun_name"],
+                     "compile_s": b["seconds"], "cache": b["cache"]}
+                    for b in self._births if b["stage"] == "compile"]
 
     # -- recording (called by LedgeredJit) ------------------------------
     def record(self, site: str, signature: str, wall_s: float,
@@ -402,23 +530,38 @@ class LedgeredJit:
     def __call__(self, *args, **kwargs):
         if not LEDGER.enabled:
             return self._fn(*args, **kwargs)
-        sites = _site_stack()
         with self._lock:
             before = self._fn._cache_size()
             t0 = time.perf_counter()
-            sites.append(self.site)
-            try:
-                out = self._fn(*args, **kwargs)
-            finally:
-                sites.pop()
+            out = self._at_site(self._fn, args, kwargs)
             if self._fn._cache_size() > before:
                 LEDGER.record(self.site, call_signature(args, kwargs),
                               time.perf_counter() - t0,
                               aot=self._capture_specs(args, kwargs))
         return out
 
+    def _at_site(self, method, args, kwargs):
+        """`method(*args, **kwargs)` with this site in flight on the
+        thread, so that the ledger charges it what JAX does there."""
+        if not LEDGER.enabled:
+            return method(*args, **kwargs)
+        sites = _site_stack()
+        sites.append(self.site)
+        try:
+            return method(*args, **kwargs)
+        finally:
+            sites.pop()
+
+    def trace(self, *args, **kwargs):
+        """`jax.jit(fn).trace`, its stage events charged to this site."""
+        return self._at_site(self._fn.trace, args, kwargs)
+
+    def lower(self, *args, **kwargs):
+        """`jax.jit(fn).lower`, its stage events charged to this site."""
+        return self._at_site(self._fn.lower, args, kwargs)
+
     def __getattr__(self, name):
-        # transparent delegation (lower/_cache_size/clear_cache/...)
+        # transparent delegation (_cache_size/clear_cache/eval_shape/...)
         return getattr(self._fn, name)
 
 

@@ -31,6 +31,8 @@ The contract under test:
   compile_s.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -218,23 +220,25 @@ class TestCompileAttribution:
         def listening():
             return (LEDGER._on_duration
                     in mon.get_event_duration_listeners(),
-                    LEDGER._on_event in mon.get_event_listeners())
+                    LEDGER._on_event in mon.get_event_listeners(),
+                    LEDGER._on_start in mon.get_scalar_listeners())
 
         LEDGER.enable(False)
-        assert listening() == (False, False)
+        assert listening() == (False, False, False)
         LEDGER.enable()
         LEDGER.enable()            # twice is once
         try:
-            assert listening() == (True, True)
+            assert listening() == (True, True, True)
             assert mon.get_event_duration_listeners().count(
                 LEDGER._on_duration) == 1
+            assert mon.get_scalar_listeners().count(LEDGER._on_start) == 1
         finally:
             LEDGER.enable(False)
             LEDGER.enable(False)
-        assert listening() == (False, False)
+        assert listening() == (False, False, False)
         LEDGER.reset()
         jax.jit(_loose_unit_program)(jnp.ones(19))
-        assert LEDGER.compiles() == []
+        assert LEDGER.compiles() == [] and LEDGER.births() == []
 
     def test_cold_then_warm_cache_turns_miss_to_hit(self, tmp_path):
         """A fresh process per run: the first fills an empty persistent
@@ -261,6 +265,205 @@ class TestCompileAttribution:
         assert set(cold) == set(warm) == sites
         assert set(cold.values()) == {"miss"}
         assert set(warm.values()) == {"hit"}
+
+
+@jax.jit
+def _inner_unit_program(x):
+    return jnp.where(x > 0, x, 0.0) * 2
+
+
+def _nested_unit_program(x):
+    return _inner_unit_program(x) + jnp.sum(x)    # four inner jits deep
+
+
+def _stage_counter(stage: str, what: str, site: str) -> float:
+    from lightgbm_tpu import obs
+
+    return obs.REGISTRY.value(f"lgbm_{stage}_{what}_total", site=site)
+
+
+class TestProgramBirths:
+    """ISSUE 39: a program's birth in three stages (trace, lower,
+    compile), each charged to the site in flight, nested ones counted
+    once."""
+
+    STAGES = ("trace", "lower", "compile")
+
+    def test_a_first_call_is_one_of_each_stage_and_a_second_is_none(
+            self, ledger):
+        f = ledger_jit(_nested_unit_program, site="unit.born")
+        x = jnp.ones(21)
+        before = {(st, what): _stage_counter(st, what, "unit.born")
+                  for st in ("trace", "lower")
+                  for what in ("programs", "seconds")}
+        f(x)
+        rows = [b for b in ledger.births() if b["site"] == "unit.born"]
+        assert all(set(b) - {"cache"} == {"site", "fun_name", "stage",
+                                          "seconds", "self_s", "depth"}
+                   and 0 <= b["self_s"] <= b["seconds"] for b in rows)
+        assert all(("cache" in b) == (b["stage"] == "compile")
+                   for b in rows)
+        outer = [b for b in rows if b["depth"] == 0]
+        assert [(b["stage"], b["fun_name"]) for b in outer] == [
+            ("trace", "_nested_unit_program"),
+            ("lower", "jit(_nested_unit_program)"),
+            ("compile", "jit(_nested_unit_program)")]
+        # the inner jits were traced too, inside the one trace
+        assert {"_inner_unit_program", "_where"} <= {
+            b["fun_name"] for b in rows if b["depth"] > 0}
+        assert {b["stage"] for b in rows if b["depth"] > 0} == {"trace"}
+        for st in ("trace", "lower"):
+            assert _stage_counter(st, "programs", "unit.born") \
+                == before[st, "programs"] + 1
+            assert _stage_counter(st, "seconds", "unit.born") \
+                - before[st, "seconds"] == pytest.approx(
+                    sum(b["self_s"] for b in rows if b["stage"] == st))
+        assert ledger.compiles() == [
+            {"site": b["site"], "fun_name": b["fun_name"],
+             "compile_s": b["seconds"], "cache": b["cache"]}
+            for b in ledger.births() if b["stage"] == "compile"]
+        # nothing in a window: a warmed site fires no stage event
+        y, n = x + 1, len(ledger.births())
+        f(x)
+        f(y)
+        assert len(ledger.births()) == n
+
+    @pytest.mark.parametrize("method, stages", [
+        ("trace", ["trace"]), ("lower", ["trace", "lower"])])
+    def test_trace_and_lower_on_the_wrapper_name_its_site(
+            self, ledger, method, stages):
+        f = ledger_jit(lambda x: jnp.cos(x) * 3, site="unit.aot")
+        getattr(f, method)(jax.ShapeDtypeStruct((23,), jnp.float32))
+        rows = [b for b in ledger.births() if b["depth"] == 0]
+        assert [(b["site"], b["stage"]) for b in rows] == [
+            ("unit.aot", st) for st in stages]
+        assert ledger.n_programs() == 0      # no program was recorded
+        # and with the ledger off they are the jit's own
+        LEDGER.enable(False)
+        assert getattr(f, method)(jnp.ones(5)) is not None
+        assert len(ledger.births()) == len(
+            [b for b in ledger.births() if b["site"] == "unit.aot"])
+
+    def test_closed_over_bytes_is_a_trace_at_the_site(self, ledger):
+        from lightgbm_tpu.utils.compile_ledger import closed_over_bytes
+
+        table = jnp.ones((29, 3))
+        f = ledger_jit(lambda x: x + table.sum(), site="unit.gauge")
+        assert closed_over_bytes(f, (jnp.ones(3),), {}, [29]) == 29 * 3 * 4
+        (row,) = [b for b in ledger.births()
+                  if b["site"] == "unit.gauge" and b["depth"] == 0]
+        assert row["stage"] == "trace"
+
+    def test_nested_stages_are_not_counted_twice(self, ledger):
+        """(a) and (c): self seconds add up to no more than the wall of
+        the calls that caused them, in the rows and in the counters."""
+        sites = ("unit.nest", "unit.nest2")
+        before = {(st, s): _stage_counter(st, "seconds", s)
+                  for st in ("trace", "lower") for s in sites}
+        f = ledger_jit(_nested_unit_program, site=sites[0])
+        g = ledger_jit(lambda x: _nested_unit_program(x) * 2,
+                       site=sites[1])
+        t0 = time.perf_counter()
+        f.trace(jax.ShapeDtypeStruct((31,), jnp.float32))
+        f(jnp.ones(31))
+        g(jnp.ones(31))
+        wall = time.perf_counter() - t0
+        rows = [b for b in ledger.births() if b["site"] in sites]
+        nested = sum(b["seconds"] for b in rows)
+        own = sum(b["self_s"] for b in rows)
+        assert own <= wall < nested * 10
+        assert own < nested                   # inner traces were enclosed
+        assert own == pytest.approx(
+            sum(b["seconds"] for b in rows if b["depth"] == 0))
+        counted = sum(_stage_counter(st, "seconds", s) - before[st, s]
+                      for st in ("trace", "lower") for s in sites)
+        assert counted == pytest.approx(
+            sum(b["self_s"] for b in rows if b["stage"] != "compile"))
+        assert counted <= wall
+
+    def test_an_eager_op_outside_every_site_is_none_in_all_stages(
+            self, ledger):
+        from lightgbm_tpu.utils.compile_ledger import NO_SITE
+
+        jnp.ones(37) * 41.5                   # shapes no other test uses
+        rows = [b for b in ledger.births() if b["depth"] == 0]
+        assert rows and {b["site"] for b in rows} == {NO_SITE}
+        assert [b["stage"] for b in rows
+                if "multiply" in b["fun_name"]] == list(self.STAGES)
+
+    def test_stages_are_spans_under_whatever_was_open(self, ledger):
+        from lightgbm_tpu import obs
+
+        obs.configure(mode="trace")
+        obs.reset_events()
+        try:
+            f = ledger_jit(_nested_unit_program, site="unit.tree")
+            with obs.span("unit/build"):
+                f.trace(jax.ShapeDtypeStruct((43,), jnp.float32))
+            with obs.span("unit/dispatch"):
+                f(jnp.ones(43))
+            evs = {e["id"]: e for e in obs.events()}
+        finally:
+            obs.configure(mode="off")
+            obs.reset_events()
+        mine = [e for e in evs.values()
+                if e["tags"].get("site") == "unit.tree"]
+        assert [(e["name"], evs[e["parent_id"]]["name"],
+                 e["tags"]["fun_name"]) for e in mine] == [
+            ("program/trace", "unit/build", "_nested_unit_program"),
+            ("program/trace", "unit/dispatch", "_nested_unit_program"),
+            ("program/lower", "unit/dispatch",
+             "jit(_nested_unit_program)"),
+            ("compile", "unit/dispatch", "jit(_nested_unit_program)")]
+        # the traces inside the first trace are its `inner=`, not spans;
+        # the second found the first in JAX's trace cache
+        assert mine[0]["tags"]["inner"] >= 4
+        assert "inner" not in mine[1]["tags"]
+        assert mine[1]["dur"] < mine[0]["dur"]
+        rows = [b for b in ledger.births()
+                if b["site"] == "unit.tree" and b["depth"] == 0]
+        assert [b["stage"] for b in rows] == ["trace", "trace", "lower",
+                                              "compile"]
+        for e, b in zip(mine, rows):
+            # a span is on the tracer's clock around JAX's own
+            assert e["dur"] / 1e6 >= b["seconds"] * 0.999
+            parent = evs[e["parent_id"]]
+            assert parent["ts"] <= e["ts"] and e["ts"] + e["dur"] \
+                <= parent["ts"] + parent["dur"]
+
+    def test_a_stage_inside_another_stage_is_its_child(self, ledger):
+        """An eager op run while a function is traced is a program of its
+        own, born inside that trace: its spans are the trace's children
+        and its seconds leave the trace's self time."""
+        from lightgbm_tpu import obs
+
+        def folds_a_constant(x):
+            with jax.ensure_compile_time_eval():
+                k = jnp.arange(47.0).sum() * 0.25
+            return x * k
+
+        obs.configure(mode="trace")
+        obs.reset_events()
+        try:
+            ledger_jit(folds_a_constant, site="unit.fold").trace(
+                jax.ShapeDtypeStruct((47,), jnp.float32))
+            evs = obs.events()
+        finally:
+            obs.configure(mode="off")
+            obs.reset_events()
+        (outer,) = [e for e in evs if e["name"] == "program/trace"
+                    and e["parent_id"] is None]
+        inside = [e for e in evs if e is not outer]
+        assert {e["name"] for e in inside} == {"program/lower", "compile"}
+        assert all(e["parent_id"] == outer["id"] for e in inside)
+        assert sum(e["dur"] for e in inside) <= outer["dur"]
+        rows = ledger.births()
+        (row,) = [b for b in rows if b["depth"] == 0]
+        assert row["fun_name"] == "folds_a_constant"
+        assert row["self_s"] == pytest.approx(row["seconds"] - sum(
+            b["seconds"] for b in rows if b["depth"] == 1))
+        assert {b["stage"] for b in rows if b["depth"] >= 1} == set(
+            self.STAGES)
 
 
 class TestBucketPolicy:
