@@ -603,13 +603,21 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     block to VMEM scratch, left-packs its live rows there (bins as 32-bit
     words, the stat planes' bit patterns and the leaf ids, moved together
     and in order: `left_pack`) and runs its dots only over the sub-blocks
-    that then hold a row.  A block with no live row does no dot; a block
-    that packing would not shorten by a sub-block (every block of the root
-    call) is swept as it came.  A dead row added an exact zero, so the sums
-    are the same; only which rows share a sub-block's f32 partial sum
-    moves.  The other two arms (G = 1, 4-bit rows) sweep every row.  What
-    a block's trip count follows is its own live count, which the kernel
-    also writes out, one int32 a block.
+    that then are full.  The last, part-filled one is not contracted with
+    its block: its rows join a carry, one more sub-block of VMEM that
+    holds up to `lanes - 1` rows of earlier blocks, and the sub-block that
+    fills is contracted in the block that fills it (`merge`, one roll of a
+    sub-block's tile).  A feature chunk's last block contracts what is left
+    in the carry, and its first starts with none, so a chunk contracts its
+    packed blocks' live rows in whole sub-blocks but one.  A block with no
+    live row does no dot; a block that packing would not shorten by a
+    sub-block (every block of the root call) is swept as it came and
+    leaves the carry as it was.  A dead row added an exact zero, so the
+    sums are the same; only which rows share a sub-block's f32 partial sum
+    moves, and a block's partial sums still meet the accumulator once.
+    The other two arms (G = 1, 4-bit rows) sweep every row.  The kernel
+    also writes out, one int32 a block each, the block's live rows and,
+    packing, the sub-blocks it contracted.
 
     Returns the [K, F, B, 3] histograms and the call's `_call_rows`: the
     call, the sub-blocks (of `perfeature_dot_lanes(block)` rows) it
@@ -690,6 +698,8 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
 
         def kernel(bins_ref, stats_ref, leaf_ref, slots_ref, out_ref,
                    live_ref, *scratch):
+            # the packing arm also writes the sub-blocks a block contracted
+            sweeps_ref, *scratch = scratch if compacts else (None, *scratch)
             fi = pl.program_id(0)  # feature-chunk axis
             i = pl.program_id(1)   # row-block axis (innermost)
             live_row = live_lanes(leaf_ref, slots_ref)
@@ -772,24 +782,32 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
                     out_ref[rows, :] += acc
                 sweep_block(add)
                 return
-            part, packed = scratch[1], scratch[4]
+            part, packed, carried = scratch[1], scratch[4], scratch[5]
             as_stored = packed.bitcast(bins_t_blocks.dtype) if narrow \
                 else packed
             stats_rows, leaf_row = slice(meta, meta + S), slice(
                 meta + S, meta + S + 1)
+            # `packed` is the block's sub-blocks and one more, the carry:
+            # rows of earlier blocks at its first `carried` lanes, no
+            # slot's leaf id past them
+            rows_of_block = slice(0, block)
+            carry_sb = block // lanes
+            carry_at = slice(block, block + lanes)
 
             def stage():
                 """The block's rows into `packed`, as they came."""
                 if narrow:
-                    packed[0:meta, :] = bins_ref.bitcast(jnp.int32)[0]
+                    packed[0:meta, rows_of_block] = bins_ref.bitcast(
+                        jnp.int32)[0]
                 else:
-                    packed[0:fblk, :] = bins_ref[0].astype(jnp.int32)
+                    packed[0:fblk, rows_of_block] = bins_ref[0].astype(
+                        jnp.int32)
                 s = stats_ref[0]
                 if not jnp.issubdtype(s.dtype, jnp.integer):
                     s = jax.lax.bitcast_convert_type(
                         s.astype(jnp.float32), jnp.int32)
-                packed[stats_rows, :] = s.astype(jnp.int32)
-                packed[leaf_row, :] = leaf_ref[0]
+                packed[stats_rows, rows_of_block] = s.astype(jnp.int32)
+                packed[leaf_row, rows_of_block] = leaf_ref[0]
 
             def left_pack():
                 """`packed`'s live rows moved to its first `n_live` lanes,
@@ -852,18 +870,18 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
 
                 def shifts(j, carry):
                     tile = pl.ds(pl.multiple_of(j * 8, 8), 8)
-                    x = packed[tile, :]
+                    x = packed[tile, rows_of_block]
                     for bit in range(bits):
                         x = jnp.where(
                             comes_to[bit * 8:bit * 8 + 8, :] != 0,
                             pltpu.roll(x, block - (1 << bit), 1), x)
-                    packed[tile, :] = x
+                    packed[tile, rows_of_block] = x
                     return carry
 
                 jax.lax.fori_loop(0, meta // 8 + 1, shifts, 0)
-                packed[leaf_row, :] = jnp.where(
+                packed[leaf_row, rows_of_block] = jnp.where(
                     jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-                    < n_live, packed[leaf_row, :], _NO_SLOT)
+                    < n_live, packed[leaf_row, rows_of_block], _NO_SLOT)
 
             # a block's sub-blocks are summed apart, in `part`, and meet the
             # accumulator once, as a whole block's dot does: its sums run
@@ -879,31 +897,76 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
             def add(rows, acc):
                 part[rows, :] += acc
 
-            def sub_block(sb, carry):
+            def meet(groups):
+                rows = rows_of(groups)
+                out_ref[rows, :] += part[rows, :]
+
+            @pl.when(i == 0)
+            def _():
+                # each feature chunk's row sweep starts with no carry; its
+                # lanes hold zeros, not stale VMEM, since the flush sweeps
+                # them too (a dead lane's stats times a zero)
+                carried[0] = 0
+                packed[:, carry_at] = jnp.zeros((meta + 8, lanes), jnp.int32)
+                packed[leaf_row, carry_at] = jnp.full((1, lanes), _NO_SLOT,
+                                                   jnp.int32)
+
+            # where packing frees no sub-block the rows stay where they
+            # are (a dead row's leaf is no slot's either way), all of them
+            # contracted; a packed block's full sub-blocks are, and its
+            # part-filled one joins the carry: `q` full, `m` rows over
+            packs = (n_live > 0) & (n_live <= block - lanes)
+            r = carried[0]
+            q, m = n_live // lanes, n_live % lanes
+            full = r + m >= lanes
+            held = jnp.where(packs, jnp.where(full, r + m - lanes, r + m), r)
+            carried[0] = held
+            # the contracted sub-blocks: the block's, then the carry's at
+            # the chunk's last block
+            own = jnp.where(packs, jnp.where(full, q + 1, q),
+                            (n_live + lanes - 1) // lanes)
+            trips = jnp.where((i == nb - 1) & (held > 0), own + 1, own)
+            sweeps_ref[i] = trips
+
+            def merge():
+                """The carry's `r` rows and sub-block `q`'s `m`, turned
+                right by `r`, into sub-block `q` and the carry.  Where
+                they fill it, sub-block `q` is contracted and the carry is
+                the rows that turned round (the lanes past them are dead
+                rows of `q` or the carry's own); else the carry is the
+                two."""
+                at = pl.ds(pl.multiple_of(q * lanes, lanes), lanes)
+                x, c = packed[:, at], packed[:, carry_at]
+                turned = pltpu.roll(x, r, 1)
+                lane = jax.lax.broadcasted_iota(jnp.int32, (meta + 8, lanes),
+                                                1)
+                packed[:, at] = jnp.where(lane < r, c, turned)
+                # the lanes the carry takes from `turned`
+                lo, hi = jnp.where(full, 0, r), jnp.where(full, r, lanes)
+                packed[:, carry_at] = jnp.where((lane >= lo) & (lane < hi),
+                                             turned, c)
+
+            pl.when(n_live > 0)(stage)
+
+            @pl.when(packs)
+            def _():
+                left_pack()
+                merge()
+
+            def sub_block(t, state):
+                # the block's sub-blocks in order, then the carry
+                sb = jnp.where(t < own, t, carry_sb)
                 at = pl.ds(pl.multiple_of(sb * lanes, lanes), lanes)
                 stats = packed[stats_rows, at]
                 if not jnp.issubdtype(stats_ref.dtype, jnp.integer):
                     stats = jax.lax.bitcast_convert_type(stats, jnp.float32)
                 sweep(lambda f: as_stored[f, at].astype(jnp.int32),
                       stats, packed[leaf_row, at], add)
-                return carry
+                return state
 
-            def meet(groups):
-                rows = rows_of(groups)
-                out_ref[rows, :] += part[rows, :]
-
-            @pl.when(n_live > 0)
-            def _():
-                stage()
-                # where packing frees no sub-block the rows stay where they
-                # are: a dead row's leaf is no slot's either way
-                pl.when(n_live <= block - lanes)(left_pack)
-                each_run(clear)
-
-            # only the sub-blocks that hold a live row: none of a block
-            # that has none
-            jax.lax.fori_loop(0, (n_live + lanes - 1) // lanes, sub_block, 0)
-            pl.when(n_live > 0)(functools.partial(each_run, meet))
+            pl.when(trips > 0)(functools.partial(each_run, clear))
+            jax.lax.fori_loop(0, trips, sub_block, 0)
+            pl.when(trips > 0)(functools.partial(each_run, meet))
         return kernel
 
     # Mosaic block-shape rule: the last two dims of every block must be
@@ -950,13 +1013,18 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     temporaries = lanes * (Bp * (4 + 4 + dot_bytes) + ks_pad * (4 + 4))
     stacked = (G > 1) * G * Bp * (2 * lanes * dot_bytes + ks_pad * 4)
     part = compacts * fblk * Bp * ks_pad * 4
-    packing = compacts * (meta + 8 + 8 * (10 + pack_bits)) * block * 4
+    packing = compacts * ((meta + 8) * (block + lanes)
+                          + 8 * (10 + pack_bits) * block) * 4
     vmem_limit = pipelined + temporaries + stacked + part + packing
     # grid order: the row-block axis is LAST (innermost), so each
     # feature chunk's accumulator block stays resident while the row
-    # sweep accumulates into it.  The second output is a block's live
-    # rows, a scalar a grid step (every feature chunk writes the same)
-    raw, live_rows = pl.pallas_call(
+    # sweep accumulates into it, and in order (`arbitrary`): the carry
+    # goes from one row block to the next.  The second output is a
+    # block's live rows, the packing arm's third the sub-blocks it
+    # contracted, a scalar a grid step each (every feature chunk writes
+    # the same)
+    counts = pl.BlockSpec(memory_space=pltpu.SMEM)
+    raw, live_rows, *sweeps = pl.pallas_call(
         kernel_perfeature_chunk(fblk, nf, G, lanes, compacts, narrow, meta),
         grid=(nf, nb),
         in_specs=[
@@ -967,16 +1035,19 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
         ],
         out_specs=(
             pl.BlockSpec((fblk * Bp, K * S), lambda fi, i: (fi, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM)),
+            counts, *[counts] * compacts),
         out_shape=(jax.ShapeDtypeStruct((F * Bp, K * S), acc_dtype),
-                   jax.ShapeDtypeStruct((nb,), jnp.int32)),
+                   *[jax.ShapeDtypeStruct((nb,), jnp.int32)] * (1 + compacts)),
         scratch_shapes=(
             [pltpu.VMEM((G * Bp, lanes), dot_dtype)] * (G > 1)
             + [pltpu.VMEM((fblk * Bp, K * S), acc_dtype),
                pltpu.VMEM((8 * pack_bits, block), jnp.int32),
                pltpu.VMEM((8 * pack_bits, block // 8), jnp.int32),
-               pltpu.VMEM((meta + 8, block), jnp.int32)] * compacts),
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+               pltpu.VMEM((meta + 8, block + lanes), jnp.int32),
+               pltpu.SMEM((1,), jnp.int32)] * compacts),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
     )(bins_t_blocks, stats_nb, leaf_blocks.reshape(nb, 1, block),
       slot_leaf_ids.reshape(K, 1))
@@ -984,7 +1055,7 @@ def _hist_pallas(bins_t_blocks, stats_blocks, leaf_blocks, slot_leaf_ids,
     raw = raw.reshape(K, S, F * B)
     hist = jax.vmap(lambda r: _unpack_hist(r.reshape(S, F * B), precision))(
         raw)
-    contracted = (-(-live_rows // lanes) if compacts
+    contracted = (sweeps[0] if compacts
                   else nb * (block // perfeature_dot_lanes(block)))
     return hist.reshape(K, F, B, 3), _call_rows(contracted, live_rows)
 

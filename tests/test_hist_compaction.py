@@ -1,10 +1,12 @@
 """Row compaction in the pallas2 histogram kernel (interpret mode off-TPU):
 a row block's live rows (leaf one of the call's slots) are left-packed
-before the one-hot and only the lane sub-blocks that then hold a row are
-contracted.  The histograms against the xla scan's over slots x live share x
+before the one-hot, only the lane sub-blocks that then are full are
+contracted, and the part-filled last one is carried into the next packed
+block.  The histograms against the xla scan's over slots x live share x
 feature chunks x precision, the edges of the mechanism (no live row, one
 row, a sub-block's last lane and the next, a dead slot beside the packed
-tail, a padded last block, the all-live root call), what the kernel says it
+tail, a padded last block, the all-live root call, the carry across a
+block, a dead or unpacked one, the last block and a chunk), what the kernel says it
 did with the rows against numpy, the two arms that do not pack, whole
 255-leaf trees, the `lgbm_hist_rows_per_tree` gauges against a recount from
 the model text, and the cells' kernel shapes compiled for a described v5e.
@@ -89,8 +91,15 @@ def assert_same(a, b, precision, live_columns=None):
 
 
 def want_rows(leaf, slots, lanes):
+    """[calls, sub-blocks contracted, live rows] of a call, by the carried
+    rule: every sub-block of a block that packing would not shorten, and
+    the packed blocks' live rows in whole sub-blocks, the last one
+    part-filled (a feature chunk's; every chunk sees the same rows)."""
     live = np.isin(leaf, [s for s in slots if s >= 0]).sum(axis=1)
-    return [1, int((-(-live // lanes)).sum()), int(live.sum())]
+    packs = (live > 0) & (live <= BLOCK - lanes)
+    unpacked = (live > BLOCK - lanes).sum()
+    return [1, int(BLOCK // lanes * unpacked + -(-live[packs].sum() // lanes)),
+            int(live.sum())]
 
 
 SHARES = {"none": 0.0, "one-row": None, "3%": 0.03, "21%": 0.21,
@@ -185,7 +194,9 @@ def test_a_padded_last_block(small_lanes):
 
 def test_rows_the_kernel_reports_are_numpys(small_lanes):
     """Blocks of very different live shares: the sub-blocks contracted are
-    the per-block ceilings, not the ceiling of the sum."""
+    every sub-block of the blocks packing would not shorten and the
+    ceiling of the packed blocks' summed live rows, not a ceiling a
+    block."""
     rng = np.random.default_rng(4)
     nb = 6
     bins, stats = table(rng, nb, 32, BLOCK, "int8")
@@ -194,9 +205,65 @@ def test_rows_the_kernel_reports_are_numpys(small_lanes):
     _, _, rows = both(bins, stats, leaf, SLOTS[16], "int8", live_columns=28)
     per_block = np.isin(leaf, SLOTS[16]).sum(axis=1)
     assert per_block[0] == 0 and per_block[-1] == BLOCK
-    assert list(rows) == [1, int(np.ceil(per_block / LANES).sum()),
+    packs = (per_block > 0) & (per_block <= BLOCK - LANES)
+    assert list(rows) == [1, int(BLOCK // LANES * (per_block > BLOCK - LANES).sum()
+                                 + np.ceil(per_block[packs].sum() / LANES)),
                           int(per_block.sum())]
-    assert rows[1] < nb * BLOCK // LANES
+    assert rows[1] < int(np.ceil(per_block / LANES).sum())
+
+
+# live rows a block (of 512 at 128 lanes; 384 and under are packed), the
+# sub-blocks the carried rule contracts, and what a ceiling a block would
+CARRIES = {
+    "crosses-a-block": ([80, 80, 80], 2, 3),
+    "dead-block-between": ([50, 0, 50], 1, 2),
+    "unpacked-block-with-carry-pending": ([40, 400, 40], 5, 6),
+    "flush-at-a-packed-last-block": ([0, 0, 30], 1, 1),
+    "flush-at-a-dead-last-block": ([30, 0, 0], 1, 1),
+    "flush-after-an-unpacked-last-block": ([30, 0, 500], 5, 5),
+    "fills-the-sub-block-exactly": ([100, 28, 0], 1, 2),
+    "fills-it-behind-a-full-one": ([100, 156, 0], 2, 3),
+    "one-row-over": ([100, 29, 0], 2, 2),
+}
+
+
+@pytest.mark.parametrize("precision", ["hilo", "int8"])
+@pytest.mark.parametrize("case", list(CARRIES))
+def test_a_part_filled_sub_block_is_carried(small_lanes, case, precision):
+    """A packed block's part-filled sub-block waits for the next packed
+    block's rows and is contracted once full, or at the last block: the
+    histograms are xla's and the count is numpy's."""
+    counts, carried, per_block = CARRIES[case]
+    rng = np.random.default_rng(sum(counts) + len(case))
+    bins, stats = table(rng, NB, 32, BLOCK, precision)
+    live = np.zeros((NB, BLOCK), bool)
+    for blk, count in enumerate(counts):
+        live[blk, rng.permutation(BLOCK)[:count]] = True
+    leaf = leaves(rng, live, SLOTS[4])
+    a, b, rows = both(bins, stats, leaf, SLOTS[4], precision,
+                      live_columns=28)
+    assert_same(a, b, precision, 28)
+    assert list(rows) == want_rows(leaf, SLOTS[4], LANES)
+    assert list(rows) == [1, carried, sum(counts)]
+    assert int(sum(-(-c // LANES) for c in counts)) == per_block
+
+
+@pytest.mark.parametrize("counts", [[80, 80, 80], [100, 300, 0]])
+def test_each_feature_chunk_starts_with_no_carry(small_lanes, counts):
+    """On the three-chunk grid each chunk sweeps the same rows over its
+    own columns; a chunk's last block flushes the carry, and the next
+    chunk's first block finds none, its rows in its own columns."""
+    rng = np.random.default_rng(sum(counts))
+    assert H.perfeature_chunks(96, B, 4, 5, 1) == (32, 3)
+    bins, stats = table(rng, NB, 96, BLOCK, "hilo")
+    live = np.zeros((NB, BLOCK), bool)
+    for blk, count in enumerate(counts):
+        live[blk, rng.permutation(BLOCK)[:count]] = True
+    leaf = leaves(rng, live, SLOTS[4])
+    a, b, rows = both(bins, stats, leaf, SLOTS[4], "hilo", live_columns=67)
+    assert_same(a, b, "hilo", 67)
+    assert list(rows) == want_rows(leaf, SLOTS[4], LANES)
+    assert rows[1] == -(-sum(counts) // LANES)
 
 
 def test_the_root_call_runs_the_unpacked_sweep(small_lanes, monkeypatch):
